@@ -40,6 +40,7 @@ from alphafold2_tpu.model.primitives import (
     attention_output_tail,
     zeros_init,
 )
+from alphafold2_tpu.runtime import on_tpu
 
 
 def _dense_factory(module_dtype):
@@ -421,11 +422,7 @@ class BlockSparseAttention(nn.Module):
         `ops.use_pallas_attention(True)` (interpreter mode — exactness
         tests), so CPU tier-1 keeps the cheap masked-dense fallback."""
         from alphafold2_tpu.ops.attention import pallas_attention_enabled
-        from alphafold2_tpu.ops.block_sparse import (HAS_PALLAS,
-                                                     on_tpu_backend)
-        if not HAS_PALLAS:
-            return False
-        return on_tpu_backend() or pallas_attention_enabled()
+        return on_tpu() or pallas_attention_enabled()
 
     @nn.compact
     def __call__(self, x, mask=None, deterministic: bool = True):
@@ -461,7 +458,7 @@ class BlockSparseAttention(nn.Module):
                 heads=h,                           # replays across heads
                 scale=1.0,                         # project_qkv pre-scales
                 block=self.block,
-                interpret=jax.default_backend() == "cpu")
+                interpret=not on_tpu())
             return attn.finish(out.reshape(b, h, n, dh), x)
 
         pattern = block_sparse_mask(n, self.block, self.num_global,

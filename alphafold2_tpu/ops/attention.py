@@ -38,12 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # pallas import is TPU/CPU-safe; guard for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 # Large-negative fill for masked logits (matches model/primitives.py).
 MASK_VALUE = -1e9
@@ -53,7 +48,7 @@ _BACKEND = {"pallas": False}
 
 def use_pallas_attention(enabled: bool = True):
     """Globally select the Pallas fused-attention path."""
-    _BACKEND["pallas"] = enabled and HAS_PALLAS
+    _BACKEND["pallas"] = bool(enabled)
 
 
 def pallas_attention_enabled() -> bool:

@@ -118,13 +118,10 @@ def pipeline_apply(
                              jax.tree.map(lambda x: x[0], xs), zero)
         # the carry becomes device-varying after the first tick; mark the
         # init values as varying over the pipe axis so scan's carry types
-        # line up (jax>=0.8 shard_map vma typing; older jax has no vma
-        # types — and no pcast — so the marking is a no-op there)
-        pcast = getattr(jax.lax, "pcast", None)
-        mark = (lambda x: pcast(jnp.zeros_like(x), (axis_name,),
-                                to="varying")) if pcast is not None \
-            else jnp.zeros_like
-        outputs0 = jax.tree.map(mark, xs)
+        # line up (shard_map vma typing)
+        outputs0 = jax.tree.map(
+            lambda x: jax.lax.pcast(jnp.zeros_like(x), (axis_name,),
+                                    to="varying"), xs)
         ring = [(s, (s + 1) % s_count) for s in range(s_count)]
 
         def tick(carry, t):

@@ -25,15 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from alphafold2_tpu.parallel.sharding import shard_map_compat
 
 
-def _axis_size(axis_name) -> jnp.ndarray:
-    """jax.lax.axis_size where it exists (jax >= 0.8); the classic
-    psum-of-ones identity on older jax."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _block_attend(q, k, v, bias, acc, row_max, row_sum):
     """One blockwise online-softmax update.
 
@@ -65,7 +56,7 @@ def ring_attention(
     """Exact attention where each device holds one K/V shard; runs inside
     shard_map/pmap over `axis_name`. bias/mask carry the GLOBAL key axis
     (every device already holds its full rows of pair bias)."""
-    n_shards = _axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     nk = k.shape[-2]
 
@@ -251,7 +242,7 @@ def pair_row_attention_sharded(
             coords.append(jax.lax.axis_index(j_axis))
             dev_key = _device_dropout_key(rest.pop(0), coords)
         b, h, il, jl, d = qi.shape
-        n_shards = _axis_size(j_axis)
+        n_shards = jax.lax.axis_size(j_axis)
         my_idx = jax.lax.axis_index(j_axis)
         perm = [(s, (s + 1) % n_shards) for s in range(n_shards)]
 

@@ -34,38 +34,16 @@ def active_mesh() -> Optional[Mesh]:
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs,
                      manual_axes: Optional[frozenset] = None,
                      check: Optional[bool] = None):
-    """Version-tolerant shard_map: `jax.shard_map` (jax >= 0.8 — manual
-    axes via `axis_names`, replication typing via `check_vma`) or
-    `jax.experimental.shard_map.shard_map` (jax 0.4.x — the complement
-    `auto=` set and `check_rep`). `manual_axes=None` means fully manual;
-    `check=None` keeps each API's default."""
+    """`jax.shard_map` under the call shape this package uses:
+    `manual_axes=None` means fully manual, `check=None` keeps the
+    default replication typing (`check_vma`)."""
     kw = {}
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        if manual_axes is not None:
-            kw["axis_names"] = frozenset(manual_axes)
-        if check is not None:
-            kw["check_vma"] = check
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as legacy_sm
     if manual_axes is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
+        kw["axis_names"] = frozenset(manual_axes)
     if check is not None:
-        kw["check_rep"] = check
-    return legacy_sm(f, mesh, in_specs, out_specs, **kw)
-
-
-def _enter_mesh(mesh: Mesh):
-    """The version-tolerant ambient-mesh context: `jax.set_mesh` where
-    it exists (jax >= 0.5), else the Mesh's own resource-env context
-    manager (jax 0.4.x — `with mesh:`). Constraints here always name
-    their mesh explicitly via NamedSharding, so the ambient context
-    only matters for closures traced under jit."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+        kw["check_vma"] = check
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 @contextlib.contextmanager
@@ -86,7 +64,7 @@ def use_mesh(mesh: Optional[Mesh], manual_axes: frozenset = frozenset()):
     _state.manual = frozenset(manual_axes)
     try:
         if mesh is not None and not manual_axes:
-            with _enter_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield mesh
         else:
             # inside a shard_map body the ambient mesh is already manual;
@@ -121,11 +99,8 @@ def _constraint(x, spec: P):
         # inside a shard_map body the constraint must name the mesh view
         # whose axis types carry the enclosing Manual axes — that is the
         # trace-time abstract mesh, not the concrete one we stored
-        # (jax < 0.5 has no abstract-mesh API; the concrete-mesh
-        # fallback below is what those versions expect)
-        get_amesh = getattr(jax.sharding, "get_abstract_mesh", None)
-        amesh = get_amesh() if get_amesh is not None else None
-        if amesh is not None and amesh.axis_names:
+        amesh = jax.sharding.get_abstract_mesh()
+        if amesh.axis_names:
             return jax.lax.with_sharding_constraint(
                 x, NamedSharding(amesh, P(*cleaned)))
     return jax.lax.with_sharding_constraint(

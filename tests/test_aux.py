@@ -76,7 +76,6 @@ def test_training_scripts_run(tmp_path, script, extra):
     """The reference's train scripts are stale/broken (SURVEY.md §2.6);
     ours must actually run: 3 tiny steps on synthetic data."""
     env = dict(os.environ)
-    env.pop("PYTHONPATH", None)
     env["JAX_PLATFORMS"] = "cpu"
     cfg = {
         "model": {"dim": 32, "depth": 1, "heads": 2, "dim_head": 16,

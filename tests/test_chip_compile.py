@@ -1,0 +1,146 @@
+"""Ask the chip's compiler before the chip: compile the main path's
+kernels (and the scan fold) at real widths for a DESCRIBED v5e:2x2 — the
+TPU compiler is installed here, the chip is not (on-chip-measurement
+guide, section 2). Mosaic refusals that interpret mode cannot see — a
+slice off the tiling, too much fast memory — fail here at no chip time.
+
+Nothing runs: these tests say nothing about results or times. A compile
+that passes is not a chip run; `chip_smoke.py` is.
+
+All such tests live in THIS file (one xdist worker loads the TPU library
+and keeps it), the topology is described inside a module-scoped fixture
+(never at import/collection/parametrize time), and the persistent compile
+cache is off around the compiles (an entry written for a described chip
+cannot be read back without one).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from alphafold2_tpu import Alphafold2, predict
+from alphafold2_tpu.ops.attention import fused_attention
+from alphafold2_tpu.ops.block_sparse import (banded_block_pattern,
+                                             block_sparse_attention)
+
+HEADS, D, BLOCK, FOLD_AXIS = 8, 64, 128, 2
+LENGTHS = (256, 384, 1024)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _attention_shapes(n, sharding):
+    """q/k/v bf16 (B, n, D) with heads folded innermost and a folded axis
+    of 2, the unrepeated f32 pair bias, and the (B // heads, n) key mask —
+    the layout model/primitives.py hands both kernels."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    b = FOLD_AXIS * HEADS
+    qkv = sds((b, n, D), jnp.bfloat16)
+    return (qkv, qkv, qkv, sds((HEADS, n, n), jnp.float32),
+            sds((FOLD_AXIS, n), jnp.bool_))
+
+
+def _compiled_kernel_text(fn, shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text   # Mosaic, not the interpreter
+    return text
+
+
+@pytest.mark.parametrize("n", LENGTHS)
+def test_fused_attention_compiles_for_v5e(n, one_chip, no_persistent_cache):
+    def fwd(q, k, v, bias, mask):
+        return fused_attention(q, k, v, bias=bias, q_mask=mask, k_mask=mask,
+                               heads=HEADS, bias_repeat=FOLD_AXIS)
+
+    _compiled_kernel_text(fwd, _attention_shapes(n, one_chip))
+
+
+@pytest.mark.parametrize("n", LENGTHS)
+def test_block_sparse_attention_compiles_for_v5e(n, one_chip,
+                                                 no_persistent_cache):
+    pattern = banded_block_pattern(n // BLOCK, window=0, num_global=1)
+
+    def fwd(q, k, v, bias, mask):
+        return block_sparse_attention(
+            q, k, v, pattern, bias=bias, bias_repeat=FOLD_AXIS, k_mask=mask,
+            heads=HEADS, scale=1.0, block=BLOCK)
+
+    _compiled_kernel_text(fwd, _attention_shapes(n, one_chip))
+
+
+@pytest.mark.parametrize("n", LENGTHS[:2])
+def test_fused_attention_gradient_compiles_for_v5e(n, one_chip,
+                                                   no_persistent_cache):
+    """jax.grad through the custom_vjp: Pallas forward, XLA-recompute
+    backward, cotangents for q/k/v and the unrepeated bias."""
+    def loss(q, k, v, bias, mask):
+        out = fused_attention(q, k, v, bias=bias, k_mask=mask, heads=HEADS,
+                              bias_repeat=FOLD_AXIS)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _compiled_kernel_text(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                          _attention_shapes(n, one_chip))
+
+
+def test_scan_fold_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The 3-recycle predict.fold of chip_smoke.py's model (published
+    widths, depth 2) at L=256, MSA 5, from eval_shape parameter shapes; the
+    program must fit one v5e's 16 GB with room to spare."""
+    import chip_smoke
+
+    n, m = chip_smoke.BUCKET, chip_smoke.MSA_DEPTH
+    model = Alphafold2(predict_coords=True, dtype=jnp.bfloat16,
+                       **chip_smoke.FULL_MODEL)
+    seq = jnp.zeros((1, n), jnp.int32)
+    msa = jnp.zeros((1, m, n), jnp.int32)
+    params = jax.eval_shape(
+        functools.partial(model.init, msa=msa, mask=jnp.ones((1, n), bool),
+                          msa_mask=jnp.ones((1, m, n), bool)),
+        jax.random.PRNGKey(0), seq)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree.map(lambda s: sds(s.shape, s.dtype), params)
+
+    def fold(params, seq, msa, mask, msa_mask):
+        return predict.fold(model, params, seq, msa=msa, mask=mask,
+                            msa_mask=msa_mask,
+                            num_recycles=chip_smoke.NUM_RECYCLES)
+
+    compiled = jax.jit(fold).lower(
+        params, sds((1, n), jnp.int32), sds((1, m, n), jnp.int32),
+        sds((1, n), jnp.bool_), sds((1, m, n), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < 8 * 2**30, total

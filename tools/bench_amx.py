@@ -22,7 +22,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.pop("PYTHONPATH", None)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

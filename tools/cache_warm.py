@@ -198,9 +198,9 @@ def load_serve_log_profile(log_dir: str):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    import __graft_entry__
     if args.platform == "cpu":
-        __graft_entry__.force_cpu_fallback()
+        from alphafold2_tpu.runtime import use_cpu_platform
+        use_cpu_platform()
     if args.emit_synthetic:
         return emit_synthetic(args)
     if not args.freq and not args.from_serve_log:

@@ -6,8 +6,9 @@
 # (r05, jax 0.9; crash is in-process-state dependent — every file is
 # green standalone). conftest.py also clears jax caches between modules,
 # which mitigates the monolithic run; this runner is the isolation-
-# guaranteed form. The persistent per-platform compile cache keeps the
-# chunked wall time close to the monolithic one.
+# guaranteed form. The persistent compile cache (<checkout>/.jax_cache,
+# or JAX_COMPILATION_CACHE_DIR) keeps the chunked wall time close to the
+# monolithic one. CPU only (tests/conftest.py names the platform).
 #
 # Usage: sh tools/run_suite.sh [extra pytest args]
 set -u
@@ -17,6 +18,6 @@ PY="${PYTHON:-/opt/venv/bin/python}"
 fail=0
 for f in tests/test_*.py; do
   echo "== $f"
-  env -u PYTHONPATH "$PY" -m pytest "$f" -q --no-header "$@" || fail=1
+  "$PY" -m pytest "$f" -q --no-header "$@" || fail=1
 done
 exit $fail

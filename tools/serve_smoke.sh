@@ -211,6 +211,10 @@
 #
 # The overall timeouts leave headroom for the cold per-bucket compiles
 # (warmup is excluded from the serving window but not from wall clock).
+#
+# CPU only, by construction: every command below names JAX_PLATFORMS=cpu
+# and the multi-process phases spawn CPU replicas (ProcFleet). Nothing
+# here touches a chip; the chip's smoke is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -227,7 +231,7 @@ phase_on() {
 if phase_on 1; then
 rm -f /tmp/serve_smoke_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --duration-s "$DURATION" \
@@ -246,7 +250,7 @@ fi
 if phase_on 2; then
 rm -f /tmp/serve_smoke_dup_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --requests 48 \
@@ -268,11 +272,11 @@ fi
 # (non-zero fold span for accelerator-served ones, no orphan spans,
 # schema-versioned) and the Prometheus exposition parses
 if phase_on 3; then
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_traces.jsonl \
     --check --prom /tmp/serve_smoke.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_dup_traces.jsonl \
     --check --prom /tmp/serve_smoke_dup.prom
 fi
@@ -285,7 +289,7 @@ rm -f /tmp/serve_smoke_fleet_traces.jsonl
 
 fleet_phase() {  # $1 = on|off, $2 = report path, extra args follow
     local mode="$1" out="$2"; shift 2
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 48 \
@@ -312,14 +316,14 @@ fleet_phase on /tmp/serve_smoke_fleet.json \
     --trace-path /tmp/serve_smoke_fleet_traces.jsonl \
     --prom-path /tmp/serve_smoke_fleet.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_fleet_traces.jsonl \
     --check --prom /tmp/serve_smoke_fleet.prom
 
 # the fleet must measurably beat independent replicas on the same
 # duplicated traffic, and the epoch bump must have produced zero
 # stale-tag hits
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_fleet_base.json"))
 fleet = json.load(open("/tmp/serve_smoke_fleet.json"))
@@ -358,7 +362,7 @@ fi
 if phase_on 5; then
 rm -f /tmp/serve_smoke_chaos_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --chaos \
@@ -378,7 +382,7 @@ timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
     --trace-path /tmp/serve_smoke_chaos_traces.jsonl \
     --prom-path /tmp/serve_smoke_chaos.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_chaos_traces.jsonl \
     --check --prom /tmp/serve_smoke_chaos.prom
 fi
@@ -394,7 +398,7 @@ if phase_on 6; then
 rm -rf /tmp/serve_smoke_procs
 rm -f /tmp/serve_smoke_procs_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --procs 3 \
@@ -415,7 +419,7 @@ timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
     --trace-path /tmp/serve_smoke_procs_traces.jsonl \
     --prom-path /tmp/serve_smoke_procs.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_procs_traces.jsonl \
     --check --prom /tmp/serve_smoke_procs.prom
 fi
@@ -429,7 +433,7 @@ fi
 if phase_on 7; then
 rm -f /tmp/serve_smoke_mesh_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python tools/serve_loadtest.py \
     --smoke \
@@ -446,7 +450,7 @@ timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
     --trace-path /tmp/serve_smoke_mesh_traces.jsonl \
     --prom-path /tmp/serve_smoke_mesh.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_mesh_traces.jsonl \
     --check --prom /tmp/serve_smoke_mesh.prom
 fi
@@ -461,7 +465,7 @@ rm -f /tmp/serve_smoke_recycle_traces.jsonl
 
 recycle_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 48 \
@@ -484,11 +488,11 @@ recycle_phase /tmp/serve_smoke_recycle.json \
     --trace-path /tmp/serve_smoke_recycle_traces.jsonl \
     --prom-path /tmp/serve_smoke_recycle.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_recycle_traces.jsonl \
     --check --prom /tmp/serve_smoke_recycle.prom
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_recycle_base.json"))
 sched = json.load(open("/tmp/serve_smoke_recycle.json"))
@@ -530,7 +534,7 @@ rm -f /tmp/serve_smoke_feat_traces.jsonl
 
 feature_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 32 \
@@ -555,11 +559,11 @@ feature_phase /tmp/serve_smoke_feat.json \
     --trace-path /tmp/serve_smoke_feat_traces.jsonl \
     --prom-path /tmp/serve_smoke_feat.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_feat_traces.jsonl \
     --check --prom /tmp/serve_smoke_feat.prom
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_feat_base.json"))
 pipe = json.load(open("/tmp/serve_smoke_feat.json"))
@@ -617,7 +621,7 @@ rm -f /tmp/serve_smoke_cont_traces.jsonl
 
 cont_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 64 \
@@ -643,11 +647,11 @@ cont_phase /tmp/serve_smoke_cont.json \
     --trace-path /tmp/serve_smoke_cont_traces.jsonl \
     --prom-path /tmp/serve_smoke_cont.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_cont_traces.jsonl \
     --check --prom /tmp/serve_smoke_cont.prom
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_cont_base.json"))
 cont = json.load(open("/tmp/serve_smoke_cont.json"))
@@ -711,7 +715,7 @@ rm -f /tmp/serve_smoke_kernel_traces.jsonl
 
 kernel_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 32 \
@@ -735,11 +739,11 @@ kernel_phase /tmp/serve_smoke_kernel.json \
     --trace-path /tmp/serve_smoke_kernel_traces.jsonl \
     --prom-path /tmp/serve_smoke_kernel.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_kernel_traces.jsonl \
     --check --prom /tmp/serve_smoke_kernel.prom
 
-env -u PYTHONPATH python - <<'EOF2'
+python - <<'EOF2'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_kernel_base.json"))
 sparse = json.load(open("/tmp/serve_smoke_kernel.json"))
@@ -813,7 +817,7 @@ rm -f /tmp/serve_smoke_xb_traces.jsonl
 
 xb_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 64 \
@@ -837,11 +841,11 @@ xb_phase /tmp/serve_smoke_xb.json \
     --trace-path /tmp/serve_smoke_xb_traces.jsonl \
     --prom-path /tmp/serve_smoke_xb.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_xb_traces.jsonl \
     --check --prom /tmp/serve_smoke_xb.prom
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_xb_base.json"))
 xb = json.load(open("/tmp/serve_smoke_xb.json"))
@@ -918,7 +922,7 @@ rm -f /tmp/serve_smoke_stepfault_traces.jsonl
 
 stepfault_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --chaos \
@@ -949,11 +953,11 @@ stepfault_phase /tmp/serve_smoke_stepfault.json \
     --trace-path /tmp/serve_smoke_stepfault_traces.jsonl \
     --prom-path /tmp/serve_smoke_stepfault.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_stepfault_traces.jsonl \
     --check --prom /tmp/serve_smoke_stepfault.prom
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 base = json.load(open("/tmp/serve_smoke_stepfault_base.json"))
 hard = json.load(open("/tmp/serve_smoke_stepfault.json"))
@@ -1022,7 +1026,7 @@ if phase_on 14; then
 rm -rf /tmp/serve_smoke_obsfleet /tmp/serve_smoke_obsfleet_out
 rm -f /tmp/serve_smoke_obsfleet_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --procs 3 \
@@ -1046,13 +1050,13 @@ cat /tmp/serve_smoke_obsfleet.json
 
 # the merged driver+replica trace file + the per-replica /metrics
 # scrapes, through the fleet aggregator's tripwire
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_fleet.py /tmp/serve_smoke_obsfleet_traces.jsonl \
     --prom-dir /tmp/serve_smoke_obsfleet_out \
     --check --json > /tmp/serve_smoke_obsfleet_fleet.json
 cat /tmp/serve_smoke_obsfleet_fleet.json
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 run = json.load(open("/tmp/serve_smoke_obsfleet.json"))
 agg = json.load(open("/tmp/serve_smoke_obsfleet_fleet.json"))
@@ -1102,7 +1106,7 @@ rm -rf /tmp/serve_smoke_ctrl /tmp/serve_smoke_ctrl_out \
        /tmp/serve_smoke_ctrl_warmcache
 rm -f /tmp/serve_smoke_ctrl_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --procs 3 \
@@ -1130,7 +1134,7 @@ cat /tmp/serve_smoke_ctrl.json
 
 # merged traces + run dir (controller traces, decision log, keys) +
 # scrapes through the fleet aggregator — identity pins included
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_fleet.py /tmp/serve_smoke_ctrl_traces.jsonl \
     /tmp/serve_smoke_ctrl \
     --prom-dir /tmp/serve_smoke_ctrl_out \
@@ -1139,7 +1143,7 @@ cat /tmp/serve_smoke_ctrl_fleet.json
 
 # the telemetry-driven warm: rebuild a profile from the run's own
 # keys.jsonl records and warm its head into a fresh cache dir
-timeout -k 10 300 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 300 env JAX_PLATFORMS=cpu \
     python tools/cache_warm.py \
     --from-serve-log /tmp/serve_smoke_ctrl \
     --top 2 \
@@ -1149,7 +1153,7 @@ timeout -k 10 300 env -u PYTHONPATH JAX_PLATFORMS=cpu \
     > /tmp/serve_smoke_ctrl_warm.json
 cat /tmp/serve_smoke_ctrl_warm.json
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 run = json.load(open("/tmp/serve_smoke_ctrl.json"))
 agg = json.load(open("/tmp/serve_smoke_ctrl_fleet.json"))
@@ -1235,7 +1239,7 @@ if phase_on 16; then
 rm -rf /tmp/serve_smoke_bulk
 mkdir -p /tmp/serve_smoke_bulk
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python - <<'EOF'
 import json
 import os
@@ -1452,7 +1456,7 @@ fi
 if phase_on 17; then
 casc_phase() {  # $1 = report path, extra args follow
     local out="$1"; shift
-    timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    timeout -k 10 600 env JAX_PLATFORMS=cpu \
         python tools/serve_loadtest.py \
         --smoke \
         --requests 48 \
@@ -1473,7 +1477,7 @@ casc_phase /tmp/serve_smoke_casc_on.json \
     --cascade --draft-accept-rate 0.6 \
     --prom-path /tmp/serve_smoke_casc.prom
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python - <<'EOF'
 import json
 import sys
@@ -1561,7 +1565,7 @@ if phase_on 18; then
 rm -rf /tmp/serve_smoke_preempt
 rm -f /tmp/serve_smoke_preempt_traces.jsonl
 
-timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 600 env JAX_PLATFORMS=cpu \
     python tools/serve_loadtest.py \
     --smoke \
     --procs 3 \
@@ -1583,11 +1587,11 @@ timeout -k 10 600 env -u PYTHONPATH JAX_PLATFORMS=cpu \
     > /tmp/serve_smoke_preempt.json
 cat /tmp/serve_smoke_preempt.json
 
-timeout -k 10 120 env -u PYTHONPATH JAX_PLATFORMS=cpu \
+timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/obs_report.py /tmp/serve_smoke_preempt_traces.jsonl \
     --check --json > /tmp/serve_smoke_preempt_obs.json
 
-env -u PYTHONPATH python - <<'EOF'
+python - <<'EOF'
 import json, sys
 run = json.load(open("/tmp/serve_smoke_preempt.json"))
 obs = json.load(open("/tmp/serve_smoke_preempt_obs.json"))
