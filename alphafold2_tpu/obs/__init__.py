@@ -2,7 +2,7 @@
 
 Three uncoordinated telemetry surfaces grew up with the serving stack
 (`StepTimer`, `ServeMetrics`' per-batch JSONL, `MetricsLogger`); this
-package replaces their private bookkeeping with one pair of primitives:
+package replaces their private bookkeeping with these primitives:
 
 - trace:    request-scoped spans with stable trace IDs, created at
             `Scheduler.submit` and propagated through coalescing
@@ -13,6 +13,12 @@ package replaces their private bookkeeping with one pair of primitives:
 - registry: process-wide `MetricsRegistry` (counter / gauge /
             histogram with fixed exponential latency buckets, labels,
             thread-safe) that serve, cache, and train report into.
+- device:   the same clock's other half: device time by kernel from the
+            program's own executable (`device.profile`, the kernel
+            vocabulary `device.KERNELS`), and a profiler capture's idle
+            gaps booked to the scheduler worker's intervals, which the
+            tracer enters as `jax.profiler.TraceAnnotation`s
+            (`device.reduce`).
 - export:   Prometheus text exposition + JSONL sharing one versioned
             `"schema": 1` record convention; `flatten()` for
             arbitrary-depth dict keys.
@@ -21,6 +27,7 @@ package replaces their private bookkeeping with one pair of primitives:
 top-K slowest traces from a trace JSONL file (README "Observability").
 """
 
+from alphafold2_tpu.obs import device  # noqa: F401
 from alphafold2_tpu.obs.export import (JsonlExporter, SCHEMA_VERSION,  # noqa: F401
                                        flatten, prometheus_text,
                                        registry_json, write_prometheus)

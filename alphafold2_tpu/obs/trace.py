@@ -26,7 +26,14 @@ Design constraints, in priority order:
   record, never an orphan;
 - completed traces are emitted as one JSONL record each (`"schema": 1`,
   spans with offsets relative to trace start) and the K slowest are
-  kept in a ring the scheduler exposes via `serve_stats()["traces"]`.
+  kept in a ring the scheduler exposes via `serve_stats()["traces"]`;
+- one clock with the device: every `span()` scope of an enabled trace
+  (and `Tracer.annotate`, for intervals that belong to the worker and
+  to no request) also enters a `jax.profiler.TraceAnnotation` of the
+  same name, so that in any profiler capture the program's intervals
+  and the device's operations lie on one time base
+  (`obs.device.reduce` books the device's idle gaps to them). Outside a
+  capture an annotation costs a flag test; the null path enters none.
 
 Cross-process propagation (ISSUE 15): a trace CROSSES the RPC seam.
 `Trace.wire_context()` mints a `TraceContext` — trace id + a fresh
@@ -58,6 +65,8 @@ import time
 import uuid
 from dataclasses import dataclass
 from typing import IO, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # the one schema tag every observability record carries (obs/export.py)
 from alphafold2_tpu.obs.export import SCHEMA_VERSION
@@ -158,20 +167,26 @@ NULL_TRACE = _NullTrace()
 
 
 class _SpanContext:
-    __slots__ = ("_trace", "_name", "_attrs", "_t0")
+    """A timed scope of one trace (or, through `MultiTrace`, of a batch's
+    members), entered as a profiler annotation of the same name too."""
+
+    __slots__ = ("_trace", "_name", "_attrs", "_t0", "_annotation")
 
     def __init__(self, trace, name, attrs):
         self._trace = trace
         self._name = name
         self._attrs = attrs
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._trace.add_span(self._name, self._t0, time.monotonic(),
-                             **self._attrs)
+        t1 = time.monotonic()
+        self._annotation.__exit__(*exc)
+        self._trace.add_span(self._name, self._t0, t1, **self._attrs)
         return False
 
 
@@ -375,7 +390,7 @@ class MultiTrace:
         self._traces = [t for t in traces if t.enabled]
 
     def span(self, name, **attrs):
-        return _MultiSpanContext(self._traces, name, attrs)
+        return _SpanContext(self, name, attrs)
 
     def add_span(self, name, t0, t1, **attrs):
         for t in self._traces:
@@ -386,25 +401,6 @@ class MultiTrace:
             t.event(name, **attrs)
 
 
-class _MultiSpanContext:
-    __slots__ = ("_traces", "_name", "_attrs", "_t0")
-
-    def __init__(self, traces, name, attrs):
-        self._traces = traces
-        self._name = name
-        self._attrs = attrs
-
-    def __enter__(self):
-        self._t0 = time.monotonic()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.monotonic()
-        for t in self._traces:
-            t.add_span(self._name, self._t0, t1, **self._attrs)
-        return False
-
-
 class _NullTracer:
     __slots__ = ()
     enabled = False
@@ -412,6 +408,9 @@ class _NullTracer:
 
     def start_trace(self, request_id, context=None):
         return NULL_TRACE
+
+    def annotate(self, name):
+        return _NULL_CTX
 
     def slowest(self):
         return []
@@ -475,6 +474,12 @@ class Tracer:
             t.parent_span_id = context.parent_span_id or None
             t.parent_origin = context.origin
         return t
+
+    def annotate(self, name: str) -> TraceAnnotation:
+        """A profiler annotation for an interval that belongs to the
+        worker and to no request (`idle`, `hold`, `resolve`): seen by a
+        profiler capture, kept in no trace's record."""
+        return TraceAnnotation(name)
 
     def _on_finish(self, record: dict):
         # serialize OUTSIDE the lock: finish() runs on the serving

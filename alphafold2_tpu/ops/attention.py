@@ -223,6 +223,10 @@ def _fused_attention_vjp(heads, bias_repeat, block_q, interpret):
     return f
 
 
+# a Pallas call is no flax module: the scope is the one name its
+# instructions carry of their own (the enclosing module decides the kernel,
+# obs/device.py)
+@jax.named_scope("fused_attention")
 def fused_attention(
     q: jnp.ndarray,              # (B, Nq, D)
     k: jnp.ndarray,              # (B, Nk, D)

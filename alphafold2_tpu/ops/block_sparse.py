@@ -136,6 +136,10 @@ def _kernel(cols_ref, valid_ref, *refs, t_total, scale, has_bias,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
+# a Pallas call is no flax module: the scope is the one name its
+# instructions carry of their own (the enclosing module decides the kernel,
+# obs/device.py)
+@jax.named_scope("block_sparse_attention")
 def block_sparse_attention(
     q: jnp.ndarray,                # (B, N, D)
     k: jnp.ndarray,                # (B, N, D)

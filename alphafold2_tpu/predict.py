@@ -87,6 +87,9 @@ def fold(
     if num_recycles > 0:
         # carry the latest outputs instead of stacking per-iteration ys:
         # keeps one copy of the O(n^2) distogram live, not num_recycles
+        # the scan body is no flax module: `recycle` is the name its
+        # instructions carry for the profiler's reader (obs/device.py)
+        @jax.named_scope("recycle")
         def body(carry, _):
             recyclables, *_ = carry
             coords, ret = one_pass(recyclables)

@@ -84,6 +84,20 @@ _DENSE = "dense"
 _BATCH_INPUTS = ("seq", "mask", "msa", "msa_mask")
 
 
+def _zero_batch(bucket_len: int, batch_size: int, msa_depth: int) -> dict:
+    """An assembled batch of a signature's shapes, all zeros: what a
+    signature is warmed (and profiled) on."""
+    batch = {"seq": jnp.zeros((batch_size, bucket_len), jnp.int32),
+             "mask": jnp.zeros((batch_size, bucket_len), bool),
+             "msa": None, "msa_mask": None}
+    if msa_depth:
+        batch["msa"] = jnp.zeros((batch_size, msa_depth, bucket_len),
+                                 jnp.int32)
+        batch["msa_mask"] = jnp.zeros((batch_size, msa_depth, bucket_len),
+                                      bool)
+    return batch
+
+
 class FoldExecutor:
     """LRU cache of compiled fold executables, keyed by shape signature.
 
@@ -339,7 +353,7 @@ class FoldExecutor:
                 fn = self._compile(cache_key, key[3], args,
                                    kernel=kernel)
         with trace.span("fold", bucket_len=key[0], **ktag):
-            return self._invoke(fn, args, batch)
+            return self._invoke(fn, args, batch, trace=trace)
 
     def _run_on_slice(self, batch: dict, num_recycles: int, trace,
                       devices, mesh_shape, kernel=None) -> FoldResult:
@@ -368,7 +382,7 @@ class FoldExecutor:
             ctx = use_mesh(mesh) if mesh is not None \
                 else contextlib.nullcontext()
             with ctx:
-                return self._invoke(fn, args, batch)
+                return self._invoke(fn, args, batch, trace=trace)
 
     # -- step-mode execution (scheduler-owned recycle loop) --------------
 
@@ -489,10 +503,15 @@ class FoldExecutor:
                 else contextlib.nullcontext()
             with ctx:
                 return self._invoke(fn, args, batch, variant=variant,
-                                    recycle=attrs.get("recycle"))
+                                    recycle=attrs.get("recycle"),
+                                    trace=trace)
 
     def _invoke(self, fn, args, batch, variant: str = "fold",
-                recycle=None) -> FoldResult:
+                recycle=None, trace=NULL_TRACE) -> FoldResult:
+        """One execution, inside the caller's `fold` (or `recycle` /
+        `admit`) span, which it splits in two for `trace`: `dispatch`,
+        the host's share (inputs to the device, the call enqueued), and
+        `device_wait`, blocked until the results have landed."""
         if self.faults is not None:
             # injected exceptions/latency fire BEFORE the device
             # call (a chaos fault must not waste real accelerator
@@ -501,8 +520,10 @@ class FoldExecutor:
             # index let a chaos plan hit a SPECIFIC recycle depth
             self.faults.on_executor_run(batch, variant=variant,
                                         recycle=recycle)
-        result = fn(*args)
-        result = jax.block_until_ready(result)
+        with trace.span("dispatch"):
+            result = fn(*args)
+        with trace.span("device_wait"):
+            result = jax.block_until_ready(result)
         if self.faults is not None:
             result = self.faults.mutate_result(batch, result)
         return result
@@ -535,16 +556,7 @@ class FoldExecutor:
             bucket_len, batch_size, msa_depth, num_recycles = \
                 self._normalize_key(key)[:4]
             before = self.misses
-            batch = {
-                "seq": jnp.zeros((batch_size, bucket_len), jnp.int32),
-                "mask": jnp.zeros((batch_size, bucket_len), bool),
-                "msa": None, "msa_mask": None,
-            }
-            if msa_depth:
-                batch["msa"] = jnp.zeros(
-                    (batch_size, msa_depth, bucket_len), jnp.int32)
-                batch["msa_mask"] = jnp.zeros(
-                    (batch_size, msa_depth, bucket_len), bool)
+            batch = _zero_batch(bucket_len, batch_size, msa_depth)
 
             # a spec only covers its own bucket length: warming a key
             # of another bucket under it would label a dense program
@@ -578,6 +590,30 @@ class FoldExecutor:
                 _one()
             fresh += self.misses - before
         return fresh
+
+    def profile(self, key, repeats: int = 3) -> dict:
+        """Where the executable of `key` spends the chip: device seconds
+        per execution by kernel (`obs.device.profile`; its result). `key`
+        as `warmup` takes it, legacy 4-tuple (len, batch, msa_depth,
+        recycles) or full ExecKey; the opaque fold on the default device,
+        on a zero batch as `warmup` uses. Compiles the executable if it
+        is not resident. Needs a device plane: raises on the CPU."""
+        from alphafold2_tpu.obs import device
+
+        key = self._normalize_key(key)
+        bucket_len, batch_size, msa_depth, num_recycles = key[:4]
+        batch = _zero_batch(bucket_len, batch_size, msa_depth)
+        args = (self.params, batch["seq"], batch["mask"], batch["msa"],
+                batch["msa_mask"])
+        cache_key = self.key_for(batch, num_recycles) + ((),)
+        fn = self._lookup(cache_key) \
+            or self._compile(cache_key, num_recycles, args)
+        if not hasattr(fn, "as_text"):
+            raise RuntimeError(
+                "FoldExecutor.profile: no compiled executable for "
+                f"{key[:4]} (ahead-of-time lowering was refused)")
+        return device.profile(fn, lambda: self._invoke(fn, args, batch),
+                              repeats=repeats)
 
     def stats(self) -> dict:
         with self._lock:

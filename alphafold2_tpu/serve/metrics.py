@@ -62,6 +62,22 @@ class ServeMetrics:
         # exists to drive down (ISSUE 10: the accelerator must never
         # idle waiting on features); serve_loadtest reports it
         self.exec_busy_s = 0.0
+        # where the worker thread's wall time went, always on (one clock
+        # read per wait or per batch). `worker_idle_s`: parked with
+        # nothing pending, counted from the first request ever enqueued
+        # (the time between start() and the first arrival is set-up, not
+        # idleness in service); `worker_hold_s`: entries pending, no
+        # batch ready yet; `worker_busy_s`: from taking a batch to its
+        # last resolution. The three tile the worker's time in service.
+        # `fetch_s` (device to host) and `resolve_s` (validation, copies,
+        # callbacks) are the host tail every row of a batch waits through
+        # after the device has finished; `exec_busy_s` ends before the
+        # resolve loop and leaves `resolve_s` out
+        self.worker_idle_s = 0.0
+        self.worker_hold_s = 0.0
+        self.worker_busy_s = 0.0
+        self.fetch_s = 0.0
+        self.resolve_s = 0.0
         # result-cache outcomes at submit (all zero when caching is off)
         self.cache_hits = 0         # served straight from the store
         self.cache_misses = 0       # key looked up, not found
@@ -181,6 +197,18 @@ class ServeMetrics:
         errors/shed as usual)."""
         with self._lock:
             self.retried += n
+
+    def record_worker(self, idle_s: float = 0.0, hold_s: float = 0.0,
+                      busy_s: float = 0.0, fetch_s: float = 0.0,
+                      resolve_s: float = 0.0):
+        """Seconds of the worker thread's time, by what it was doing
+        (the `worker_*_s`, `fetch_s` and `resolve_s` counters)."""
+        with self._lock:
+            self.worker_idle_s += idle_s
+            self.worker_hold_s += hold_s
+            self.worker_busy_s += busy_s
+            self.fetch_s += fetch_s
+            self.resolve_s += resolve_s
 
     def record_admit(self, pad_fraction: float):
         """One row admitted mid-loop (continuous batching): observe
@@ -325,6 +353,11 @@ class ServeMetrics:
                 "batches": self.batches,
                 "queue_depth": self.queue_depth,
                 "exec_busy_s": self.exec_busy_s,
+                "worker_idle_s": self.worker_idle_s,
+                "worker_hold_s": self.worker_hold_s,
+                "worker_busy_s": self.worker_busy_s,
+                "fetch_s": self.fetch_s,
+                "resolve_s": self.resolve_s,
                 "padding_waste": waste,
                 # occupancy-weighted over executed recycle steps
                 # (0.0 when the step loop never ran — ISSUE 13)

@@ -2523,9 +2523,9 @@ class Scheduler:
                     if any(self._pending.values()) \
                             or (self._bulk_queue is not None
                                 and len(self._bulk_queue)):
-                        self._cond.wait(timeout=poll_s)
+                        self._worker_wait("hold", poll_s)
                     else:
-                        self._cond.wait()
+                        self._worker_wait("idle")
                 while self._incoming:
                     entry = self._incoming.popleft()
                     self._pending.setdefault(entry.bucket_len,
@@ -2585,16 +2585,32 @@ class Scheduler:
                         if self._allocator is not None:
                             # every eligible slice is busy: wait for a
                             # completion to free one, don't hot-spin
-                            self._cond.wait(timeout=poll_s)
+                            self._worker_wait("hold", poll_s)
                         continue
                     if self._inflight_execs > 0:
                         # mesh batches still running on the dispatch
                         # pool: a drained stop means every ticket
                         # resolved, so wait them out (they may also
                         # requeue retries — re-check from the top)
-                        self._cond.wait(timeout=poll_s)
+                        self._worker_wait("hold", poll_s)
                         continue
                 break
+
+    def _worker_wait(self, name: str, timeout: Optional[float] = None):
+        """One wait of the worker on its condition (caller holds it):
+        `hold` while entries pend and no batch is ready yet, `idle` when
+        parked with nothing pending. Counted always
+        (`worker_hold_s`/`worker_idle_s`, one clock read a wait) and,
+        with the tracer on, entered as a profiler annotation of that
+        name. Idleness counts from the first request ever enqueued: a
+        wait that began before it is set-up, not idleness in service."""
+        in_service = name != "idle" or self.metrics.enqueued > 0
+        t0 = time.monotonic()
+        with self.tracer.annotate(name):
+            self._cond.wait(timeout=timeout)
+        if in_service:
+            self.metrics.record_worker(
+                **{name + "_s": time.monotonic() - t0})
 
     def _resolve_removed(self, entries: List[_Entry]):
         """Entries left the queue: update depth, wake blocked submitters."""
@@ -2783,7 +2799,7 @@ class Scheduler:
         DISJOINT slices execute concurrently and short traffic never
         queues behind a flagship fold."""
         if self._allocator is None:
-            self._execute(bucket_len, entries)
+            self._execute_timed(bucket_len, entries)
             return
         lease = self._allocator.acquire(
             self.mesh_policy.shape_for(bucket_len))
@@ -2792,7 +2808,7 @@ class Scheduler:
             # only acquirer, so this is unreachable in practice — but a
             # policy/allocator bug must degrade to a serial fold on the
             # default device, never lose the batch
-            self._execute(bucket_len, entries)
+            self._execute_timed(bucket_len, entries)
             return
         # EVERYTHING between acquire and the pool handoff is guarded
         # (ISSUE 14 audit): an exception from the gauge or the inflight
@@ -2815,12 +2831,24 @@ class Scheduler:
                 with self._cond:
                     self._inflight_execs -= 1
                     self._cond.notify_all()
-            self._execute(bucket_len, entries)
+            self._execute_timed(bucket_len, entries)
+
+    def _execute_timed(self, bucket_len: int, entries: List[_Entry],
+                       lease: Optional[SliceLease] = None):
+        """`_execute`, booked as `worker_busy_s`: from taking the batch
+        to its last resolution, on every path out (served, retried,
+        errored). Leased batches run side by side on the dispatch pool,
+        so there the counter is batch time, not the worker's own."""
+        t0 = time.monotonic()
+        try:
+            self._execute(bucket_len, entries, lease=lease)
+        finally:
+            self.metrics.record_worker(busy_s=time.monotonic() - t0)
 
     def _execute_on_lease(self, bucket_len: int, entries: List[_Entry],
                           lease: SliceLease):
         try:
-            self._execute(bucket_len, entries, lease=lease)
+            self._execute_timed(bucket_len, entries, lease=lease)
         finally:
             self._release_lease(lease)
             with self._cond:
@@ -2866,13 +2894,17 @@ class Scheduler:
             kspec = self._kernel_spec_for(bucket_len)
             result = self._run_executor(batch, batch_trace, lease,
                                         kernel=kspec)
-            coords = np.asarray(result.coords)
-            confidence = np.asarray(result.confidence)
-            distogram = None
-            if cfg.confidence_summary:
-                dg = getattr(result, "distogram", None)
-                if dg is not None:
-                    distogram = np.asarray(dg)
+            t_ran = time.monotonic()
+            with self.tracer.annotate("fetch"):
+                coords = np.asarray(result.coords)
+                confidence = np.asarray(result.confidence)
+                distogram = None
+                if cfg.confidence_summary:
+                    dg = getattr(result, "distogram", None)
+                    if dg is not None:
+                        distogram = np.asarray(dg)
+            t_fetched = time.monotonic()
+            batch_trace.add_span("fetch", t_ran, t_fetched)
         except Exception as exc:  # resolve/retry, never kill the worker
             if self._handle_batch_failure(bucket_len, entries, exc, t0):
                 return            # retried, bisected, or quarantined
@@ -2883,6 +2915,47 @@ class Scheduler:
                     bucket_len=bucket_len, error=repr(exc),
                     attempts=e.attempts))
             return
+        # the host tail: everything between the device's last byte on the
+        # host and the last ticket resolved is `resolve`, an annotation
+        # and a counter of the worker's and not a span of a request's
+        # (each request's trace finishes inside the loop)
+        with self.tracer.annotate("resolve"):
+            resolved = self._resolve_rows(bucket_len, entries, coords,
+                                          confidence, distogram)
+        self.metrics.record_worker(fetch_s=t_fetched - t_ran,
+                                   resolve_s=time.monotonic() - t_fetched)
+        if resolved is None:
+            return
+        now, real_tokens = resolved
+        if lease is not None:
+            self._c_mesh_folds.inc(mesh=lease.label)
+        self._record_kernel_batch(bucket_len, kspec, len(entries))
+        with self._cond:
+            if lease is not None:
+                self._mesh_batches[lease.label] = \
+                    self._mesh_batches.get(lease.label, 0) + 1
+                self._mesh_served[lease.label] = \
+                    self._mesh_served.get(lease.label, 0) + len(entries)
+            depth = self._depth
+        try:
+            self.metrics.record_batch(
+                bucket_len, cfg.max_batch_size, len(entries), real_tokens,
+                waste, now - t0, depth,
+                cache_store=(None if self.cache is None
+                             else self.cache.snapshot()))
+        except Exception:
+            # last-resort worker protection (sink I/O failures are
+            # already absorbed inside ServeMetrics.record_batch; this
+            # additionally survives a misbehaving metrics subclass —
+            # observability must never take down serving)
+            pass
+
+    def _resolve_rows(self, bucket_len: int, entries: List[_Entry],
+                      coords, confidence, distogram):
+        """Validate a fetched batch and resolve every row's ticket.
+        Returns (the clock read the latencies were taken at, the batch's
+        real tokens), or None where the resolution machinery itself
+        failed and the rows were error-resolved."""
         # output validation (retry-enabled only): non-finite coords/
         # confidence never leave as "ok" — they count toward poison
         # detection for this entry's key
@@ -2945,29 +3018,8 @@ class Scheduler:
                             status="error", bucket_len=bucket_len,
                             error=f"post-fold resolution failed: "
                                   f"{exc!r}"))
-            return
-        if lease is not None:
-            self._c_mesh_folds.inc(mesh=lease.label)
-        self._record_kernel_batch(bucket_len, kspec, len(entries))
-        with self._cond:
-            if lease is not None:
-                self._mesh_batches[lease.label] = \
-                    self._mesh_batches.get(lease.label, 0) + 1
-                self._mesh_served[lease.label] = \
-                    self._mesh_served.get(lease.label, 0) + len(entries)
-            depth = self._depth
-        try:
-            self.metrics.record_batch(
-                bucket_len, cfg.max_batch_size, len(entries), real_tokens,
-                waste, now - t0, depth,
-                cache_store=(None if self.cache is None
-                             else self.cache.snapshot()))
-        except Exception:
-            # last-resort worker protection (sink I/O failures are
-            # already absorbed inside ServeMetrics.record_batch; this
-            # additionally survives a misbehaving metrics subclass —
-            # observability must never take down serving)
-            pass
+            return None
+        return now, real_tokens
 
     # -- step-mode recycle loop (ISSUE 9) --------------------------------
 

@@ -107,12 +107,17 @@ def make_train_step(model):
     def train_step(state: TrainState, batch):
         rng, new_rng = jax.random.split(state.rng)
 
+        # `loss` and `optimizer`: no flax module names what happens
+        # outside the model, and the profiler's reader (obs/device.py)
+        # goes by names
+        @jax.named_scope("loss")
         def loss_fn(params):
             return compute_loss(model, params, batch, rng, train=True)
 
         grads, metrics = jax.grad(loss_fn, has_aux=True)(state.params)
-        new_state = state.apply_gradients(grads=grads).replace(rng=new_rng)
-        return new_state, metrics
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads=grads)
+        return new_state.replace(rng=new_rng), metrics
 
     return train_step
 

@@ -1,4 +1,4 @@
-"""Tracing / profiling utilities.
+"""Timing utilities.
 
 Net-new vs the reference, which has no profiler hooks at all (SURVEY.md
 §5.1 — ad-hoc time.time() in a notebook is all it offers). Step time IS
@@ -8,10 +8,10 @@ the benchmark metric (BASELINE.json), so the timer is first-class:
   through (StepTimer, serve.ServeMetrics, bench) — one stats path, no
   two subtly-different p99 definitions;
 - `StepTimer`: wall-clock accumulator with mean/p50/p90/p99/min stats,
-  used by `train.fit(step_timer=...)`, bench.py, and serve warmup;
-- `trace`: context manager around `jax.profiler` emitting a TensorBoard-
-  loadable trace directory;
-- `annotate`: named-scope annotation that shows up in profiler timelines.
+  used by `train.fit(step_timer=...)`, bench.py, and serve warmup.
+
+The door to the profiler is `alphafold2_tpu.obs.device` (device time by
+kernel, and a capture's idle gaps booked to the worker's intervals).
 """
 
 from __future__ import annotations
@@ -19,8 +19,6 @@ from __future__ import annotations
 import contextlib
 import time
 from typing import List, Optional, Sequence
-
-import jax
 
 
 def percentile(values: Sequence[float], q: float) -> float:
@@ -94,16 +92,3 @@ class StepTimer:
         return {"count": self.count, "mean_s": self.mean,
                 "p50_s": self.p50, "p90_s": self.p90, "p99_s": self.p99,
                 "best_s": self.best}
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """jax.profiler trace scope; view with TensorBoard or xprof."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-annotate = jax.profiler.TraceAnnotation

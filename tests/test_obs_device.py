@@ -1,0 +1,296 @@
+"""`obs.device`: the kernel vocabulary against the programs' own compiled
+text, the reducer against a small capture recorded on a TPU v5e through
+`serve.Scheduler` with the tracer on (`tools/record_worker_capture.py`), and
+the worker's intervals: off means off, and on they tile the worker's time.
+"""
+
+import gzip
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import tiny_programs
+from alphafold2_tpu import obs
+from alphafold2_tpu.obs import device
+from alphafold2_tpu.obs import trace as obs_trace
+from alphafold2_tpu.obs.trace import NULL_TRACE
+from alphafold2_tpu.serve import (BucketPolicy, FoldRequest, Scheduler,
+                                  SchedulerConfig, ServeMetrics)
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+NAMED = set(device.KERNEL_NAMES) - {"other"}
+
+
+# -- the vocabulary ----------------------------------------------------------
+
+@pytest.mark.quick
+@pytest.mark.parametrize("op_name,kernel", [
+    ("jit(run)/while/body/closed_call/recycle/Alphafold2/net/while/body/"
+     "closed_call/layers/checkpoint/block/attn/triangle_multiply_outgoing/"
+     "to_out/dot_general", "triangle_multiply"),
+    ("jit(train_step)/transpose(jvp(loss))/Alphafold2/net/while/body/"
+     "closed_call/layers/layers/checkpoint/rematted_computation/block/attn/"
+     "triangle_attention_ingoing/attn/bhid,bhjd->bhij/dot_general",
+     "triangle_attention"),
+    ("jit(run)/Alphafold2/net/layers_1/msa_attn/row_attn/attn/"
+     "attn.project_qkv/to_q/dot_general", "msa_row_attention"),
+    ("jit(run)/Alphafold2/net/layers_0/msa_attn/col_attn/attn/to_out/add",
+     "msa_col_attention"),
+    ("jit(run)/Alphafold2/net/layers_0/attn/outer_mean/proj_out/dot_general",
+     "outer_product_mean"),
+    ("jit(run)/Alphafold2/net/layers_0/ff/Dense_0/dot_general", "transition"),
+    ("jit(run)/jvp(loss)/Alphafold2/net/layers_0/msa_ff/Dense_1/dot_general",
+     "transition"),
+    # the structure module's own transitions and attention are its own
+    ("jit(run)/Alphafold2/structure_module/ipa_block/ff_1/dot_general",
+     "structure"),
+    ("jit(run)/Alphafold2/structure_module/ipa_block/attn/to_out/dot_general",
+     "structure"),
+    ("jit(run)/Alphafold2/to_distogram_logits/dot_general", "other"),
+    ("jit(train_step)/optimizer/mul", "other"),
+    # XLA joins merged instructions' names: the first is the instruction's
+    ("jit(f)/Alphafold2/net/layers_0/ff/Dense_0/reshape;jit(f)/Alphafold2/"
+     "structure_module/transpose", "transition"),
+    ("", "other"),
+    (None, "other"),
+])
+def test_kernel_of(op_name, kernel):
+    assert device.kernel_of(op_name) == kernel
+
+
+def test_the_table_names_only_the_kernels():
+    assert {kernel for _, kernel in device.KERNELS} == NAMED
+    assert len(device.KERNEL_NAMES) == 8
+
+
+HLO = """HloModule jit_f, is_scheduled=true
+
+%fused_computation.1 (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  %mul.1 = f32[8]{0} multiply(%p0, %p0), metadata={op_name="jit(f)/Alphafold2/net/layers_0/ff/Dense_0/mul"}
+  ROOT %bitcast.1 = f32[8]{0} bitcast(%mul.1)
+}
+
+%fused_computation.2 (p0.1: f32[8]) -> f32[8] {
+  %p0.1 = f32[8]{0} parameter(0)
+  ROOT %add.2 = f32[8]{0} add(%p0.1, %p0.1), metadata={op_name="jit(f)/Alphafold2/net/layers_0/attn/outer_mean/add"}
+}
+
+ENTRY %main.3 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %fusion.1 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(f)/Alphafold2/net/layers_0/msa_ff/add"}
+  %copy.4 = f32[8]{0} copy(%fusion.2)
+  ROOT %fusion.3 = f32[8]{0} fusion(%copy.4), kind=kLoop, calls=%fused_computation.2
+}
+"""
+
+
+def test_instruction_table_joins_fusions_to_their_roots():
+    table = device.instruction_op_names(HLO)
+    # its own name first; else its root's; else the nearest before the root
+    assert table["fusion.2"].endswith("msa_ff/add")
+    assert table["fusion.3"].endswith("outer_mean/add")
+    assert table["fusion.1"].endswith("ff/Dense_0/mul")
+    # no name anywhere: left out, and what is fused is no operation of its own
+    assert set(table) == {"fusion.1", "fusion.2", "fusion.3"}
+
+
+@pytest.mark.parametrize("program", tiny_programs.PROGRAMS)
+def test_every_trunk_contraction_resolves_to_a_named_kernel(program):
+    """Every contraction of the optimized HLO whose path passes through the
+    trunk or the structure module resolves to one of the seven named
+    kernels, forward and backward, scan, remat and unrolled. How many
+    contractions carry no `op_name` at all is the compiler's doing, and is
+    held under 1% where it counts: in the programs compiled for the chip
+    (tests/test_chip_compile.py). The CPU compiler rewrites batched dots
+    without carrying their metadata over (45 of the 192 here)."""
+    text = tiny_programs.compile_tiny(program).as_text()
+    resolved, through = {}, 0
+    for op_name in filter(None, tiny_programs.contraction_op_names(text)):
+        parts = op_name.split("/")
+        if "net" in parts or "structure_module" in parts:
+            through += 1
+            kernel = device.kernel_of(op_name)
+            assert kernel in NAMED, op_name
+            resolved.setdefault(kernel, set()).add(
+                "transpose(jvp(" in op_name)
+    assert through > 100
+    assert set(resolved) == NAMED
+    if program == "train_step":     # every kernel is met going both ways
+        assert all(ways == {False, True} for ways in resolved.values())
+        assert 'op_name="jit(train_step)/optimizer/' in text
+    else:
+        assert "/recycle/Alphafold2/" in text
+
+
+# -- the reducer, on a capture recorded on the chip --------------------------
+
+@pytest.fixture(scope="module")
+def profile_data():
+    with gzip.open(os.path.join(DATA, "worker_capture.xplane.pb.gz")) as f:
+        return jax.profiler.ProfileData.from_serialized_xspace(f.read())
+
+
+@pytest.fixture(scope="module")
+def capture(profile_data):
+    with open(os.path.join(DATA, "worker_capture.json")) as f:
+        planted = json.load(f)
+    return planted, device.reduce(profile_data, planted["op_names"])
+
+
+def test_capture_kernels_sum_to_busy_time(capture):
+    _, reduced = capture
+    by_kernel = sum(k["seconds"] for k in reduced["kernels"].values())
+    assert by_kernel == pytest.approx(reduced["busy_s"], rel=5e-3)
+    assert 0 < reduced["busy_s"] < reduced["window_s"]
+    assert reduced["unnamed_s"] <= reduced["kernels"]["other"]["seconds"]
+    # the tiny model runs every kernel, and the table names them
+    assert all(reduced["kernels"][k]["seconds"] > 0 for k in NAMED)
+    xla_name, kernel, tail, seconds = reduced["top"][0]
+    assert xla_name.startswith("%") and kernel in device.KERNEL_NAMES
+    assert seconds > 0 and (tail or kernel == "other")
+
+
+def test_capture_holds_the_workers_annotations(capture):
+    planted, reduced = capture
+    for span in ("hold", "batch_form", "dispatch", "device_wait", "fetch",
+                 "resolve"):
+        assert reduced["annotations"].get(span, 0) >= planted["requests"], span
+
+
+def test_capture_books_the_planted_gap_to_hold(capture):
+    """Each request was sent alone into a batch of two held open for
+    `hold_ms`: between two folds the device idles that long, in `hold`."""
+    planted, reduced = capture
+    idle = reduced["idle"]
+    gaps = (planted["requests"] - 1) * planted["hold_ms"] / 1e3
+    assert idle["hold"] >= 0.8 * gaps
+    assert max(idle, key=idle.get) == "hold"
+    long_gaps = sum(v for k, v in idle.items() if k != "between_ops")
+    assert idle.get("unannotated", 0.0) < 0.05 * long_gaps
+    assert sum(idle.values()) == pytest.approx(
+        reduced["window_s"] - reduced["busy_s"], rel=1e-6)
+
+
+def test_reduce_without_a_table_books_everything_to_other(profile_data):
+    bare = device.reduce(profile_data)
+    assert bare["kernels"]["other"]["seconds"] == pytest.approx(
+        bare["busy_s"], rel=5e-3)
+    assert bare["unnamed_s"] == pytest.approx(
+        bare["kernels"]["other"]["seconds"])
+
+
+def test_profile_needs_a_device_plane():
+    """The CPU backend records no device plane: `profile` says so, and
+    leaves no capture behind."""
+    fn = jax.jit(lambda x: x @ x).lower(jnp.ones((8, 8))).compile()
+    with pytest.raises(RuntimeError, match="no device operation"):
+        device.profile(fn, lambda: jax.block_until_ready(
+            fn(jnp.ones((8, 8)))), repeats=1)
+
+
+# -- the worker's intervals --------------------------------------------------
+
+class _StubResult:
+    def __init__(self, b, n):
+        self.coords = np.zeros((b, n, 3), np.float32)
+        self.confidence = np.ones((b, n), np.float32)
+
+
+class _StubExecutor:
+    def __init__(self, delay_s=0.0):
+        self.delay_s = delay_s
+
+    def run(self, batch, num_recycles, trace=NULL_TRACE):
+        with trace.span("fold"):
+            time.sleep(self.delay_s)
+            b, n = batch["seq"].shape
+            return _StubResult(b, n)
+
+
+def _scheduler(executor, tracer=None, **config):
+    reg = obs.MetricsRegistry()
+    return Scheduler(executor, BucketPolicy((16,)),
+                     SchedulerConfig(num_recycles=0, **config),
+                     ServeMetrics(registry=reg), registry=reg, tracer=tracer)
+
+
+class _CountingAnnotation:
+    entered = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _CountingAnnotation.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.quick
+def test_off_means_off(monkeypatch):
+    """With NULL_TRACER the worker enters no profiler annotation and builds
+    no context per batch or per wait; with a Tracer every interval it times
+    is one."""
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", _CountingAnnotation)
+    monkeypatch.setattr(_CountingAnnotation, "entered", [])
+    assert obs.NULL_TRACER.annotate("idle") is obs.NULL_TRACER.annotate(
+        "resolve") is NULL_TRACE.span("fetch")        # one shared no-op
+    rng = np.random.default_rng(0)
+    requests = lambda: [FoldRequest(seq=rng.integers(0, 20, 12))
+                        for _ in range(4)]
+    with _scheduler(_StubExecutor(), max_batch_size=2,
+                    max_wait_ms=10.0) as off:
+        for request in requests():
+            assert off.submit(request).result(timeout=30).ok
+    assert _CountingAnnotation.entered == []
+    snap = off.metrics.snapshot()
+    assert snap["worker_busy_s"] > 0 and snap["worker_hold_s"] > 0
+    assert snap["fetch_s"] > 0 and snap["resolve_s"] > 0
+
+    with _scheduler(_StubExecutor(), tracer=obs.Tracer(), max_batch_size=2,
+                    max_wait_ms=10.0) as on:
+        for request in requests():
+            assert on.submit(request).result(timeout=30).ok
+    assert {"idle", "hold", "batch_form", "fold", "fetch",
+            "resolve"} <= set(_CountingAnnotation.entered)
+    # worker intervals stay out of the requests' records
+    spans = {s["name"] for rec in on.tracer.slowest() for s in rec["spans"]}
+    assert {"queue", "batch_form", "fold", "fetch"} <= spans
+    assert not spans & {"idle", "hold", "resolve"}
+
+
+def test_worker_counters_tile_its_time_in_service():
+    """idle + hold + busy is the worker's wall clock from the first request
+    enqueued to its exit, within 2%; what came before the first request is
+    not idleness in service."""
+    scheduler = _scheduler(_StubExecutor(delay_s=0.05), max_batch_size=2,
+                           max_wait_ms=40.0, poll_ms=20.0)
+    rng = np.random.default_rng(1)
+    with scheduler:
+        time.sleep(0.3)                  # parked before the first arrival
+        t0 = time.monotonic()
+        tickets = []
+        for i in range(16):
+            tickets.append(scheduler.submit(
+                FoldRequest(seq=rng.integers(0, 20, 12))))
+            time.sleep(0.12 if i % 4 == 3 else 0.02)
+        assert all(t.result(timeout=30).ok for t in tickets)
+    # read once the worker has gone: a ticket resolves before its batch is
+    # booked, and a wait still under way is not counted yet
+    wall = time.monotonic() - t0
+    snap = scheduler.metrics.snapshot()
+    tiled = snap["worker_idle_s"] + snap["worker_hold_s"] \
+        + snap["worker_busy_s"]
+    assert tiled == pytest.approx(wall, rel=0.02)
+    assert snap["worker_idle_s"] > 0 and snap["worker_hold_s"] > 0
+    assert snap["fetch_s"] + snap["resolve_s"] < snap["worker_busy_s"]
+    assert snap["exec_busy_s"] <= snap["worker_busy_s"]

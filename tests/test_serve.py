@@ -236,12 +236,19 @@ class TestExecutor:
         ex.run(batch, 0, trace=cold)
         cold.finish("ok")
         names = [s["name"] for s in cold.record()["spans"]]
-        assert names == ["compile", "fold"]
+        # `fold` closes after the two halves it is split in
+        assert names == ["compile", "dispatch", "device_wait", "fold"]
         warm = tracer.start_trace("warm")
         ex.run(batch, 0, trace=warm)
         warm.finish("ok")
-        (span,) = warm.record()["spans"]
+        dispatch, device_wait, span = warm.record()["spans"]
         assert span["name"] == "fold" and span["dur_s"] > 0
+        assert (dispatch["name"], device_wait["name"]) == (
+            "dispatch", "device_wait")
+        assert span["start_s"] <= dispatch["start_s"] \
+            <= device_wait["start_s"]
+        assert dispatch["dur_s"] + device_wait["dur_s"] <= span["dur_s"] \
+            + 2e-6
 
 
 class TestScheduler:

@@ -76,6 +76,13 @@ from alphafold2_tpu.utils.profiling import percentile  # noqa: E402
 # hop by tools/obs_fleet.py) with ISSUE 15 — the rpc span now also
 # covers the WHOLE forwarded exchange (submit POST through terminal
 # pickup) and carries outcome/span_id attrs the fleet stitcher reads.
+# dispatch / device_wait (the two halves every executor run splits its
+# fold, recycle or admit span in: the host's share, then blocked on the
+# device) and fetch (device to host, after the run) with ISSUE 26: the
+# worker's intervals that a profiler capture books the device's idle
+# gaps to (obs/device.py). dispatch and device_wait lie INSIDE their
+# parent span, so the waterfall's stages no longer add up to a request's
+# latency: read fold OR its two halves.
 # --check's orphan-span rules apply to all of them unchanged, which is
 # how the chaos smokes prove recovery cost is fully accounted.
 #
@@ -86,6 +93,7 @@ from alphafold2_tpu.utils.profiling import percentile  # noqa: E402
 STAGE_ORDER = ("reconcile", "featurize", "submit", "forward", "rpc",
                "queue", "parked", "retry", "drain", "batch_form",
                "shard", "compile", "fold", "recycle", "admit",
+               "dispatch", "device_wait", "fetch",
                "watchdog", "resume", "writeback", "peer_fetch",
                "peer_serve", "cache_lookup", "write", "preempt",
                "adopt")
