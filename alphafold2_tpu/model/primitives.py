@@ -18,21 +18,27 @@ Behavioral parity with the reference blocks
 
 TPU notes: weights live in fp32; activations run in `dtype` (bf16 by default
 under the train policy) so matmuls hit the MXU at full rate. Folding an axis
-into batch is a free reshape under XLA. Attention here is plain einsum +
-softmax — XLA fuses bias/mask/softmax; a Pallas fused variant can be swapped
-in via `alphafold2_tpu.ops` once it beats the XLA baseline.
+into batch is a free reshape under XLA. Attention written as einsum + softmax
++ einsum keeps its (rows x heads, n, n) logits in HBM and passes over them
+four times: two thirds of the 640 fold's device time (PERF.md section 5,
+PR 26). On a TPU every forward-only self-attention whose shape the fused
+kernel admits takes `ops.attention.fused_attention_merged` instead, on the
+projections as the Dense layers lay them out (no head is split off: the
+relayout copies cost more than the kernel); differentiated traces keep the
+einsum path (PERF.md section 6, PR 27).
 """
 
 from __future__ import annotations
 
 import contextlib
-import warnings
 
 from typing import Optional
 
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import nn as jnn
+
+from alphafold2_tpu import runtime
 
 
 def Dense(features, **kw):
@@ -118,6 +124,11 @@ def attention_output_tail(dense, out, x, inner, gating, dim):
                  bias_init=zeros_init())(out)
 
 
+def _split_heads(t, heads):
+    """(..., n, heads * dh) -> (..., n, dh) with the heads at axis 1."""
+    return jnp.moveaxis(t.reshape(*t.shape[:-1], heads, -1), -2, 1)
+
+
 class Attention(nn.Module):
     """Gated multi-head attention (reference alphafold2.py:98-190).
 
@@ -148,23 +159,25 @@ class Attention(nn.Module):
                              bias_init=zeros_init())
         self._drop = nn.Dropout(self.dropout)
 
+    def project_merged(self, x, kv_input=None):
+        """The projections as the Dense layers produce them, heads side by
+        side in the last axis: x (..., n, d) -> q (..., n, h * dh),
+        pre-scaled, and [k | v] (..., m, 2 * h * dh). The fused kernel's
+        layout."""
+        kv_input = x if kv_input is None else kv_input
+        return self._to_q(x) * (self.dim_head ** -0.5), self._to_kv(kv_input)
+
     def project_qkv(self, x, kv_input=None):
         """QKV projections with heads split out and q pre-scaled.
 
         x: (..., n, d) -> q/k/v (..., h, n, dh). Rank-agnostic: the ring
         path passes the unfolded (b, I, J, d) pair tensor.
         """
-        h, dh = self.heads, self.dim_head
-        kv_input = x if kv_input is None else kv_input
-        q = self._to_q(x)
-        k, v = jnp.split(self._to_kv(kv_input), 2, axis=-1)
+        return self._split_qkv(*self.project_merged(x, kv_input))
 
-        def split_heads(t):
-            t = t.reshape(*t.shape[:-1], h, dh)
-            return jnp.moveaxis(t, -2, 1)  # heads to axis 1
-
-        q, k, v = map(split_heads, (q, k, v))
-        return q * (dh ** -0.5), k, v
+    def _split_qkv(self, q, kv):
+        return tuple(_split_heads(t, self.heads)
+                     for t in (q, *jnp.split(kv, 2, axis=-1)))
 
     def finish(self, out, x):
         """Shared output tail: merge heads, sigmoid gate from the *input*
@@ -197,7 +210,8 @@ class Attention(nn.Module):
         h, dh = self.heads, self.dim_head
         has_context = context is not None
 
-        q, k, v = self.project_qkv(x, kv_input=context)  # (b, h, n, dh)
+        q_merged, kv_merged = self.project_merged(x, kv_input=context)
+        q, k, v = self._split_qkv(q_merged, kv_merged)      # (b, h, n, dh)
 
         if mask is not None:
             if has_context:
@@ -265,56 +279,38 @@ class Attention(nn.Module):
             attn_bias = fill if attn_bias is None else \
                 attn_bias + fill.astype(attn_bias.dtype)
 
-        # optional Pallas fused path (bias+mask+softmax+AV in one
-        # VMEM-resident kernel; alphafold2_tpu/ops/attention.py). Bias
+        # the fused kernel (ops/attention.py: bias + mask + softmax + values
+        # with the logits in VMEM only) takes every attention the trace can
+        # see it applies to: a TPU backend, one device (GSPMD cannot
+        # partition the custom call over a mesh), self-attention, no tied
+        # rows, no active dropout (the kernel has none), a shape it admits.
+        # q and [k | v] go in and the output comes back as the Dense layers
+        # lay them out: no head is split off, moved or lane-padded. Bias
         # stays *unrepeated* (replayed over the folded axial axis by the
-        # kernel's index map) and masks stay (b, n) vectors — no O(N^2)
-        # HBM bias/mask tensor is ever built on this path. Tie-dim
-        # (global-query) and dropout-active traces fall back to the XLA
-        # path. Both backends share the gating/projection tail below.
-        from alphafold2_tpu.ops.attention import (
-            fused_attention, pallas_attention_enabled)
-        use_pallas = pallas_attention_enabled() and tie_dim is None
-        if use_pallas and self.dropout > 0.0 and not deterministic:
-            # refuse-don't-drop convention (evoformer.py menu): the fused
-            # kernel has no dropout; say so instead of silently slowing
-            warnings.warn(
-                "Pallas fused attention is enabled but attention dropout "
-                f"({self.dropout}) is active in a training trace; this "
-                "layer falls back to the XLA attention path. Set "
-                "attn_dropout=0.0 or run deterministic to keep the "
-                "kernel.", stacklevel=2)
-            use_pallas = False
-        if use_pallas:
+        # kernel's index map) and masks stay (b, n) vectors. Off the chip
+        # `use_pallas_attention` opens the same door for the CPU tests. A
+        # differentiated trace runs the XLA attention below through the
+        # kernel's custom_vjp. Both paths share the gating/projection tail.
+        from alphafold2_tpu.ops import attention as fused
+        from alphafold2_tpu.parallel.sharding import active_mesh
+        dropping = self.dropout > 0.0 and not deterministic
+        mesh = active_mesh()
+        if (tie_dim is None and not has_context and not dropping
+                and (mesh is None or mesh.size == 1)
+                and (runtime.on_tpu() or fused.pallas_attention_enabled())
+                and fused.admits(n_q, dh)):
             b_all = q.shape[0]
-            n_q, n_k = q.shape[-2], k.shape[-2]
             if attn_bias is not None:
                 # callers may pass broadcast-shaped bias, e.g. (1,1,n,n)
                 # from BlockSparseAttention; the kernel's index map needs
                 # the full (b, heads) leading shape
                 attn_bias = jnp.broadcast_to(
-                    attn_bias.astype(jnp.float32),
-                    (b_all // attn_bias_repeat, h, n_q, n_k))
-            out = fused_attention(
-                q.reshape(b_all * h, n_q, dh),
-                k.reshape(b_all * h, n_k, dh),
-                v.reshape(b_all * h, n_k, dh),
-                bias=None if attn_bias is None else
-                attn_bias.reshape(-1, n_q, n_k),
-                q_mask=mask,
-                k_mask=cmask,
-                heads=h,
-                bias_repeat=attn_bias_repeat)
-            return self.finish(out.reshape(b_all, h, n_q, dh), x)
-
-        pair_mask = None if mask is None else \
-            mask[:, None, :, None] & cmask[:, None, None, :]
-
-        if attn_bias is not None and attn_bias_repeat != 1:
-            # replay the (b, h, n, m) bias across the folded axial axis
-            # (reference alphafold2.py:246-248); only the XLA path needs
-            # the materialized repeat
-            attn_bias = jnp.repeat(attn_bias, attn_bias_repeat, axis=0)
+                    attn_bias, (b_all // attn_bias_repeat, h, n_q, n_k)
+                ).reshape(-1, n_q, n_k)
+            out = fused.fused_attention_merged(
+                q_merged, kv_merged, bias=attn_bias, q_mask=mask,
+                k_mask=cmask, heads=h, bias_repeat=attn_bias_repeat)
+            return self._gate_and_project(out, x)
 
         # the attention contractions route to the AMX host GEMM on the CPU
         # fallback path (ops/cpu_gemm.py; exact XLA einsums otherwise).
@@ -346,13 +342,8 @@ class Attention(nn.Module):
             dots = amx_attn_qk(q_n, k_n) if natural \
                 else amx_attention_dots(q, k)
 
-        if attn_bias is not None:
-            dots = dots + attn_bias.astype(dots.dtype)
-
-        if pair_mask is not None:
-            dots = jnp.where(pair_mask, dots, MASK_VALUE)
-
-        attn = jnn.softmax(dots, axis=-1)
+        attn = fused.attention_weights(dots, attn_bias, mask, cmask,
+                                       bias_repeat=attn_bias_repeat)
         attn = self._drop(attn, deterministic=deterministic)
 
         if natural:

@@ -66,6 +66,19 @@ KERNELS = (
 _SUBTREE = KERNELS[0][0]
 _BY_COMPONENT = dict(KERNELS)
 
+# The name scope `ops.attention.fused_attention` puts around its Pallas call:
+# a custom call whose `op_name` has this component ran the fused kernel
+FUSED_SCOPE = "fused_attention"
+
+
+def is_fused(opcode: str, op_name: Optional[str]) -> bool:
+    """Whether a device operation is the fused attention kernel itself (a
+    custom call under `FUSED_SCOPE`), not the XLA attention a differentiated
+    trace runs under the same scope."""
+    return opcode == "custom-call" and op_name is not None \
+        and FUSED_SCOPE in op_name.split(";", 1)[0].split("/")
+
+
 # The scheduler worker's intervals that tile its time (serve/scheduler.py):
 # what an idle gap of the device is booked to. `fold` is left out: it is the
 # parent of `dispatch` and `device_wait`, which say more.
@@ -253,8 +266,11 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     last. Returns
 
     - `window_s`, `busy_s` (the union of the operations' intervals), `events`;
-    - `kernels`: {kernel: {"seconds", "events"}} over `KERNEL_NAMES`,
-      containers left out: the kernels' seconds sum to `busy_s`;
+    - `kernels`: {kernel: {"seconds", "events", "fused_s"}} over
+      `KERNEL_NAMES`, containers left out: the kernels' seconds sum to
+      `busy_s`; `fused_s` is the part of `seconds` spent in the fused
+      attention kernel's custom calls (`is_fused`): the counter of a
+      mechanism that engages when the program is traced;
       `unnamed_s`: the part of `other` that had no `op_name`; `xla_flops` /
       `xla_bytes` per kernel where the profiler's events carry XLA's own
       counts (this installation's do not);
@@ -269,7 +285,8 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     """
     op_names = op_names or {}
     devices = _device_lines(profile_data)
-    kernels = {k: {"seconds": 0.0, "events": 0} for k in KERNEL_NAMES}
+    kernels = {k: {"seconds": 0.0, "events": 0, "fused_s": 0.0}
+               for k in KERNEL_NAMES}
     by_instr: Dict[str, float] = {}
     unnamed_ns = events = 0
     busy = []                    # each device's merged (start, end) intervals
@@ -290,6 +307,8 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
             entry = kernels[kernel_of(named)]
             entry["seconds"] += e.duration_ns / 1e9
             entry["events"] += 1
+            if is_fused(opcode, named):
+                entry["fused_s"] += e.duration_ns / 1e9
             events += 1
             if named is None:
                 unnamed_ns += e.duration_ns
@@ -308,6 +327,7 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     lo, hi = first[0][0], first[-1][1]
     for entry in kernels.values():
         entry["seconds"] /= n
+        entry["fused_s"] /= n
 
     wanted, annotations, seen = set(spans), [], {}
     for plane in profile_data.planes:
@@ -343,8 +363,8 @@ def profile(executable, call: Callable[[], object],
     `call`: a zero-argument function that executes it once and returns only
     when the device has finished. Runs `call` once unprofiled, then `repeats`
     times under `jax.profiler` (python tracer off; the capture goes to a
-    temporary directory that is removed), and returns `reduce`'s kernels,
-    `unnamed_s`, `busy_s` and `top` PER EXECUTION, with `repeats` and the
+    temporary directory that is removed), and returns `reduce`'s kernels
+    (`seconds` and `fused_s`), `unnamed_s`, `busy_s` and `top` PER EXECUTION, with `repeats` and the
     `window_s` of all of them. Raises where the capture holds no device
     operation (the CPU backend has no device plane).
     """
@@ -375,7 +395,7 @@ def profile(executable, call: Callable[[], object],
     for entry in reduced["kernels"].values():
         entry["seconds"] = per(entry["seconds"])
         entry["events"] //= repeats
-        for key in ("xla_flops", "xla_bytes"):
+        for key in ("fused_s", "xla_flops", "xla_bytes"):
             if key in entry:
                 entry[key] = per(entry[key])
     return {"repeats": repeats, "window_s": reduced["window_s"],

@@ -1,6 +1,7 @@
 from alphafold2_tpu.ops.attention import (  # noqa: F401
     attention_reference,
     fused_attention,
+    fused_attention_merged,
     pallas_attention,
     pallas_attention_enabled,
     use_pallas_attention,

@@ -122,11 +122,9 @@ def main() -> int:
         return 2
     enable_compile_cache()
 
+    # a training step is a differentiated trace: its attention is the XLA
+    # one (the fused kernel is forward-only, ops/attention.py)
     backend = "xla"
-    if os.environ.get("BENCH_PALLAS") == "1":
-        from alphafold2_tpu.ops import use_pallas_attention
-        use_pallas_attention(True)
-        backend = "pallas"
 
     from alphafold2_tpu import Alphafold2
     from alphafold2_tpu.data.synthetic import synthetic_batch

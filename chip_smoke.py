@@ -56,7 +56,6 @@ import numpy as np
 
 from alphafold2_tpu import Alphafold2, constants, predict, serve
 from alphafold2_tpu.data.synthetic import synthetic_batch
-from alphafold2_tpu.ops import pallas_attention
 from alphafold2_tpu.ops.attention import (MASK_VALUE, attention_reference,
                                           fused_attention)
 from alphafold2_tpu.ops.block_sparse import KernelSpec, block_sparse_attention
@@ -82,14 +81,14 @@ TRAIN_STEPS = 3
 #   different compiled programs (the direct jit and the executor's scan
 #   fold are the same one); the four-chip program computes the same
 #   function (1e-6 apart in f32 on virtual devices) and reorders every
-#   reduction. Each recycle amplifies bf16 rounding: the server phase
-#   prints the same measure between two one-chip programs that differ
-#   only in rounding (`bf16_noise_floor`) to size these against, and a
-#   2^-9 relative jitter of every weight moves the coords by 2.4% (CPU
-#   probe at full width, PR 22). On the v5e the step loop was 1.3e-2 from
-#   the scan fold, the four-chip fold 1.3e-2 from the one-chip fold, and
-#   the noise floor 1.3e-2 (my chip runs, PR 22). A wrong program is O(1)
-#   away;
+#   reduction. Each recycle amplifies bf16 rounding: a 2^-9 relative
+#   jitter of every weight moves the coords by 2.4% (CPU probe at full
+#   width, PR 22). On the v5e the step loop was 1.3e-2 from the scan fold,
+#   the four-chip fold 1.3e-2 from the one-chip fold, and the same fold
+#   with its attention in the fused kernel against XLA's (two programs
+#   that differ only in rounding) 1.3e-2 (my chip runs, PR 22; since PR 27
+#   every fold on a TPU takes the kernel by shape and no switch is left to
+#   make that pair). A wrong program is O(1) away;
 # - loss: relative difference of one train step's loss.
 KERNEL_TOL = 3e-2
 FOLD_TOL = 1e-1
@@ -332,17 +331,11 @@ def phase_server(model, params, *, bucket: int, lengths, msa_depth: int,
     require(all(r.recycles == num_recycles for r in step), step_obs)
 
     # the same padded inputs through a direct jit of predict.fold
-    def make_direct_fold():
-        # a fresh function object per call: jit's cache is keyed on the
-        # function, and the fused-attention switch is read at trace time
-        @jax.jit
-        def direct_fold(params, seq, msa, mask, msa_mask):
-            return predict.fold(model, params, seq, msa=msa, mask=mask,
-                                msa_mask=msa_mask,
-                                num_recycles=num_recycles)
-        return direct_fold
+    @jax.jit
+    def direct_fold(params, seq, msa, mask, msa_mask):
+        return predict.fold(model, params, seq, msa=msa, mask=mask,
+                            msa_mask=msa_mask, num_recycles=num_recycles)
 
-    direct_fold = make_direct_fold()
     policy = serve.BucketPolicy((bucket,))
     diffs = {"step_vs_scan": [], "scan_vs_direct": [], "confidence": []}
     for req, a, b in zip(requests, scan, step):
@@ -357,22 +350,10 @@ def phase_server(model, params, *, bucket: int, lengths, msa_depth: int,
             np.asarray(b.confidence, np.float32)
             - np.asarray(a.confidence, np.float32)))))
     timing = time_fold_two_ways(direct_fold, args)
-    # how far bf16 rounding alone moves a fold: the same program with the
-    # attention routed through the fused Pallas kernel (same mathematics)
-    with pallas_attention(True):
-        fused_fold = make_direct_fold()(*args)
-    n = requests[-1].length      # compare the real residues only
-    pairs = {"coords": (fused_fold.coords[0, :n], ref.coords[0, :n]),
-             "distogram": (fused_fold.distogram[0, :n, :n],
-                           ref.distogram[0, :n, :n])}
-    require(all(_finite(got) for got, _ in pairs.values()), "fused fold")
-    noise = {f"{k}_{m}": fn(got, want) for k, (got, want) in pairs.items()
-             for m, fn in (("l2", rel_l2), ("max", rel_err))}
     problems = [f"{k}: {max(v):.4g} > tol {tol}" for k, v in diffs.items()
                 if max(v) > tol]
     return {"scan": scan_obs, "step_loop": step_obs, "tol": tol,
             "problems": problems,
-            "bf16_noise_floor": {k: round(v, 6) for k, v in noise.items()},
             "diff": {k: [round(x, 6) for x in v] for k, v in diffs.items()},
             "coords_max_abs": float(np.max(np.abs(ref_coords))),
             "direct_fold_timing": timing}

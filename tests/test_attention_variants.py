@@ -137,8 +137,9 @@ class TestBlockSparse:
         from alphafold2_tpu.ops import attention as ops_attn
 
         monkeypatch.setattr(
-            ops_attn, "fused_attention",
-            functools.partial(ops_attn.fused_attention, interpret=True))
+            ops_attn, "fused_attention_merged",
+            functools.partial(ops_attn.fused_attention_merged,
+                              interpret=True))
         x, mask = x_mask(jax.random.PRNGKey(16), n=64)
         mod = BlockSparseAttention(dim=16, heads=2, dim_head=8, block=16)
         params = mod.init(jax.random.PRNGKey(17), x, mask=mask)

@@ -23,12 +23,15 @@ from jax.sharding import SingleDeviceSharding
 
 import tiny_programs
 from alphafold2_tpu import Alphafold2, predict
-from alphafold2_tpu.ops.attention import fused_attention
+from alphafold2_tpu.ops.attention import fused_attention_merged
 from alphafold2_tpu.ops.block_sparse import (banded_block_pattern,
                                              block_sparse_attention)
 
 HEADS, D, BLOCK, FOLD_AXIS = 8, 64, 128, 2
 LENGTHS = (256, 384, 1024)
+# every bucket of the benchmark's three cells (64 is half a lane tile, 384
+# and 640 are no powers of two), and the long-fold bucket
+FUSED_LENGTHS = (64, 128, 256, 384, 512, 640, 1024)
 
 
 @pytest.fixture(scope="module")
@@ -64,13 +67,26 @@ def no_persistent_cache():
 
 def _attention_shapes(n, sharding):
     """q/k/v bf16 (B, n, D) with heads folded innermost and a folded axis
-    of 2, the unrepeated f32 pair bias, and the (B // heads, n) key mask —
-    the layout model/primitives.py hands both kernels."""
+    of 2, the unrepeated f32 pair bias, and the (B // heads, n) key mask:
+    the layout model/primitives.py hands the block-sparse kernel."""
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
     b = FOLD_AXIS * HEADS
     qkv = sds((b, n, D), jnp.bfloat16)
     return (qkv, qkv, qkv, sds((HEADS, n, n), jnp.float32),
             sds((FOLD_AXIS, n), jnp.bool_))
+
+
+def _merged_shapes(n, sharding, batch=1, fold_axis=FOLD_AXIS):
+    """What `Attention.__call__` hands the fused kernel: q (rows, n,
+    heads * D) and [k | v] (rows, n, 2 * heads * D) in bf16 as the Dense
+    projections lay them out, the unrepeated pair bias in bf16 as
+    `edges_to_attn_bias` produces it, and the (rows, n) mask."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    rows = batch * fold_axis
+    return (sds((rows, n, HEADS * D), jnp.bfloat16),
+            sds((rows, n, 2 * HEADS * D), jnp.bfloat16),
+            sds((batch * HEADS, n, n), jnp.bfloat16),
+            sds((rows, n), jnp.bool_))
 
 
 def _compiled_kernel_text(fn, shapes):
@@ -79,13 +95,19 @@ def _compiled_kernel_text(fn, shapes):
     return text
 
 
-@pytest.mark.parametrize("n", LENGTHS)
-def test_fused_attention_compiles_for_v5e(n, one_chip, no_persistent_cache):
-    def fwd(q, k, v, bias, mask):
-        return fused_attention(q, k, v, bias=bias, q_mask=mask, k_mask=mask,
-                               heads=HEADS, bias_repeat=FOLD_AXIS)
+@pytest.mark.parametrize("n,batch", [(n, 1) for n in FUSED_LENGTHS]
+                         + [(n, 8) for n in FUSED_LENGTHS[:3]])
+def test_fused_attention_compiles_for_v5e(n, batch, one_chip,
+                                          no_persistent_cache):
+    """bf16 operands and bias, query and key masks, as many folded rows as
+    positions (the pair track's shape: the step takes several rows), at
+    batch 1 and, for the online cell's buckets, at its batch of 8."""
+    def fwd(q, kv, bias, mask):
+        return fused_attention_merged(q, kv, bias=bias, q_mask=mask,
+                                      k_mask=mask, heads=HEADS,
+                                      bias_repeat=n)
 
-    _compiled_kernel_text(fwd, _attention_shapes(n, one_chip))
+    _compiled_kernel_text(fwd, _merged_shapes(n, one_chip, batch, n))
 
 
 @pytest.mark.parametrize("n", LENGTHS)
@@ -104,23 +126,52 @@ def test_block_sparse_attention_compiles_for_v5e(n, one_chip,
 @pytest.mark.parametrize("n", LENGTHS[:2])
 def test_fused_attention_gradient_compiles_for_v5e(n, one_chip,
                                                    no_persistent_cache):
-    """jax.grad through the custom_vjp: Pallas forward, XLA-recompute
-    backward, cotangents for q/k/v and the unrepeated bias."""
-    def loss(q, k, v, bias, mask):
-        out = fused_attention(q, k, v, bias=bias, k_mask=mask, heads=HEADS,
-                              bias_repeat=FOLD_AXIS)
+    """jax.grad through the custom_vjp: the XLA attention forward and
+    backward (no fused backward exists yet), cotangents for q/k/v and the
+    unrepeated bias, and NO custom call: a training step compiles to the
+    program it had before the kernel."""
+    def loss(q, kv, bias, mask):
+        out = fused_attention_merged(q, kv, bias=bias, k_mask=mask,
+                                     heads=HEADS, bias_repeat=FOLD_AXIS)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    _compiled_kernel_text(jax.grad(loss, argnums=(0, 1, 2, 3)),
-                          _attention_shapes(n, one_chip))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_merged_shapes(n, one_chip)).compile().as_text()
+    assert "tpu_custom_call" not in text   # no Mosaic kernel
 
 
-def test_scan_fold_compiles_for_v5e(one_chip, no_persistent_cache):
+def _logits_shaped(text, rows_heads, n):
+    """The array shapes in an executable's text that hold one logit for
+    every (row, head, query, key): rows x heads x n x n elements with (n, n)
+    innermost, however the leading axes are grouped."""
+    import math
+    import re
+    found = set()
+    for dims in re.findall(r"\b(?:bf16|f32)\[([\d,]+)\]", text):
+        shape = tuple(int(d) for d in dims.split(","))
+        if shape[-2:] == (n, n) and math.prod(shape[:-2]) == rows_heads:
+            found.add(shape)
+    return found
+
+
+@pytest.mark.parametrize("rule", ("on_a_tpu", "off_the_chip"))
+def test_scan_fold_compiles_for_v5e(rule, one_chip, no_persistent_cache,
+                                    monkeypatch):
     """The 3-recycle predict.fold of chip_smoke.py's model (published
     widths, depth 2) at L=256, MSA 5, from eval_shape parameter shapes; the
-    program must fit one v5e's 16 GB with room to spare."""
-    import chip_smoke
+    program must fit one v5e's 16 GB with room to spare. With the platform
+    predicate saying TPU (`Attention.__call__`'s rule sees the CPU here, so
+    the test steers it), both triangle attentions and the MSA row attention
+    are Mosaic custom calls that `obs.device` books to their kernels, and
+    no tensor of the logits' shape is left in the program; with the
+    predicate as it is here the same detector finds the logits."""
+    import re
 
+    import chip_smoke
+    from alphafold2_tpu import runtime
+    from alphafold2_tpu.obs import device
+
+    monkeypatch.setattr(runtime, "on_tpu", lambda: rule == "on_a_tpu")
     n, m = chip_smoke.BUCKET, chip_smoke.MSA_DEPTH
     model = Alphafold2(predict_coords=True, dtype=jnp.bfloat16,
                        **chip_smoke.FULL_MODEL)
@@ -145,6 +196,27 @@ def test_scan_fold_compiles_for_v5e(one_chip, no_persistent_cache):
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
     assert total < 8 * 2**30, total
+
+    text = compiled.as_text()
+    logits = _logits_shaped(text, n * chip_smoke.FULL_MODEL["heads"], n)
+    calls = [op_name for line in text.splitlines()
+             if "tpu_custom_call" in line
+             for op_name in re.findall(r'op_name="([^"]*)"', line)]
+    if rule == "off_the_chip":
+        assert logits and not calls
+        return
+    assert not logits, logits
+    booked = {}
+    for op_name in calls:
+        assert device.is_fused("custom-call", op_name), op_name
+        site = [p for p in op_name.split("/") if p in device._BY_COMPONENT]
+        booked.setdefault(site[-1], set()).add(device.kernel_of(op_name))
+    # the MSA column attention attends 5 alignment rows: under the rule's
+    # lower bound, so it stays with XLA
+    assert booked == {
+        "triangle_attention_outgoing": {"triangle_attention"},
+        "triangle_attention_ingoing": {"triangle_attention"},
+        "row_attn": {"msa_row_attention"}}, booked
 
 
 @pytest.mark.parametrize("program", tiny_programs.PROGRAMS)

@@ -186,6 +186,62 @@ def test_reduce_without_a_table_books_everything_to_other(profile_data):
         bare["kernels"]["other"]["seconds"])
 
 
+FUSED = ("jit(fold)/Alphafold2/net/block/attn/triangle_attention_outgoing/"
+         "attn/fused_attention/pallas_call")
+
+
+@pytest.mark.parametrize("opcode,op_name,fused", [
+    ("custom-call", FUSED, True),
+    # XLA joins merged instructions' names: the first is the instruction's
+    ("custom-call", FUSED + ";jit(fold)/Alphafold2/net/reshape", True),
+    # the XLA attention a differentiated trace runs under the same scope
+    ("fusion", FUSED.replace("pallas_call", "dot_general"), False),
+    # another kernel's custom call
+    ("custom-call", "jit(fold)/Alphafold2/net/block/attn/block_sparse/"
+     "pallas_call", False),
+    ("custom-call", None, False),
+])
+def test_is_fused(opcode, op_name, fused):
+    assert device.is_fused(opcode, op_name) is fused
+
+
+def test_reduce_books_the_fused_kernels_time_beside_its_kernels():
+    """`fused_s`, the counter of a mechanism that engages when the program
+    is traced: the device seconds of the fused attention's custom calls,
+    within the `seconds` of the kernel their module belongs to."""
+    from types import SimpleNamespace as NS
+    table = {"fused_attention.3": FUSED,
+             "fusion.7": FUSED.replace("fused_attention/pallas_call",
+                                       "attn.project_merged/to_q/dot_general"),
+             "fused_attention.4": FUSED.replace("triangle_attention_outgoing",
+                                                "row_attn")}
+    texts = {"fused_attention.3": "bf16[8,64,128] custom-call(bf16[8] %a)",
+             "fusion.7": "bf16[8,64,128] fusion(bf16[8] %a), kind=kOutput",
+             "fused_attention.4": "bf16[8,64,128] custom-call(bf16[8] %a)"}
+    durations = {"fused_attention.3": 3_000_000, "fusion.7": 1_000_000,
+                 "fused_attention.4": 500_000}
+    start, events = 10_000, []
+    for _ in range(2):
+        for instr, ns in durations.items():
+            events.append(NS(name=f"%{instr} = {texts[instr]}", start_ns=start,
+                             duration_ns=ns, stats=()))
+            start += ns + 100
+    data = NS(planes=[NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=events)])])
+    kernels = device.reduce(data, table)["kernels"]
+    assert kernels["triangle_attention"]["fused_s"] == pytest.approx(6e-3)
+    assert kernels["triangle_attention"]["seconds"] == pytest.approx(8e-3)
+    assert kernels["msa_row_attention"]["fused_s"] == pytest.approx(1e-3) \
+        == pytest.approx(kernels["msa_row_attention"]["seconds"])
+    assert all(kernels[k]["fused_s"] == 0 for k in device.KERNEL_NAMES
+               if k not in ("triangle_attention", "msa_row_attention"))
+
+
+def test_capture_of_the_xla_attention_books_no_fused_time(capture):
+    _, reduced = capture     # recorded before the kernel took the folds
+    assert all(k["fused_s"] == 0 for k in reduced["kernels"].values())
+
+
 def test_profile_needs_a_device_plane():
     """The CPU backend records no device plane: `profile` says so, and
     leaves no capture behind."""

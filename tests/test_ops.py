@@ -20,6 +20,118 @@ def make_inputs(key, b=4, n=64, d=32):
     return q, k, v, bias
 
 
+def axial_inputs(key, batch, rows, heads, n, d, dtype, bias=True,
+                 masks=True):
+    """q/k/v in the axial layout (batch * rows * heads, n, d), the
+    unrepeated bias and one mask for queries and keys whose FIRST row is
+    fully padded."""
+    ks = jax.random.split(key, 5)
+    b_all = batch * rows * heads
+    q = (jax.random.normal(ks[0], (b_all, n, d)) * 0.5).astype(dtype)
+    k = (jax.random.normal(ks[1], (b_all, n, d)) * 0.5).astype(dtype)
+    v = jax.random.normal(ks[2], (b_all, n, d)).astype(dtype)
+    bias = jax.random.normal(ks[3], (batch * heads, n, n)).astype(dtype) \
+        if bias else None
+    mask = None
+    if masks:
+        lengths = jax.random.randint(ks[4], (batch * rows, 1), n // 2, n + 1)
+        mask = (jnp.arange(n)[None, :] < lengths).at[0].set(False)
+    return q, k, v, bias, mask
+
+
+# (batch, rows, heads, n, d, dtype, bias, masks, step overrides)
+KERNEL_CASES = {
+    "n64-f32": (2, 4, 2, 64, 32, jnp.float32, True, True, {}),
+    "n64-bf16": (2, 4, 2, 64, 32, jnp.bfloat16, True, True, {}),
+    "n128-bf16-no-bias": (1, 6, 2, 128, 16, jnp.bfloat16, False, True, {}),
+    "n128-f32-no-masks": (1, 4, 2, 128, 16, jnp.float32, True, False, {}),
+    "n256-bf16": (1, 3, 2, 256, 16, jnp.bfloat16, True, True, {}),
+    "n256-f32-query-blocks": (1, 2, 1, 256, 16, jnp.float32, True, True,
+                              {"block_q": 128}),
+    "n384-bf16": (1, 2, 1, 384, 16, jnp.bfloat16, True, True, {}),
+    "n640-bf16": (1, 2, 1, 640, 16, jnp.bfloat16, True, True, {}),
+    "n640-f32": (1, 2, 1, 640, 16, jnp.float32, True, True, {}),
+    "four-rows-a-step": (2, 8, 2, 64, 16, jnp.float32, True, True,
+                         {"block_rows": 4}),
+    "rows-the-step-does-not-divide": (1, 7, 2, 64, 16, jnp.float32, True,
+                                      True, {"block_rows": 4}),
+    "odd-rows-default-step": (1, 5, 2, 128, 16, jnp.bfloat16, True, True,
+                              {}),
+    "no-bias-no-masks": (1, 4, 2, 64, 16, jnp.float32, False, False, {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_matches_reference(case):
+    """The kernel in interpret mode against `attention_reference`: both
+    dtypes, with and without bias, `bias_repeat` > 1, query and key masks
+    with a fully padded row, every bucket length, several rows a step and a
+    row count the step does not divide."""
+    batch, rows, heads, n, d, dtype, bias, masks, step = KERNEL_CASES[case]
+    q, k, v, b, m = axial_inputs(jax.random.PRNGKey(n + rows), batch, rows,
+                                 heads, n, d, dtype, bias, masks)
+    kw = dict(heads=heads, bias_repeat=rows if bias else 1)
+    out = ops_attn.fused_attention(q, k, v, b, m, m, interpret=True, **kw,
+                                   **step)
+    ref = ops_attn.attention_reference(q, k, v, b, m, m, **kw)
+    assert out.dtype == dtype and bool(jnp.isfinite(out).all())
+    # bf16: the reference rounds the probabilities of a whole row, the
+    # kernel those of its own float32 accumulation: an ulp of O(1) outputs
+    atol = 1e-5 if dtype == jnp.float32 else 4e-2
+    assert np.allclose(np.asarray(out, np.float32),
+                       np.asarray(ref, np.float32), atol=atol)
+    if masks:   # the padded row reads the uniform average of v
+        first = np.asarray(out, np.float32)[:heads]
+        mean_v = np.asarray(v, np.float32)[:heads].mean(axis=1, keepdims=True)
+        assert np.allclose(first, np.broadcast_to(mean_v, first.shape),
+                           atol=atol)
+
+
+@pytest.mark.parametrize("heads,d", [(2, 64), (4, 64), (8, 16), (2, 16)])
+def test_values_are_read_from_the_key_value_projection(heads, d):
+    """`v=None`: k is [k | v] as the Dense layer lays it out, and the index
+    map (lane tiles of heads) or a split (heads that fill no tile) finds
+    the values in its second half: the same output, and the same gradient,
+    as with k and v apart."""
+    rows, n = 3, 64
+    q, k, v, bias, mask = axial_inputs(jax.random.PRNGKey(d), 1, rows, heads,
+                                       n, d, jnp.float32)
+    merged = lambda t: ops_attn.merge_heads(
+        t.reshape(rows, heads, n, d))
+    q, k, v = merged(q), merged(k), merged(v)
+    kv = jnp.concatenate([k, v], axis=-1)
+    kw = dict(bias=bias, q_mask=mask, k_mask=mask, heads=heads,
+              bias_repeat=rows, interpret=True)
+    apart = ops_attn.fused_attention_merged(q, k, v, **kw)
+    together = ops_attn.fused_attention_merged(q, kv, **kw)
+    np.testing.assert_array_equal(np.asarray(apart), np.asarray(together))
+    g_apart = jax.grad(lambda k, v: jnp.sum(
+        ops_attn.fused_attention_merged(q, k, v, **kw) ** 2),
+        argnums=(0, 1))(k, v)
+    g_together = jax.grad(lambda kv: jnp.sum(
+        ops_attn.fused_attention_merged(q, kv, **kw) ** 2))(kv)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(g_apart, -1)),
+                               np.asarray(g_together), rtol=1e-5, atol=1e-6)
+
+
+def test_step_shape_follows_the_length():
+    """Rows per step and the query block come from the shape: whole rows up
+    to 640, two query blocks at 1,024, more rows a step the shorter they
+    are, never more rows than there are; two heads of 64 lanes share a
+    step, narrower heads fill a lane tile or take all their lanes."""
+    shape = ops_attn._step_shape
+    assert shape(640, 640, 640)[0] == 640
+    assert shape(1024, 1024, 1024)[0] == 512
+    assert shape(64, 64, 64)[1] >= shape(256, 256, 256)[1] \
+        > shape(640, 640, 640)[1] >= 2
+    assert shape(640, 640, 3)[1] <= 3 and shape(8, 8, 1)[1] == 1
+    for n in (64, 128, 256, 384, 512, 640, 1024):
+        assert n % shape(n, n, n)[0] == 0
+    group = ops_attn._head_group
+    assert group(8, 64) == 2 and group(8, 32) == 4 and group(8, 16) == 8
+    assert group(2, 16) == 2 and group(1, 64) == 1 and group(3, 64) == 3
+
+
 class TestFusedAttention:
     def test_matches_reference(self):
         q, k, v, bias = make_inputs(jax.random.PRNGKey(0))
@@ -125,20 +237,84 @@ class TestBackendSwitch:
     def test_model_runs_with_pallas_backend(self, monkeypatch):
         """Run the full model through the Pallas path (interpreter mode on
         CPU) and compare against the XLA path — numerics must agree."""
+        calls = []
+        interpreted = functools.partial(ops_attn.fused_attention_merged,
+                                        interpret=True)
         monkeypatch.setattr(
-            ops_attn, "fused_attention",
-            functools.partial(ops_attn.fused_attention, interpret=True))
+            ops_attn, "fused_attention_merged",
+            lambda *a, **kw: calls.append(a[0].shape) or interpreted(*a, **kw))
         from alphafold2_tpu import Alphafold2
         model = Alphafold2(dim=32, depth=1, heads=2, dim_head=16)
-        seq = jax.random.randint(jax.random.PRNGKey(6), (1, 16), 0, 21)
-        msa = jax.random.randint(jax.random.PRNGKey(7), (1, 3, 16), 0, 21)
+        # 64 residues: the shortest rows the kernel's rule admits
+        seq = jax.random.randint(jax.random.PRNGKey(6), (1, 64), 0, 21)
+        msa = jax.random.randint(jax.random.PRNGKey(7), (1, 3, 64), 0, 21)
         params = model.init(jax.random.PRNGKey(8), seq, msa=msa)
 
         ret_xla = model.apply(params, seq, msa=msa)
         with ops_attn.pallas_attention(True):
             ret_pal = model.apply(params, seq, msa=msa)
+        assert calls   # both triangle attentions and the MSA row attention
         assert np.allclose(np.asarray(ret_xla.distance),
                            np.asarray(ret_pal.distance), atol=2e-3)
+
+
+def _attention_module_case(dropout=0.0):
+    from alphafold2_tpu.model.primitives import Attention
+    mod = Attention(dim=32, heads=2, dim_head=16, dropout=dropout)
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 64, 32))
+    mask = jnp.ones((3, 64), bool).at[:, 50:].set(False)
+    params = mod.init(jax.random.PRNGKey(1), x, mask=mask)
+    return mod, params, x, mask
+
+
+# what `Attention.__call__` decides, by what the trace can see:
+# (on a TPU, the flag, call keywords, module dropout) -> takes the kernel
+RULE_CASES = {
+    "cpu-flag-off": (False, False, {}, 0.0, False),
+    "cpu-flag-on": (False, True, {}, 0.0, True),
+    "tpu": (True, False, {}, 0.0, True),
+    "tpu-tied-rows": (True, False, {"tie_dim": 3}, 0.0, False),
+    "tpu-cross-attention": (True, False, {"context": True}, 0.0, False),
+    "tpu-active-dropout": (True, False, {"deterministic": False}, 0.1,
+                           False),
+    "tpu-idle-dropout": (True, False, {"deterministic": True}, 0.1, True),
+    "tpu-short-rows": (True, False, {"length": 24}, 0.0, False),
+    "tpu-under-a-mesh": (True, False, {"mesh": 2}, 0.0, False),
+    "tpu-under-a-mesh-of-one": (True, False, {"mesh": 1}, 0.0, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_attention_takes_the_kernel_by_what_it_can_see(case, monkeypatch):
+    """Off the chip with the flag off (the tier-1 suite) the rule is false;
+    on a TPU no flag is read and shape, tied rows, context and active
+    dropout decide."""
+    from alphafold2_tpu import runtime
+    on_tpu, flag, call, dropout, expect = RULE_CASES[case]
+    mod, params, x, mask = _attention_module_case(dropout)
+    call = dict(call)
+    length = call.pop("length", x.shape[1])
+    x, mask = x[:, :length], mask[:, :length]
+    if call.pop("context", False):
+        call.update(context=x, context_mask=mask)
+    devices = np.array(jax.devices()[:call.pop("mesh", 0)])
+    mesh = jax.sharding.Mesh(devices, ("data",)) if devices.size else None
+    calls = []
+    interpreted = functools.partial(ops_attn.fused_attention_merged,
+                                    interpret=True)
+
+    def spy(*args, **kwargs):
+        calls.append(kwargs)
+        return interpreted(*args, **kwargs)
+
+    monkeypatch.setattr(ops_attn, "fused_attention_merged", spy)
+    monkeypatch.setattr(runtime, "on_tpu", lambda: on_tpu)
+    from alphafold2_tpu.parallel.sharding import use_mesh
+    with ops_attn.pallas_attention(flag), use_mesh(mesh):
+        out = mod.apply(params, x, mask=mask,
+                        rngs={"dropout": jax.random.PRNGKey(2)}, **call)
+    assert bool(calls) == expect
+    assert out.shape == x.shape and bool(jnp.isfinite(out).all())
 
 
 class TestBlockSparseKernel:
@@ -361,6 +537,39 @@ class TestFusedAttentionGrad:
         np.testing.assert_allclose(
             np.asarray(jax.grad(f_kernel)(bias)),
             np.asarray(jax.grad(f_ref)(bias)), rtol=1e-4, atol=1e-5)
+
+    def test_differentiated_call_is_the_xla_attention(self):
+        """Under differentiation the custom_vjp's `fwd` stands in for the
+        kernel: the traced gradient holds no Pallas call, and its values ARE
+        the inline XLA path's (`xla_attention`, bias repeated, masks
+        filled), to the bit."""
+        heads, rows = 2, 3
+        q, k, v, bias, mask = axial_inputs(
+            jax.random.PRNGKey(5), 1, rows, heads, 64, 16, jnp.float32)
+        unfold = lambda t: t.reshape(-1, heads, *t.shape[1:])
+
+        def through_kernel(q, k, v, bias):
+            out = ops_attn.fused_attention(
+                q, k, v, bias, mask, mask, heads=heads, bias_repeat=rows,
+                interpret=True)
+            return jnp.sum(out * out)
+
+        def inline(q, k, v, bias):
+            out = ops_attn.xla_attention(
+                unfold(q), unfold(k), unfold(v), unfold(bias), mask, mask,
+                bias_repeat=rows)
+            return jnp.sum(out * out)
+
+        grad = jax.grad(through_kernel, argnums=(0, 1, 2, 3))
+        assert "pallas_call" in str(jax.make_jaxpr(through_kernel)(
+            q, k, v, bias))
+        assert "pallas_call" not in str(jax.make_jaxpr(grad)(q, k, v, bias))
+        assert "pallas_call" not in str(jax.make_jaxpr(
+            jax.value_and_grad(jax.checkpoint(through_kernel)))(
+                q, k, v, bias))
+        for a, b in zip(grad(q, k, v, bias),
+                        jax.grad(inline, argnums=(0, 1, 2, 3))(q, k, v, bias)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_degenerate_tiles_fall_back(self):
         """Nq/Nk < 8 (e.g. 1x1 init-coverage pair maps) route to the XLA
