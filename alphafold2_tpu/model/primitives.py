@@ -21,11 +21,11 @@ under the train policy) so matmuls hit the MXU at full rate. Folding an axis
 into batch is a free reshape under XLA. Attention written as einsum + softmax
 + einsum keeps its (rows x heads, n, n) logits in HBM and passes over them
 four times: two thirds of the 640 fold's device time (PERF.md section 5,
-PR 26). On a TPU every forward-only self-attention whose shape the fused
-kernel admits takes `ops.attention.fused_attention_merged` instead, on the
-projections as the Dense layers lay them out (no head is split off: the
-relayout copies cost more than the kernel); differentiated traces keep the
-einsum path (PERF.md section 6, PR 27).
+PR 26). On a TPU every self-attention whose shape the fused kernel admits
+takes `ops.attention.fused_attention_merged` instead, on the projections as
+the Dense layers lay them out (no head is split off: the relayout copies
+cost more than the kernel; PERF.md section 6, PR 27); a differentiated trace
+takes it too, forward and backward kernel (PR 32).
 """
 
 from __future__ import annotations
@@ -289,8 +289,12 @@ class Attention(nn.Module):
         # stays *unrepeated* (replayed over the folded axial axis by the
         # kernel's index map) and masks stay (b, n) vectors. Off the chip
         # `use_pallas_attention` opens the same door for the CPU tests. A
-        # differentiated trace runs the XLA attention below through the
-        # kernel's custom_vjp. Both paths share the gating/projection tail.
+        # differentiated trace takes the same call: its custom_vjp runs the
+        # forward kernel and a backward kernel that makes the logits again
+        # in VMEM and sums the bias cotangent over the rows in its own grid
+        # (a length whose queries the forward blocks, 1,024, keeps the XLA
+        # attention's backward). Both paths share the gating/projection
+        # tail.
         from alphafold2_tpu.ops import attention as fused
         from alphafold2_tpu.parallel.sharding import active_mesh
         dropping = self.dropout > 0.0 and not deterministic

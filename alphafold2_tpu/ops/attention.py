@@ -40,12 +40,29 @@ Contract:
 into the batch, (B, n, d): the layout of `attention_reference` and of the
 block-sparse kernel.
 
-Forward-only and differentiated attention have different best programs
-until a fused backward exists, so they are separate: the primal of the
-`jax.custom_vjp` is the kernel, and its `fwd`/`bwd`, which run only under
-differentiation, are `xla_attention`, the einsum + softmax path the model
-has always trained with (PERF.md section 7 names the fused backward as the
-next step).
+Differentiation keeps the logits in VMEM too. `fused_attention_merged` is a
+`jax.custom_vjp`: its `fwd` is the forward kernel, saving nothing but its own
+inputs (a remat'd block keeps nothing new), and its `bwd` is one backward
+kernel on the same layout and grid. Per row and head it makes the logits and
+the probabilities p again from q, k, the bias and the key mask, then
+dP = dO v^T, dS = p (dP - rowsum(p dP)), dq = dS k, dk = dS^T q, dv = p^T dO
+(five contractions, operands in their own dtype, float32 accumulation; the
+softmax and dS in float32). The bias cotangent is the sum of dS over the rows
+that share the bias: an output block (heads of the group, n, n) in float32
+whose index does not depend on the innermost, sequential row-group axis, so
+it is zeroed by the first row group, added to by each, and written once; XLA
+kept a (rows x heads, n, n) dS in HBM and reduced it. With two heads a lane
+tile the other head's lanes of q and dO are zeroed before the contractions,
+so dk and dv of the two heads land in their own lanes and are added; dq
+takes its head's lanes by the forward's select. The loop body takes four rows
+(`_BWD_UNROLL`), so that one row's contractions overlap another's softmax. The
+query mask stays outside both kernels: the forward's `where` after the call
+is transposed by hand in `bwd` (dO zeroed at the masked queries by a pass XLA
+fuses into dO's producer; their share of dv, the average of v they read, a
+row to a row that the kernel adds before it writes dv). The backward takes a
+whole row of queries a grid step (`backward_admits`: every length up to 640);
+where the forward blocks the queries (1,024) `fwd` and `bwd` are
+`xla_attention`, the einsum + softmax path, and its own VJP.
 
 Selection is `model/primitives.py:Attention.__call__`'s, by what the trace
 can see: a TPU backend, self-attention, no tied rows, no active dropout and
@@ -106,6 +123,12 @@ _LOGITS_BYTES = 2 * 2**20
 _STEP_BYTES = 2 * 2**20
 _MAX_ROWS = 64
 _LANES = 128
+# Rows the backward kernel's loop body takes together. A call of 8 heads of
+# 64 in bf16, 1 / 2 / 4 / 8 rows a body (my chip runs, PR 32): 256 rows of
+# 256: 2.52 / 2.43 / 2.35 / 2.32 ms; 256 rows of 128 without bias: 1.49 /
+# 1.21 / 1.09 / 1.03; 640 of 640: 23.4 / 23.0 / 22.7 / 25.8, and Mosaic takes
+# 4 / 10 / 24 s to compile the 640 kernel at 2 / 4 / 8.
+_BWD_UNROLL = 4
 
 
 def _head_group(heads, d):
@@ -187,11 +210,12 @@ def _attn_kernel(*refs, has_bias, cast_bias, has_km, block_rows, group, d):
     jax.lax.fori_loop(0, block_rows, one_row, 0)
 
 
-def _fused_attention_pallas(q, k, v, bias, q_mask, k_mask, *, heads,
-                            bias_repeat, block_q=None, block_rows=None,
-                            interpret=False):
-    """The pallas_call on the merged layout and the query mask after it
-    (forward only; `fused_attention_merged` is the differentiable door)."""
+def _step_layout(q, k, v, bias, k_mask, *, heads, bias_repeat, block_q,
+                 block_rows):
+    """What the forward and the backward call share: the grid, the operands
+    folded to (batch, rows, ...) and their block specs, on the merged
+    layout. Returns (grid, specs, args, shape): `specs`/`args` by operand
+    name, `shape` the sizes a kernel needs."""
     b, n, width = q.shape
     nk, d = k.shape[1], width // heads
     if bias is None:
@@ -214,56 +238,250 @@ def _fused_attention_pallas(q, k, v, bias, q_mask, k_mask, *, heads,
     v_first = 0 if v is not None else heads // group
     assert k.shape[-1] == (width if v is not None else 2 * width), k.shape
 
-    q_spec = pl.BlockSpec((None, block_rows, block_q, group * d),
-                          lambda bi, h, qi, g: (bi, g, qi, h))
-    k_spec = pl.BlockSpec((None, block_rows, nk, group * d),
-                          lambda bi, h, qi, g: (bi, g, 0, h))
-    v_spec = pl.BlockSpec((None, block_rows, nk, group * d),
-                          lambda bi, h, qi, g: (bi, g, 0, v_first + h))
     fold = lambda t: t.reshape(batch, rows, *t.shape[1:])
-    in_specs = [q_spec, k_spec, v_spec]
-    args = [fold(q), fold(k), fold(k if v is None else v)]
-    scratch = []
-    cast_bias = bias is not None and bias.dtype != jnp.float32
+    specs = {
+        "q": pl.BlockSpec((None, block_rows, block_q, group * d),
+                          lambda bi, h, qi, g: (bi, g, qi, h)),
+        "k": pl.BlockSpec((None, block_rows, nk, group * d),
+                          lambda bi, h, qi, g: (bi, g, 0, h)),
+        "v": pl.BlockSpec((None, block_rows, nk, group * d),
+                          lambda bi, h, qi, g: (bi, g, 0, v_first + h))}
+    args = {"q": fold(q), "k": fold(k), "v": fold(k if v is None else v)}
     if bias is not None:
-        in_specs.append(pl.BlockSpec(
-            (None, group, block_q, nk), lambda bi, h, qi, g: (bi, h, qi, 0)))
-        args.append(bias.reshape(batch, heads, n, nk))
-        if cast_bias:
-            scratch.append(pltpu.VMEM((group, block_q, nk), jnp.float32))
+        specs["bias"] = pl.BlockSpec(
+            (None, group, block_q, nk), lambda bi, h, qi, g: (bi, h, qi, 0))
+        args["bias"] = bias.reshape(batch, heads, n, nk)
     if k_mask is not None:
         assert k_mask.shape == (b, nk), (k_mask.shape, b, nk)
-        in_specs.append(pl.BlockSpec(
-            (None, block_rows, 1, nk), lambda bi, h, qi, g: (bi, g, 0, 0)))
-        args.append(k_mask.astype(jnp.float32).reshape(batch, rows, 1, nk))
+        specs["k_mask"] = pl.BlockSpec(
+            (None, block_rows, 1, nk), lambda bi, h, qi, g: (bi, g, 0, 0))
+        args["k_mask"] = k_mask.astype(jnp.float32).reshape(
+            batch, rows, 1, nk)
+    grid = (batch, heads // group, n // block_q, pl.cdiv(rows, block_rows))
+    shape = dict(batch=batch, rows=rows, n=n, nk=nk, width=width, d=d,
+                 group=group, block_q=block_q, block_rows=block_rows)
+    return grid, specs, args, shape
 
+
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _fused_attention_pallas(q, k, v, bias, q_mask, k_mask, *, heads,
+                            bias_repeat, block_q=None, block_rows=None,
+                            interpret=False):
+    """The forward pallas_call on the merged layout and the query mask after
+    it (`fused_attention_merged` is the differentiable door)."""
+    grid, specs, args, s = _step_layout(
+        q, k, v, bias, k_mask, heads=heads, bias_repeat=bias_repeat,
+        block_q=block_q, block_rows=block_rows)
+    scratch = []
+    cast_bias = bias is not None and bias.dtype != jnp.float32
+    if cast_bias:
+        scratch.append(
+            pltpu.VMEM((s["group"], s["block_q"], s["nk"]), jnp.float32))
     kernel = functools.partial(
         _attn_kernel, has_bias=bias is not None, cast_bias=cast_bias,
-        has_km=k_mask is not None, block_rows=block_rows, group=group, d=d)
+        has_km=k_mask is not None, block_rows=s["block_rows"],
+        group=s["group"], d=s["d"])
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((batch, rows, n, width), q.dtype),
-        grid=(batch, heads // group, n // block_q,
-              pl.cdiv(rows, block_rows)),
-        in_specs=in_specs,
-        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (s["batch"], s["rows"], s["n"], s["width"]), q.dtype),
+        grid=grid,
+        in_specs=list(specs.values()),
+        out_specs=specs["q"],
         scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(*args).reshape(b, n, width)
+    )(*args.values()).reshape(q.shape)
     if q_mask is None:
         return out
     # a masked query's logits are all MASK_VALUE: uniform weights, the
     # average of v. An elementwise pass that XLA fuses into the gate that
     # reads the output; inside the kernel the (1, n) -> (n, 1) relayout of
     # the mask cost a third of the 128-long attention (PERF.md section 6)
-    values = k[..., width:] if v is None else v
+    values = k[..., s["width"]:] if v is None else v
     uniform = jnp.mean(values.astype(jnp.float32), axis=1, keepdims=True)
     return jnp.where(q_mask.astype(bool)[..., None], out,
                      uniform.astype(out.dtype))
+
+
+def _attn_bwd_kernel(*refs, has_bias, cast_bias, has_km, has_qm, block_rows,
+                     rows, group, d):
+    """One grid step of the backward: per row and head the logits and the
+    probabilities again from q, k, the bias and the key mask (float32, in
+    VMEM, as `_attn_kernel` makes them), then dP = dO v^T,
+    dS = p (dP - rowsum(p dP)), dq = dS k, dk = dS^T q, dv = p^T dO; dS is
+    added to the heads' bias cotangent, an output block that stays resident
+    while the rows that share the bias stream past. With a query mask, dO
+    comes zeroed at the masked queries and their share of dv (they read the
+    average of v) comes as one row to a row, added before dv is written."""
+    refs = list(refs)
+    q_ref, k_ref, v_ref, do_ref = refs[:4]    # (R, bq, W), 2 x (R, nk, W), q's
+    idx = 4
+    bias_ref = refs[idx] if has_bias else None        # (G, bq, nk)
+    idx += int(has_bias)
+    km_ref = refs[idx] if has_km else None            # (R, 1, nk) float32
+    idx += int(has_km)
+    dv_mean_ref = refs[idx] if has_qm else None       # (R, 1, W) float32
+    idx += int(has_qm)
+    dq_ref, dk_ref, dv_ref = refs[idx:idx + 3]        # q's, k's, v's blocks
+    idx += 3
+    dbias_ref = refs[idx] if has_bias else None       # (G, bq, nk) float32
+    idx += int(has_bias)
+    first = pl.program_id(3) == 0
+    if cast_bias:
+        bias_f32 = refs[idx]
+
+        @pl.when(first)
+        def _():
+            bias_f32[...] = bias_ref[...].astype(jnp.float32)
+        bias_ref = bias_f32
+    if has_bias:
+        # the row-group axis is the innermost, sequential grid axis and the
+        # block's index does not depend on it: zeroed by the heads' first
+        # row group, added to by every one, written back after the last
+        @pl.when(first)
+        def _():
+            dbias_ref[...] = jnp.zeros_like(dbias_ref)
+
+    low = q_ref.dtype == jnp.bfloat16
+    dot = functools.partial(
+        jax.lax.dot_general, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT if low
+        else jax.lax.Precision.HIGHEST)
+    nt = (((1,), (1,)), ((), ()))     # a b^T
+    nn = (((1,), (0,)), ((), ()))     # a b
+    tn = (((0,), (0,)), ((), ()))     # a^T b
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, q_ref.shape[-1]), 1)
+
+    def one_row(r, carry):
+        q, k, v, do = q_ref[r], k_ref[r], v_ref[r], do_ref[r]
+        if has_km:
+            keys = km_ref[r] > 0
+            # a row with no valid key: every logit is the fill, which no
+            # operand reaches (the reference's `where` passes no cotangent):
+            # p is uniform and dv has its share, dq, dk and dbias are zero
+            any_key = jnp.max(km_ref[r], axis=-1, keepdims=True) > 0
+        dq = dk = dv = None
+        for a in range(group):
+            qa, doa = q, do
+            if group > 1:
+                # the other heads' lanes of q and dO are zero: their
+                # contractions over all lanes are head a's, and dk and dv of
+                # head a come out in its own lanes, zero in the others
+                mine = (lane >= a * d) & (lane < (a + 1) * d)
+                qa = jnp.where(mine, q, jnp.zeros_like(q))
+                doa = jnp.where(mine, do, jnp.zeros_like(do))
+            logits = dot(qa, k, nt)                       # (bq, nk)
+            if has_bias:
+                logits = logits + bias_ref[a]
+            if has_km:
+                logits = jnp.where(keys, logits, MASK_VALUE)
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            p = jnp.exp(logits - m)
+            denom = jnp.sum(p, axis=-1, keepdims=True)
+            p = p * pl.reciprocal(denom, approx=True) if low else p / denom
+            dp = dot(jnp.where(any_key, doa, jnp.zeros_like(doa))
+                     if has_km else doa, v, nt)           # (bq, nk)
+            ds = p * (dp - jnp.sum(p * dp, axis=-1, keepdims=True))
+            if has_bias:
+                dbias_ref[a] += ds
+            ds = ds.astype(k.dtype)
+            dq_a = dot(ds, k, nn)                         # (bq, W)
+            dk_a = dot(ds, qa, tn)                        # (nk, W)
+            dv_a = dot(p.astype(do.dtype), doa, tn)       # (nk, W)
+            dq = dq_a if dq is None else jnp.where(mine, dq_a, dq)
+            dk = dk_a if dk is None else dk + dk_a
+            dv = dv_a if dv is None else dv + dv_a
+        if has_qm:
+            dv = dv + dv_mean_ref[r]
+        dq_ref[r] = dq.astype(dq_ref.dtype)
+        dk_ref[r] = dk.astype(dk_ref.dtype)
+        dv_ref[r] = dv.astype(dv_ref.dtype)
+        return carry
+
+    # Several rows a loop iteration: their chains of contractions and
+    # softmax passes are independent, and one basic block lets the scheduler
+    # overlap one row's MXU work with another's VPU work (`_BWD_UNROLL`).
+    # The rows of a last, partial group that are not there hold anything:
+    # they must not reach the bias cotangent, so the loops end at the last
+    # row there is.
+    unroll = next(u for u in (_BWD_UNROLL, 2, 1) if block_rows % u == 0)
+    here = block_rows if rows % block_rows == 0 else jnp.minimum(
+        block_rows, rows - pl.program_id(3) * block_rows)
+
+    def some_rows(i, carry):
+        for j in range(unroll):
+            carry = one_row(unroll * i + j, carry)
+        return carry
+
+    jax.lax.fori_loop(0, here // unroll, some_rows, 0)
+    if rows % block_rows and unroll > 1:
+        jax.lax.fori_loop(here // unroll * unroll, here, one_row, 0)
+
+
+def _fused_attention_bwd_pallas(q, k, v, bias, q_mask, k_mask, g, *, heads,
+                                bias_repeat, block_rows=None,
+                                interpret=False):
+    """The backward pallas_call: (dq, dk, dv, dbias) on the layouts of q,
+    k, v (dk is [dk | dv] and dv None where k was [k | v]) and the
+    unrepeated bias. A whole row of queries a step (`backward_admits`)."""
+    grid, specs, args, s = _step_layout(
+        q, k, v, bias, k_mask, heads=heads, bias_repeat=bias_repeat,
+        block_q=q.shape[1], block_rows=block_rows)
+    lead, width = (s["batch"], s["rows"]), s["width"]
+    names = [name for name in ("q", "k", "v", "bias", "k_mask")
+             if name in specs]
+    in_specs = [specs[name] for name in names]
+    operands = [args[name] for name in names]
+    if q_mask is not None:
+        # the forward's `where` after the call, transposed by hand: no
+        # cotangent reaches the kernel's output at a masked query, and the
+        # average of v it read instead has the sum of theirs, a row to a row
+        keep = q_mask.astype(bool)[..., None]
+        in_specs.append(pl.BlockSpec(
+            (None, s["block_rows"], 1, s["group"] * s["d"]),
+            lambda bi, h, qi, g: (bi, g, 0, h)))
+        operands.append((jnp.sum(
+            jnp.where(keep, 0, g).astype(jnp.float32), axis=1, keepdims=True)
+            / s["nk"]).reshape(*lead, 1, width))
+        g = jnp.where(keep, g, jnp.zeros_like(g))
+    in_specs.insert(3, specs["q"])            # dO, after q, k and v
+    operands.insert(3, g.reshape(args["q"].shape))
+    # the cotangents of k and v are blocks of two arrays even where k and v
+    # came as one: an output has one block spec
+    out_specs = [specs["q"], specs["k"], specs["k"]]
+    out_shape = [jax.ShapeDtypeStruct((*lead, s["n"], width), q.dtype)] \
+        + [jax.ShapeDtypeStruct((*lead, s["nk"], width), k.dtype)] * 2
+    scratch = []
+    cast_bias = bias is not None and bias.dtype != jnp.float32
+    if bias is not None:
+        out_specs.append(specs["bias"])
+        out_shape.append(jax.ShapeDtypeStruct(args["bias"].shape,
+                                              jnp.float32))
+        if cast_bias:
+            scratch.append(
+                pltpu.VMEM((s["group"], s["n"], s["nk"]), jnp.float32))
+    kernel = functools.partial(
+        _attn_bwd_kernel, has_bias=bias is not None, cast_bias=cast_bias,
+        has_km=k_mask is not None, has_qm=q_mask is not None,
+        block_rows=s["block_rows"], rows=s["rows"], group=s["group"],
+        d=s["d"])
+    dq, dk, dv, *dbias = pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid,
+        in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS, interpret=interpret,
+    )(*operands)
+    dq = dq.reshape(q.shape)
+    dk, dv = (t.reshape(k.shape[0], k.shape[1], width) for t in (dk, dv))
+    dbias = dbias[0].reshape(bias.shape).astype(bias.dtype) if dbias \
+        else None
+    if v is None:
+        return dq, jnp.concatenate([dk, dv], axis=-1), None, dbias
+    return dq, dk, dv, dbias
 
 
 def split_heads(t, heads):
@@ -286,12 +504,16 @@ def _zero_cotangent(x):
 
 @functools.lru_cache(maxsize=None)
 def _fused_attention_vjp(heads, bias_repeat, block_q, block_rows, interpret):
-    """The kernel as the primal of a custom_vjp whose `fwd`/`bwd` are
-    `xla_attention` (on split heads) and its own VJP. JAX runs `fwd` in
-    place of the primal whenever the call is differentiated, so a training
-    step compiles to the XLA attention it always had and holds no custom
-    call; grads flow to q/k/v and the (unrepeated) bias, masks get
-    symbolic-zero cotangents."""
+    """The forward kernel as a `jax.custom_vjp`. Where `backward_admits` the
+    shape, `fwd` is the same call, saving nothing but its own inputs, and
+    `bwd` the backward kernel: a differentiated trace holds two custom calls
+    an attention (three under remat, which runs the forward again) and no
+    tensor of the logits' shape. Elsewhere `fwd`/`bwd` are `xla_attention`
+    (on split heads) and its own VJP, the program such a trace always had.
+    Grads flow to q/k/v and the (unrepeated) bias; masks get symbolic-zero
+    cotangents."""
+    step = dict(heads=heads, bias_repeat=bias_repeat, block_rows=block_rows,
+                interpret=interpret)
 
     def xla(q, k, v, bias, q_mask, k_mask):
         if v is None:
@@ -305,20 +527,27 @@ def _fused_attention_vjp(heads, bias_repeat, block_q, block_rows, interpret):
 
     @jax.custom_vjp
     def f(q, k, v, bias, q_mask, k_mask):
-        return _fused_attention_pallas(
-            q, k, v, bias, q_mask, k_mask, heads=heads,
-            bias_repeat=bias_repeat, block_q=block_q, block_rows=block_rows,
-            interpret=interpret)
+        return _fused_attention_pallas(q, k, v, bias, q_mask, k_mask,
+                                       block_q=block_q, **step)
 
     def fwd(q, k, v, bias, q_mask, k_mask):
+        if backward_admits(q.shape[1], k.shape[1], block_q):
+            return f(q, k, v, bias, q_mask, k_mask), dict(
+                inputs=(q, k, v, bias), masks=(q_mask, k_mask))
         out, vjp = jax.vjp(
             lambda q, k, v, bias: xla(q, k, v, bias, q_mask, k_mask),
             q, k, v, bias)
-        return out, (vjp, q_mask, k_mask)
+        return out, dict(xla_vjp=vjp, masks=(q_mask, k_mask))
 
     def bwd(res, g):
-        vjp, q_mask, k_mask = res
-        return (*vjp(g), _zero_cotangent(q_mask), _zero_cotangent(k_mask))
+        q_mask, k_mask = res["masks"]
+        if "xla_vjp" in res:
+            grads = res["xla_vjp"](g)
+        else:
+            q, k, v, bias = res["inputs"]
+            grads = _fused_attention_bwd_pallas(q, k, v, bias, q_mask,
+                                                k_mask, g, **step)
+        return (*grads, _zero_cotangent(q_mask), _zero_cotangent(k_mask))
 
     f.defvjp(fwd, bwd)
     return f
@@ -344,6 +573,25 @@ def admits(n: int, d: int) -> bool:
     was measured against the XLA path."""
     return (n >= MIN_FUSED_LENGTH and n % FUSED_LENGTH_MULTIPLE == 0
             and d % 8 == 0)
+
+
+# The backward kernel against the XLA attention's backward, same operands (8
+# heads of 64, bf16, both masks; my chip runs, PR 32; PERF.md section 6):
+# 640 rows of 640: 22.7 against 104.5 ms, 384 of 384: 6.0 against 20.6, 256 of
+# 256: 2.35 against 6.90, the MSA row attention's 128 rows of 256: 1.18
+# against 2.88, the MSA column attention's 256 rows of 128 without bias: 1.09
+# against 1.69, 128 of 128: 0.56 against 0.56, 64 of 64: 0.51 against 0.57.
+# In the crop-256 training step a triangle attention's three calls (forward,
+# remat's forward again, backward) take 0.875 + 0.875 + 1.615 ms where XLA's
+# attention took 9.0; a predicate that kept XLA's backward for the column
+# attention left the step where it was (486.0 against 485.0 ms) and went.
+def backward_admits(n: int, nk: int, block_q=None) -> bool:
+    """Whether a differentiated trace takes the backward kernel: a whole row
+    of queries a grid step, which is every length up to 640. Where the
+    forward blocks the queries (1,024: two blocks of 512) dk and dv would
+    have to be accumulated over the query blocks too; no cell trains
+    there."""
+    return (block_q or _step_shape(n, nk, 1)[0]) == n
 
 
 # a Pallas call is no flax module: the scope is the one name its

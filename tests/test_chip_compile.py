@@ -123,21 +123,48 @@ def test_block_sparse_attention_compiles_for_v5e(n, one_chip,
     _compiled_kernel_text(fwd, _attention_shapes(n, one_chip))
 
 
-@pytest.mark.parametrize("n", LENGTHS[:2])
-def test_fused_attention_gradient_compiles_for_v5e(n, one_chip,
+# (n, rows, bias): the training cell's three attentions (triangle, MSA row,
+# MSA column), two folded rows at 256 and 384, the longest whole row of
+# queries, and a length whose queries the forward blocks
+GRADIENT_CASES = {
+    "triangle-256": (256, 256, True),
+    "msa-row-256": (256, 128, True),
+    "msa-col-128-no-bias": (128, 256, False),
+    "two-rows-256": (256, FOLD_AXIS, True),
+    "two-rows-384": (384, FOLD_AXIS, True),
+    "pair-640": (640, 640, True),
+    "blocked-queries-1024": (1024, FOLD_AXIS, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRADIENT_CASES))
+def test_fused_attention_gradient_compiles_for_v5e(case, one_chip,
                                                    no_persistent_cache):
-    """jax.grad through the custom_vjp: the XLA attention forward and
-    backward (no fused backward exists yet), cotangents for q/k/v and the
-    unrepeated bias, and NO custom call: a training step compiles to the
-    program it had before the kernel."""
+    """jax.grad through the custom_vjp, cotangents for q, [k | v] and the
+    unrepeated bias: the forward and the backward are Mosaic kernels and no
+    tensor of the logits' shape is left in the program, at every length
+    whose row of queries is one grid step; beyond it (1,024) forward and
+    backward are the XLA attention's and the logits are there."""
+    from alphafold2_tpu.ops.attention import backward_admits
+    n, rows, has_bias = GRADIENT_CASES[case]
+    q, kv, bias, mask = _merged_shapes(n, one_chip, 1, rows)
+
     def loss(q, kv, bias, mask):
-        out = fused_attention_merged(q, kv, bias=bias, k_mask=mask,
-                                     heads=HEADS, bias_repeat=FOLD_AXIS)
+        out = fused_attention_merged(q, kv, bias=bias, q_mask=mask,
+                                     k_mask=mask, heads=HEADS,
+                                     bias_repeat=rows if has_bias else 1)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        *_merged_shapes(n, one_chip)).compile().as_text()
-    assert "tpu_custom_call" not in text   # no Mosaic kernel
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2) if has_bias else (0, 1))
+                   ).lower(q, kv, bias if has_bias else None,
+                           mask).compile().as_text()
+    calls = sum("tpu_custom_call" in line and " custom-call(" in line
+                for line in text.splitlines())
+    logits = _logits_shaped(text, rows * HEADS, n)
+    if backward_admits(n, n):
+        assert calls == 2 and not logits, (calls, logits)
+    else:
+        assert calls == 0 and logits, (calls, logits)
 
 
 def _logits_shaped(text, rows_heads, n):
@@ -217,6 +244,61 @@ def test_scan_fold_compiles_for_v5e(rule, one_chip, no_persistent_cache,
         "triangle_attention_outgoing": {"triangle_attention"},
         "triangle_attention_ingoing": {"triangle_attention"},
         "row_attn": {"msa_row_attention"}}, booked
+
+
+def test_train_step_books_every_fused_call_to_its_attention(
+        one_chip, no_persistent_cache, monkeypatch):
+    """A tiny training step (scan + remat, 64 residues, 64 alignment rows:
+    the shortest every attention's rule admits) with the platform predicate
+    saying TPU: each of the four attentions of a block is three Mosaic custom
+    calls (the forward, remat's forward again, the backward), every one under
+    the fused scope and booked by `obs.device` to its attention's kernel,
+    none to `other`; and no tensor of the logits' shape is left."""
+    import re
+
+    from alphafold2_tpu import runtime, train
+    from alphafold2_tpu.obs import device
+
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    n, heads = 64, 2
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model = Alphafold2(dim=32, depth=2, heads=heads, dim_head=16,
+                       predict_coords=True, structure_module_depth=1,
+                       dtype=jnp.bfloat16, use_scan=True)
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, n), jnp.int32),
+                             msa=jnp.zeros((1, n, n), jnp.int32)),
+        jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda p: train.TrainState.create(
+        apply_fn=model.apply, params=p, tx=train.adam(3e-4),
+        rng=jax.random.PRNGKey(0)), params)
+    batch = {"seq": sds((1, n), jnp.int32), "msa": sds((1, n, n), jnp.int32),
+             "mask": sds((1, n), jnp.bool_),
+             "msa_mask": sds((1, n, n), jnp.bool_),
+             "coords": sds((1, n, 3), jnp.float32)}
+    text = jax.jit(train.make_train_step(model)).lower(
+        jax.tree.map(lambda s: sds(s.shape, s.dtype), state),
+        batch).compile().as_text()
+
+    assert not _logits_shaped(text, n * heads, n)
+    booked = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            op_name, = re.findall(r'op_name="([^"]*)"', line)
+            assert device.is_fused("custom-call", op_name), op_name
+            site = [p for p in op_name.split("/")
+                    if p in device._BY_COMPONENT][-1]
+            part = "backward" if "transpose(" in op_name \
+                and "rematted_computation" not in op_name else "forward"
+            booked.setdefault((site, device.kernel_of(op_name)),
+                              []).append(part)
+    assert {k: sorted(v) for k, v in booked.items()} == {
+        (site, kernel): ["backward", "forward", "forward"]
+        for site, kernel in (
+            ("triangle_attention_outgoing", "triangle_attention"),
+            ("triangle_attention_ingoing", "triangle_attention"),
+            ("row_attn", "msa_row_attention"),
+            ("col_attn", "msa_col_attention"))}, booked
 
 
 @pytest.mark.parametrize("program", tiny_programs.PROGRAMS)

@@ -958,16 +958,14 @@ class TestFleetController:
             rec = ctrl.reconcile()
             assert rec["warm_submissions"] == 1
             assert len(ctrl._warmed) == 1
+            # held here: a reconcile drops the tickets that are done, and
+            # the warm fold may land before the next one
+            ticket, = ctrl._warm_tickets
             clk[0] += 1.0
             rec = ctrl.reconcile()           # same head: dedup holds
             assert rec["warm_submissions"] == 0
-            # the warm fold actually lands: wait for the ticket
-            deadline = time.monotonic() + 30
-            while time.monotonic() < deadline:
-                if all(t.done() for t in ctrl._warm_tickets):
-                    break
-                time.sleep(0.05)
-            resp = ctrl._warm_tickets[0].result(timeout=30)
+            # the warm fold actually lands
+            resp = ticket.result(timeout=30)
             assert resp.ok
             assert resp.request_id.startswith("warm-")
             assert ctrl.snapshot()["warmed"] == 1

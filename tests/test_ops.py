@@ -487,11 +487,9 @@ class TestBlockSparseKernel:
 
 
 class TestFusedAttentionGrad:
-    """The kernel's custom_vjp (r05): Pallas forward, XLA-recompute
-    backward — grads must match plain autodiff of the reference, and the
-    train path through the model must differentiate (the round-4 kernel
-    had no AD rule at all, so BENCH_PALLAS could never take a train
-    step)."""
+    """The kernel's custom_vjp: Pallas forward, Pallas backward (the
+    logits made again in VMEM, the bias cotangent summed over the rows in
+    the same grid): grads must match plain autodiff of the reference."""
 
     def test_grads_match_reference(self):
         q, k, v, bias = make_inputs(jax.random.PRNGKey(7))
@@ -538,20 +536,22 @@ class TestFusedAttentionGrad:
             np.asarray(jax.grad(f_kernel)(bias)),
             np.asarray(jax.grad(f_ref)(bias)), rtol=1e-4, atol=1e-5)
 
-    def test_differentiated_call_is_the_xla_attention(self):
-        """Under differentiation the custom_vjp's `fwd` stands in for the
-        kernel: the traced gradient holds no Pallas call, and its values ARE
-        the inline XLA path's (`xla_attention`, bias repeated, masks
-        filled), to the bit."""
+    def test_differentiated_call_is_the_kernel_forward_and_backward(self):
+        """Under differentiation the custom_vjp's `fwd` is the forward
+        kernel and its `bwd` the backward kernel: the traced gradient holds
+        two Pallas calls, three under `jax.checkpoint` (the forward runs
+        again), and no XLA attention; where `backward_admits` says no (here:
+        the queries are blocked) the rule is the one such a trace always had:
+        no Pallas call, and the inline XLA path's values to the bit."""
         heads, rows = 2, 3
         q, k, v, bias, mask = axial_inputs(
             jax.random.PRNGKey(5), 1, rows, heads, 64, 16, jnp.float32)
         unfold = lambda t: t.reshape(-1, heads, *t.shape[1:])
 
-        def through_kernel(q, k, v, bias):
+        def through_kernel(q, k, v, bias, **step):
             out = ops_attn.fused_attention(
                 q, k, v, bias, mask, mask, heads=heads, bias_repeat=rows,
-                interpret=True)
+                interpret=True, **step)
             return jnp.sum(out * out)
 
         def inline(q, k, v, bias):
@@ -560,16 +560,21 @@ class TestFusedAttentionGrad:
                 bias_repeat=rows)
             return jnp.sum(out * out)
 
+        calls = lambda f: str(jax.make_jaxpr(f)(q, k, v, bias)).count(
+            "pallas_call")
         grad = jax.grad(through_kernel, argnums=(0, 1, 2, 3))
-        assert "pallas_call" in str(jax.make_jaxpr(through_kernel)(
-            q, k, v, bias))
-        assert "pallas_call" not in str(jax.make_jaxpr(grad)(q, k, v, bias))
-        assert "pallas_call" not in str(jax.make_jaxpr(
-            jax.value_and_grad(jax.checkpoint(through_kernel)))(
-                q, k, v, bias))
-        for a, b in zip(grad(q, k, v, bias),
-                        jax.grad(inline, argnums=(0, 1, 2, 3))(q, k, v, bias)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert calls(through_kernel) == 1
+        assert calls(grad) == 2
+        assert calls(jax.value_and_grad(jax.checkpoint(through_kernel))) == 3
+        blocked = jax.grad(functools.partial(through_kernel, block_q=32),
+                           argnums=(0, 1, 2, 3))
+        assert not ops_attn.backward_admits(64, 64, 32)
+        assert calls(blocked) == 0
+        want = jax.grad(inline, argnums=(0, 1, 2, 3))(q, k, v, bias)
+        for a, b, c in zip(grad(q, k, v, bias), blocked(q, k, v, bias), want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       rtol=1e-4, atol=1e-5)
+            np.testing.assert_array_equal(np.asarray(b), np.asarray(c))
 
     def test_degenerate_tiles_fall_back(self):
         """Nq/Nk < 8 (e.g. 1x1 init-coverage pair maps) route to the XLA
@@ -583,3 +588,91 @@ class TestFusedAttentionGrad:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(
                 ops_attn.attention_reference(q, k, v)), atol=1e-6)
+
+
+# (batch, rows, heads, d, dtype, bias, masks, [k | v] whole, block_rows);
+# n = 64. masks: "both" = one mask for queries and keys whose first row is
+# fully padded, "keys" = a key mask alone whose first row has NO valid key
+BACKWARD_CASES = {
+    "head-pair-f32": (1, 3, 2, 64, jnp.float32, True, "both", True, None),
+    "head-pair-bf16": (2, 4, 2, 64, jnp.bfloat16, True, "both", True, None),
+    "head-pair-k-and-v-apart": (1, 3, 2, 64, jnp.float32, True, "both",
+                                False, None),
+    "two-head-pairs": (1, 2, 4, 64, jnp.float32, True, "both", True, None),
+    "heads-that-fill-no-lane-tile-f32": (1, 3, 2, 16, jnp.float32, True,
+                                         "both", True, None),
+    "heads-that-fill-no-lane-tile-bf16-apart": (1, 3, 2, 16, jnp.bfloat16,
+                                                True, "both", False, None),
+    "no-bias": (1, 4, 2, 64, jnp.float32, False, "both", True, None),
+    "no-masks": (1, 3, 2, 16, jnp.float32, True, None, True, None),
+    "no-bias-no-masks-bf16": (1, 4, 2, 16, jnp.bfloat16, False, None, False,
+                              None),
+    "bias-repeat-1": (3, 1, 2, 16, jnp.float32, True, "both", True, None),
+    "several-row-groups": (1, 8, 2, 16, jnp.float32, True, "both", True, 2),
+    "row-groups-that-do-not-divide": (2, 5, 2, 16, jnp.float32, True, "both",
+                                      True, 2),
+    "several-row-groups-head-pair-bf16": (1, 6, 2, 64, jnp.bfloat16, True,
+                                          "both", True, 4),
+    "a-row-with-no-valid-key": (1, 3, 2, 16, jnp.float32, True, "keys", True,
+                                None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
+def test_backward_kernel_matches_reference_grads(case):
+    """The backward kernel in interpret mode against `jax.grad` of
+    `attention_reference`, for q, k, v and the unrepeated bias: the bias
+    cotangent accumulates over every row that shares the bias, over several
+    grid steps where the rows take more than one, and a partial last step's
+    missing rows add nothing."""
+    batch, rows, heads, d, dtype, bias, masks, whole, block_rows = \
+        BACKWARD_CASES[case]
+    n = 64
+    q, k, v, b, mask = axial_inputs(jax.random.PRNGKey(rows + d), batch, rows,
+                                    heads, n, d, dtype, bias, bool(masks))
+    q_mask = mask if masks == "both" else None
+    kw = dict(heads=heads, bias_repeat=rows if bias else 1)
+    merged = lambda t: ops_attn.merge_heads(t.reshape(-1, heads, n, d))
+    weight = jax.random.normal(jax.random.PRNGKey(1), (batch * rows, n,
+                                                       heads * d))
+
+    def through_kernel(q, k, v, b):
+        k, v = (jnp.concatenate([k, v], axis=-1), None) if whole else (k, v)
+        out = ops_attn.fused_attention_merged(
+            q, k, v, b, q_mask, mask, interpret=True, block_rows=block_rows,
+            **kw)
+        return jnp.sum(out.astype(jnp.float32) * weight)
+
+    def reference(q, k, v, b):
+        out = ops_attn.attention_reference(q, k, v, b, q_mask, mask, **kw)
+        return jnp.sum(merged(out).astype(jnp.float32) * weight)
+
+    argnums = (0, 1, 2, 3) if bias else (0, 1, 2)
+    # jitted: one compile a side, not one for each of the reference's ops
+    got = jax.jit(jax.grad(through_kernel, argnums))(
+        merged(q), merged(k), merged(v), b)
+    want = jax.jit(jax.grad(reference, argnums))(q, k, v, b)
+    want = [merged(t) for t in want[:3]] + list(want[3:])
+    for name, a, w in zip("qkvb", got, want):
+        assert a.dtype == w.dtype and a.shape == w.shape, name
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(a).all(), name
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4,
+                                       err_msg=name)
+        else:   # bf16 operands into both programs' contractions
+            assert np.linalg.norm(a - w) < 0.03 * np.linalg.norm(w), name
+    if masks == "keys":
+        # every logit of the first row is the fill: no cotangent reaches q,
+        # k or the bias from it, and v has the uniform weights' share
+        dq, dk, dv = (np.asarray(t)[0] for t in got[:3])
+        assert not dq.any() and not dk.any() and dv.any()
+
+
+def test_backward_rule_follows_the_length():
+    """The backward kernel takes every length whose row of queries is one
+    grid step, which is every bucket of the cells and not 1,024."""
+    admits = ops_attn.backward_admits
+    assert [n for n in (64, 128, 192, 256, 384, 512, 640, 1024)
+            if admits(n, n)] == [64, 128, 192, 256, 384, 512, 640]
+    assert not admits(256, 256, 128)
