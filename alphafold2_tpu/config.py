@@ -63,16 +63,6 @@ class ModelConfig:
         return Alphafold2(
             **kwargs, dtype=jnp.bfloat16 if use_bf16 else jnp.float32)
 
-    def sparse_kwargs(self) -> Dict[str, int]:
-        """The one set of block-sparsity knobs, shared by the model-level
-        `sparse_self_attn` menu and the SERVING kernel policy
-        (`serve.KernelPolicy.from_model_config`, ISSUE 12): one source
-        so the pattern a model trains/evaluates under and the pattern
-        the serving executor routes long folds onto cannot drift."""
-        return {"block": self.sparse_block,
-                "num_global": self.sparse_num_global,
-                "window": self.sparse_window}
-
 
 def draft_preset(base: ModelConfig) -> ModelConfig:
     """The draft-tier config derived from a flagship config (ISSUE 19).

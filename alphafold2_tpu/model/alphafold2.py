@@ -37,7 +37,7 @@ from alphafold2_tpu.model.attention_variants import (
 )
 from alphafold2_tpu.model.evoformer import Evoformer, PairwiseAttentionBlock
 from alphafold2_tpu.model.mlm import MLM
-from alphafold2_tpu.model.primitives import Attention, Dense, LayerNorm
+from alphafold2_tpu.model.primitives import Attention, LayerNorm
 from alphafold2_tpu.model.refiners import (AtomEGNNRefiner,
                                             Refiner)
 from alphafold2_tpu.model.structure import StructureModule
@@ -233,9 +233,9 @@ class Alphafold2(nn.Module):
         def project_embed(e, prefix):
             e = e.astype(self.dtype)
             if e.shape[-1] != self.dim:
-                e = Dense(self.dim, param_dtype=jnp.float32,
-                          dtype=self.dtype,
-                          name=f"{prefix}_{e.shape[-1]}")(e)
+                e = nn.Dense(self.dim, param_dtype=jnp.float32,
+                             dtype=self.dtype,
+                             name=f"{prefix}_{e.shape[-1]}")(e)
             return e
 
         x_single = embed_tokens(seq)
@@ -273,8 +273,8 @@ class Alphafold2(nn.Module):
             if msa_mask is None:
                 msa_mask = jnp.ones_like(msa, dtype=bool)
         elif embedds is not None:
-            m = Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
-                      name="embedd_project")(embedds.astype(self.dtype))
+            m = nn.Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
+                         name="embedd_project")(embedds.astype(self.dtype))
             if msa_mask is None:
                 msa_mask = jnp.ones(embedds.shape[:-1], dtype=bool)
         else:
@@ -282,8 +282,8 @@ class Alphafold2(nn.Module):
         m = shard_msa(m)
 
         # pairwise representation by outer sum (reference alphafold2.py:715-717)
-        x_pair_proj = Dense(self.dim * 2, param_dtype=jnp.float32,
-                            dtype=self.dtype, name="to_pairwise_repr")(
+        x_pair_proj = nn.Dense(self.dim * 2, param_dtype=jnp.float32,
+                               dtype=self.dtype, name="to_pairwise_repr")(
                                    x_single)
         x_left, x_right = jnp.split(x_pair_proj, 2, axis=-1)
         x = x_left[:, :, None, :] + x_right[:, None, :, :]  # (b, i, j, d)
@@ -325,8 +325,8 @@ class Alphafold2(nn.Module):
         # templates (reference alphafold2.py:743-785)
         if templates_feats is not None:
             num_templates = templates_feats.shape[1]
-            t = Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
-                      name="to_template_embed")(
+            t = nn.Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
+                         name="to_template_embed")(
                              templates_feats.astype(self.dtype))
             t_mask_crossed = templates_mask[:, :, :, None] & \
                 templates_mask[:, :, None, :]
@@ -370,10 +370,10 @@ class Alphafold2(nn.Module):
         # alphafold2.py:782-785)
         if templates_angles is not None:
             t_angs = templates_angles.astype(self.dtype)
-            t_angle_feats = Dense(
+            t_angle_feats = nn.Dense(
                 self.dim, param_dtype=jnp.float32, dtype=self.dtype,
                 name="template_angle_mlp_in")(t_angs)
-            t_angle_feats = Dense(
+            t_angle_feats = nn.Dense(
                 self.dim, param_dtype=jnp.float32, dtype=self.dtype,
                 name="template_angle_mlp_out")(jax.nn.gelu(t_angle_feats))
             m = jnp.concatenate([m, t_angle_feats], axis=1)
@@ -434,8 +434,8 @@ class Alphafold2(nn.Module):
             if msa is not None or embedds is None:
                 # embedd_project ran only on the (msa-absent, embedds-given)
                 # path; create it otherwise
-                Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
-                      name="embedd_project")(zf(1, 1, 1, self.num_embedds))
+                nn.Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
+                         name="embedd_project")(zf(1, 1, 1, self.num_embedds))
             # projector coverage for every known pretrained-LM width plus
             # the configured num_embedds (skip widths this trace created)
             widths = {constants.MSA_EMBED_DIM, constants.PROTTRAN_EMBED_DIM,
@@ -444,13 +444,13 @@ class Alphafold2(nn.Module):
             msa_w = None if msa_embed is None else msa_embed.shape[-1]
             for w in sorted(widths):
                 if w != seq_w:
-                    Dense(self.dim, param_dtype=jnp.float32,
-                          dtype=self.dtype,
-                          name=f"seq_embed_project_{w}")(zf(1, 1, w))
+                    nn.Dense(self.dim, param_dtype=jnp.float32,
+                             dtype=self.dtype,
+                             name=f"seq_embed_project_{w}")(zf(1, 1, w))
                 if w != msa_w:
-                    Dense(self.dim, param_dtype=jnp.float32,
-                          dtype=self.dtype,
-                          name=f"msa_embed_project_{w}")(zf(1, 1, 1, w))
+                    nn.Dense(self.dim, param_dtype=jnp.float32,
+                             dtype=self.dtype,
+                             name=f"msa_embed_project_{w}")(zf(1, 1, 1, w))
             if not (train and original_msa is not None):
                 mlm(zf(1, 1, 1, self.dim), jnp.zeros((1, 1, 1), jnp.int32),
                     jnp.ones((1, 1, 1), bool))
@@ -464,8 +464,8 @@ class Alphafold2(nn.Module):
                          name="recycling_distance_embed")(
                              jnp.zeros((1, 1, 1), jnp.int32))
             if templates_feats is None:
-                t_d = Dense(self.dim, param_dtype=jnp.float32,
-                            dtype=self.dtype, name="to_template_embed")(
+                t_d = nn.Dense(self.dim, param_dtype=jnp.float32,
+                               dtype=self.dtype, name="to_template_embed")(
                                    zf(1, 1, 1, self.templates_dim))
                 t_d = PairwiseAttentionBlock(
                     dim=self.dim, heads=self.heads, dim_head=self.dim_head,
@@ -475,11 +475,11 @@ class Alphafold2(nn.Module):
                           name="template_pointwise_attn")(
                               zf(1, 1, self.dim), context=zf(1, 1, self.dim))
             if templates_angles is None:
-                a = Dense(self.dim, param_dtype=jnp.float32,
-                          dtype=self.dtype, name="template_angle_mlp_in")(
+                a = nn.Dense(self.dim, param_dtype=jnp.float32,
+                             dtype=self.dtype, name="template_angle_mlp_in")(
                                  zf(1, 1, 1, self.templates_angles_feats_dim))
-                Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
-                      name="template_angle_mlp_out")(jax.nn.gelu(a))
+                nn.Dense(self.dim, param_dtype=jnp.float32, dtype=self.dtype,
+                         name="template_angle_mlp_out")(jax.nn.gelu(a))
             if extra_msa is None:
                 Evoformer(dim=self.dim, depth=self.extra_msa_evoformer_layers,
                           heads=self.heads, dim_head=self.dim_head,
@@ -494,10 +494,10 @@ class Alphafold2(nn.Module):
         # theta / phi heads before symmetrization (reference alphafold2.py:815-817)
         x_f32 = x.astype(jnp.float32)
         if self.predict_angles:
-            ret_kwargs["theta"] = Dense(
+            ret_kwargs["theta"] = nn.Dense(
                 constants.THETA_BUCKETS, param_dtype=jnp.float32,
                 name="to_prob_theta")(x_f32)
-            ret_kwargs["phi"] = Dense(
+            ret_kwargs["phi"] = nn.Dense(
                 constants.PHI_BUCKETS, param_dtype=jnp.float32,
                 name="to_prob_phi")(x_f32)
 
@@ -505,7 +505,7 @@ class Alphafold2(nn.Module):
         trunk_embeds = (x_f32 + x_f32.swapaxes(1, 2)) * 0.5
         distance_pred = LayerNorm(
             dtype=jnp.float32, name="distogram_norm")(trunk_embeds)
-        distance_pred = Dense(
+        distance_pred = nn.Dense(
             constants.DISTOGRAM_BUCKETS, param_dtype=jnp.float32,
             name="to_distogram_logits")(distance_pred)
         ret_kwargs["distance"] = distance_pred
@@ -519,7 +519,7 @@ class Alphafold2(nn.Module):
         # omega head (reference alphafold2.py:834-836)
         if self.predict_angles:
             omega_input = trunk_embeds if self.symmetrize_omega else x_f32
-            ret_kwargs["omega"] = Dense(
+            ret_kwargs["omega"] = nn.Dense(
                 constants.OMEGA_BUCKETS, param_dtype=jnp.float32,
                 name="to_prob_omega")(omega_input)
 
@@ -532,11 +532,11 @@ class Alphafold2(nn.Module):
         # single / pairwise projections for the structure module
         # (reference alphafold2.py:843-851); fp32 island from here on
         single_msa_repr_row = m[:, 0]
-        single_repr = Dense(self.dim, param_dtype=jnp.float32,
-                            name="msa_to_single_repr_dim")(
+        single_repr = nn.Dense(self.dim, param_dtype=jnp.float32,
+                               name="msa_to_single_repr_dim")(
                                    single_msa_repr_row.astype(jnp.float32))
-        pairwise_repr = Dense(self.dim, param_dtype=jnp.float32,
-                              name="trunk_to_pairwise_repr_dim")(
+        pairwise_repr = nn.Dense(self.dim, param_dtype=jnp.float32,
+                                 name="trunk_to_pairwise_repr_dim")(
                                      x.astype(jnp.float32))
 
         if self.structure_module_type == "ipa":
@@ -589,10 +589,10 @@ class Alphafold2(nn.Module):
                     f"'egnn-atom', got "
                     f"{self.structure_module_refinement!r}")
 
-        # confidence head always built (cheap Dense(1)) so one params tree
+        # confidence head always built (cheap nn.Dense(1)) so one params tree
         # serves every return configuration
-        confidence = Dense(1, param_dtype=jnp.float32,
-                           name="lddt_linear")(single_out)
+        confidence = nn.Dense(1, param_dtype=jnp.float32,
+                              name="lddt_linear")(single_out)
         ret_kwargs["confidence"] = confidence
 
         if return_recyclables:

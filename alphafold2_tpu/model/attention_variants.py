@@ -34,7 +34,6 @@ from flax import linen as nn
 from jax import nn as jnn
 
 from alphafold2_tpu.model.primitives import (
-    Dense,
     MASK_VALUE,
     LayerNorm,
     attention_output_tail,
@@ -44,7 +43,7 @@ from alphafold2_tpu.runtime import on_tpu
 
 
 def _dense_factory(module_dtype):
-    return lambda f, name, use_bias=True, **kw: Dense(
+    return lambda f, name, use_bias=True, **kw: nn.Dense(
         f, use_bias=use_bias, dtype=module_dtype,
         param_dtype=jnp.float32, name=name, **kw)
 
@@ -350,9 +349,9 @@ class MultiKernelConvBlock(nn.Module):
                     dtype=self.dtype, param_dtype=jnp.float32,
                     name=f"conv_{kh}x{kw}_d{d}")(h))
         h = jnn.gelu(sum(branches) / len(branches))
-        out = Dense(self.dim, kernel_init=zeros_init(),
-                    bias_init=zeros_init(), dtype=self.dtype,
-                    param_dtype=jnp.float32, name="proj_out")(h)
+        out = nn.Dense(self.dim, kernel_init=zeros_init(),
+                       bias_init=zeros_init(), dtype=self.dtype,
+                       param_dtype=jnp.float32, name="proj_out")(h)
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
         return out
@@ -364,8 +363,7 @@ def block_sparse_block_pattern(n_blocks: int, num_global: int = 1,
     +-`window` blocks of the diagonal plus the first `num_global` blocks
     (global tokens). Delegates to `ops.block_sparse.
     banded_block_pattern` — the ONE local+global source the dense mask
-    below, the Pallas kernel plan, and the serving KernelPolicy's
-    static masks all share, so no two of them can diverge."""
+    below and the Pallas kernel plan share, so the two cannot diverge."""
     from alphafold2_tpu.ops.block_sparse import banded_block_pattern
     return banded_block_pattern(n_blocks, window=window,
                                 num_global=num_global)

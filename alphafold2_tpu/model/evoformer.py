@@ -155,11 +155,6 @@ class MsaAttentionBlock(nn.Module):
         x = AxialAttention(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,
             row_attn=False, col_attn=True, dropout=self.dropout,
-            # the column track attends ALIGNMENT rows — a serving
-            # KernelSpec's residue-axis block pattern must never apply
-            # here, even when msa_depth happens to equal the bucket
-            # length (ISSUE 12)
-            sparse_kernel_ok=False,
             dtype=self.dtype, name="col_attn",
         )(x, mask=mask, deterministic=deterministic) + x
         return shard_msa(x)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from alphafold2_tpu import constants
-from alphafold2_tpu.model.primitives import Dense
 
 
 def get_mask_subset_with_prob(rng, mask: jnp.ndarray, prob: float) -> jnp.ndarray:
@@ -87,8 +86,8 @@ class MLM(nn.Module):
     def __call__(self, seq_embed, original_seq, replaced_mask):
         """CE loss over replaced positions (reference mlm.py:86-92).
         seq_embed: (b, m, n, d); original_seq/replaced_mask: (b, m, n)."""
-        logits = Dense(self.num_tokens, param_dtype=jnp.float32,
-                       name="to_logits")(seq_embed.astype(jnp.float32))
+        logits = nn.Dense(self.num_tokens, param_dtype=jnp.float32,
+                          name="to_logits")(seq_embed.astype(jnp.float32))
         logp = jax.nn.log_softmax(logits, axis=-1)
         labels = jax.nn.one_hot(original_seq, self.num_tokens,
                                 dtype=logp.dtype)

@@ -18,19 +18,26 @@ Behavioral parity with the reference blocks
 
 TPU notes: weights live in fp32; activations run in `dtype` (bf16 by default
 under the train policy) so matmuls hit the MXU at full rate. Folding an axis
-into batch is a free reshape under XLA. Attention written as einsum + softmax
-+ einsum keeps its (rows x heads, n, n) logits in HBM and passes over them
-four times: two thirds of the 640 fold's device time (PERF.md section 5,
-PR 26). On a TPU every self-attention whose shape the fused kernel admits
-takes `ops.attention.fused_attention_merged` instead, on the projections as
-the Dense layers lay them out (no head is split off: the relayout copies
-cost more than the kernel; PERF.md section 6, PR 27); a differentiated trace
-takes it too, forward and backward kernel (PR 32).
+into batch is a free reshape under XLA.
+
+Which attention runs is decided in this file and `ops/attention.py`, from what
+the trace can see, with three outcomes and no switch a user sets:
+- the ring (`parallel/ring.py`) where `AxialAttention`'s attended axis is
+  sharded over a mesh;
+- the fused kernel (`ops.attention.fused_attention_merged`) where the trace is
+  on a TPU, on one device, for a self-attention with no tied rows and no
+  active dropout whose shape the kernel admits: the logits never reach HBM
+  (einsum + softmax + einsum kept them there and passed over them four times,
+  two thirds of the 640 fold's device time; PERF.md section 5, PR 26). It
+  takes the projections as the Dense layers lay them out (no head is split
+  off: the relayout copies cost more than the kernel; PERF.md section 6,
+  PR 27), and a differentiated trace takes it too, forward and backward
+  kernel (PR 32);
+- XLA's einsum + softmax + einsum otherwise: context, tied-row and meshed
+  attention, and the kernel's reference in the tests.
 """
 
 from __future__ import annotations
-
-import contextlib
 
 from typing import Optional
 
@@ -40,20 +47,6 @@ from jax import nn as jnn
 
 from alphafold2_tpu import runtime
 
-
-def Dense(features, **kw):
-    """`nn.Dense` whose contraction may route to the AMX host GEMM.
-
-    Identical to `flax.linen.Dense` (same params tree — flax names the
-    returned module by its class, `Dense_N`) except the contraction goes
-    through `ops.cpu_gemm.amx_dense_dot_general`, which dispatches eligible
-    f32 GEMMs to the native AMX kernel on the XLA:CPU fallback path and is
-    `lax.dot_general` bit-for-bit everywhere else (TPU path unchanged).
-    """
-    if "dot_general" not in kw:
-        from alphafold2_tpu.ops.cpu_gemm import amx_dense_dot_general
-        kw["dot_general"] = amx_dense_dot_general
-    return nn.Dense(features, **kw)
 
 # Large-negative fill for masked logits; -finfo.max in the reference
 # (alphafold2.py:165). A fixed large constant is safer in bf16.
@@ -99,14 +92,14 @@ class FeedForward(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
         x = LayerNorm(dtype=self.dtype)(x)
-        x = Dense(self.dim * self.mult * 2, dtype=self.dtype,
-                  param_dtype=jnp.float32)(x)
+        x = nn.Dense(self.dim * self.mult * 2, dtype=self.dtype,
+                     param_dtype=jnp.float32)(x)
         x = GEGLU()(x)
         x = nn.Dropout(self.dropout, deterministic=deterministic)(x)
         # zero-initialized output projection: the block starts as identity
         # w.r.t. the residual stream (reference init_zero_, alphafold2.py:90)
-        x = Dense(self.dim, dtype=self.dtype, param_dtype=jnp.float32,
-                  kernel_init=zeros_init(), bias_init=zeros_init())(x)
+        x = nn.Dense(self.dim, dtype=self.dtype, param_dtype=jnp.float32,
+                     kernel_init=zeros_init(), bias_init=zeros_init())(x)
         return x
 
 
@@ -147,7 +140,7 @@ class Attention(nn.Module):
 
     def setup(self):
         inner = self.heads * self.dim_head
-        dense = lambda features, name, use_bias=True, **kw: Dense(
+        dense = lambda features, name, use_bias=True, **kw: nn.Dense(
             features, use_bias=use_bias, dtype=self.dtype,
             param_dtype=jnp.float32, name=name, **kw)
         self._to_q = dense(inner, "to_q", use_bias=False)
@@ -189,9 +182,9 @@ class Attention(nn.Module):
         return self._gate_and_project(out, x)
 
     def _gate_and_project(self, out_merged, x):
-        """The tail after head merge — ONE owner for the gating semantics
-        so the XLA/Pallas/ring paths (via finish) and the token-major AMX
-        path cannot diverge."""
+        """The tail after head merge: ONE owner of the gating semantics
+        for the kernel's output (already merged) and, via `finish`, for
+        XLA's and the ring's."""
         if self.gating:
             out_merged = out_merged * jnn.sigmoid(self._gating(x))
         return self._to_out(out_merged)
@@ -211,90 +204,30 @@ class Attention(nn.Module):
         has_context = context is not None
 
         q_merged, kv_merged = self.project_merged(x, kv_input=context)
-        q, k, v = self._split_qkv(q_merged, kv_merged)      # (b, h, n, dh)
+        n_q, n_k = q_merged.shape[-2], kv_merged.shape[-2]
 
-        if mask is not None:
-            if has_context:
-                cmask = context_mask if context_mask is not None else \
-                    jnp.ones(k.shape[:1] + k.shape[-2:-1], dtype=bool)
-            else:
-                cmask = mask
-        else:
+        if mask is None:
             cmask = None
+        elif not has_context:
+            cmask = mask
+        elif context_mask is not None:
+            cmask = context_mask
+        else:
+            cmask = jnp.ones((kv_merged.shape[0], n_k), dtype=bool)
 
-        # serving-side kernel selection (ISSUE 12): a trace-time
-        # KernelSpec (ops/block_sparse.kernel_context — the executor
-        # activates it through predict.fold(kernel=)) reroutes matching
-        # SELF-attention (attended-axis length == spec.n, no context,
-        # no tie_dim) onto the true block-skipping Pallas kernel, pair
-        # bias and key masks riding along unrepeated; its masked-dense
-        # backend applies the same pattern as an additive bias instead
-        # (identical support, no FLOP skip — the CPU fallback and the
-        # numerics reference). Params are untouched either way: the
-        # kernel choice lives in which executable gets compiled.
-        from alphafold2_tpu.ops.block_sparse import active_kernel_spec
-        kspec = active_kernel_spec()
-        n_q, n_k = q.shape[-2], k.shape[-2]
-        if kspec is not None and (has_context or tie_dim is not None
-                                  or n_q != n_k
-                                  or not kspec.covers(n_q)):
-            kspec = None
-        sparse_backend = None
-        if kspec is not None:
-            sparse_backend = kspec.resolve_backend()
-            if sparse_backend == "pallas" and self.dropout > 0.0 \
-                    and not deterministic:
-                # the block-skipping kernel has no dropout; a training
-                # trace keeps the pattern via the masked-dense path
-                # (same refuse-don't-drop convention as the fused
-                # kernel below)
-                sparse_backend = "masked"
-        if sparse_backend == "pallas":
-            from alphafold2_tpu.ops.block_sparse import \
-                block_sparse_attention
-            b_all = q.shape[0]
-            bias_arg = None
-            if attn_bias is not None:
-                bias_arg = jnp.broadcast_to(
-                    attn_bias.astype(jnp.float32),
-                    (b_all // attn_bias_repeat, h, n_q, n_k)
-                ).reshape(-1, n_q, n_k)
-            out = block_sparse_attention(
-                q.reshape(b_all * h, n_q, dh),
-                k.reshape(b_all * h, n_k, dh),
-                v.reshape(b_all * h, n_k, dh),
-                kspec.pattern_array(),
-                bias=bias_arg, bias_repeat=attn_bias_repeat,
-                k_mask=cmask, heads=h,
-                scale=1.0,                # project_qkv pre-scales q
-                block=kspec.block,
-                interpret=kspec.interpret())
-            return self.finish(out.reshape(b_all, h, n_q, dh), x)
-        if sparse_backend == "masked":
-            # the pattern as a broadcastable additive bias: both the
-            # fused-Pallas and XLA dense paths below honor attn_bias,
-            # so the masked backend needs no further branching
-            fill = jnp.where(jnp.asarray(kspec.token_mask()), 0.0,
-                             MASK_VALUE).astype(jnp.float32)[None, None]
-            attn_bias = fill if attn_bias is None else \
-                attn_bias + fill.astype(attn_bias.dtype)
-
-        # the fused kernel (ops/attention.py: bias + mask + softmax + values
-        # with the logits in VMEM only) takes every attention the trace can
-        # see it applies to: a TPU backend, one device (GSPMD cannot
-        # partition the custom call over a mesh), self-attention, no tied
-        # rows, no active dropout (the kernel has none), a shape it admits.
-        # q and [k | v] go in and the output comes back as the Dense layers
-        # lay them out: no head is split off, moved or lane-padded. Bias
-        # stays *unrepeated* (replayed over the folded axial axis by the
-        # kernel's index map) and masks stay (b, n) vectors. Off the chip
-        # `use_pallas_attention` opens the same door for the CPU tests. A
-        # differentiated trace takes the same call: its custom_vjp runs the
-        # forward kernel and a backward kernel that makes the logits again
-        # in VMEM and sums the bias cotangent over the rows in its own grid
-        # (a length whose queries the forward blocks, 1,024, keeps the XLA
-        # attention's backward). Both paths share the gating/projection
-        # tail.
+        # ONE rule, from what the trace can see (the ring, the third
+        # outcome, is AxialAttention's and never reaches this call). The
+        # fused kernel (ops/attention.py) takes the attention on a TPU, on
+        # one device (GSPMD cannot partition the custom call over a mesh),
+        # for a self-attention with no tied rows and no active dropout (the
+        # kernel has none) of a shape it admits; off the chip
+        # `use_pallas_attention` opens the same door for the CPU tests. q
+        # and [k | v] go in and the output comes back as the Dense layers
+        # lay them out; the bias stays *unrepeated* (replayed over the
+        # folded axial axis by the kernel's index map) and the masks stay
+        # (b, n) vectors. A differentiated trace takes the same call (a
+        # length whose queries the forward blocks, 1,024, keeps the XLA
+        # attention's backward inside the kernel's custom_vjp).
         from alphafold2_tpu.ops import attention as fused
         from alphafold2_tpu.parallel.sharding import active_mesh
         dropping = self.dropout > 0.0 and not deterministic
@@ -303,33 +236,24 @@ class Attention(nn.Module):
                 and (mesh is None or mesh.size == 1)
                 and (runtime.on_tpu() or fused.pallas_attention_enabled())
                 and fused.admits(n_q, dh)):
-            b_all = q.shape[0]
             if attn_bias is not None:
                 # callers may pass broadcast-shaped bias, e.g. (1,1,n,n)
                 # from BlockSparseAttention; the kernel's index map needs
                 # the full (b, heads) leading shape
                 attn_bias = jnp.broadcast_to(
-                    attn_bias, (b_all // attn_bias_repeat, h, n_q, n_k)
+                    attn_bias,
+                    (x.shape[0] // attn_bias_repeat, h, n_q, n_k)
                 ).reshape(-1, n_q, n_k)
             out = fused.fused_attention_merged(
                 q_merged, kv_merged, bias=attn_bias, q_mask=mask,
                 k_mask=cmask, heads=h, bias_repeat=attn_bias_repeat)
             return self._gate_and_project(out, x)
 
-        # the attention contractions route to the AMX host GEMM on the CPU
-        # fallback path (ops/cpu_gemm.py; exact XLA einsums otherwise).
-        # When eligible, the NATURAL-layout ops consume q/k/v with heads
-        # minor to tokens ([b, n, h, dh], as the projections produce them
-        # modulo one cancelled moveaxis round-trip) and emit the output
-        # token-major — no [b,n,h,d]<->[b,h,n,d] transposes materialize
-        # around the custom calls (XLA folds the two inverse moveaxes
-        # away; an FFI boundary, unlike XLA's own dot, cannot absorb a
-        # layout change).
-        from alphafold2_tpu.ops.cpu_gemm import (amx_attention_dots,
-                                                 amx_attention_natural_ok,
-                                                 amx_attention_out,
-                                                 amx_attn_av, amx_attn_qk)
-
+        # everything else is XLA's einsum + softmax + einsum on
+        # (b, h, n, dh): context, tied-row, dropped-out and meshed
+        # attention, shapes the kernel refuses, and the kernel's own
+        # reference in the tests.
+        q, k, v = self._split_qkv(q_merged, kv_merged)
         if tie_dim is not None:
             # global-query attention: average queries across the tied rows
             # (the paper's MSAColumnGlobalAttention; reference
@@ -339,23 +263,13 @@ class Attention(nn.Module):
             k = k.reshape(b, tie_dim, *k.shape[1:])
             dots = jnp.einsum("bhid,brhjd->brhij", q, k)
             dots = dots.reshape(-1, *dots.shape[2:])
-            natural = False
         else:
-            q_n, k_n, v_n = (jnp.moveaxis(t, 1, -2) for t in (q, k, v))
-            natural = amx_attention_natural_ok(q_n, k_n)
-            dots = amx_attn_qk(q_n, k_n) if natural \
-                else amx_attention_dots(q, k)
+            dots = jnp.einsum("bhid,bhjd->bhij", q, k)
 
         attn = fused.attention_weights(dots, attn_bias, mask, cmask,
                                        bias_repeat=attn_bias_repeat)
         attn = self._drop(attn, deterministic=deterministic)
-
-        if natural:
-            out = amx_attn_av(attn, v_n)          # (b, n, h, dh)
-            return self._gate_and_project(
-                out.reshape(*x.shape[:-1], h * dh), x)
-        out = amx_attention_out(attn, v)
-        return self.finish(out, x)
+        return self.finish(jnp.einsum("bhij,bhjd->bhid", attn, v), x)
 
 
 class AxialAttention(nn.Module):
@@ -389,13 +303,6 @@ class AxialAttention(nn.Module):
     global_query_attn: bool = False
     dropout: float = 0.0
     ring_axes: Optional[tuple] = None   # (mesh axis of H, mesh axis of W)
-    # serving kernel selection (ISSUE 12): False suppresses any active
-    # ops.block_sparse KernelSpec for this attention — set on tracks
-    # whose attended axis is NOT the residue axis (the MSA column
-    # attention attends alignment rows; a residue-length pattern
-    # matching its length by coincidence would restrict the wrong
-    # axis). Params are unaffected (non-init field).
-    sparse_kernel_ok: bool = True
     dtype: jnp.dtype = jnp.float32
 
     def _ring_mesh(self, height, width):
@@ -449,9 +356,9 @@ class AxialAttention(nn.Module):
 
         bias = None
         if self.accept_edges and edges is not None:
-            bias = Dense(self.heads, use_bias=False, dtype=self.dtype,
-                         param_dtype=jnp.float32,
-                         name="edges_to_attn_bias")(edges)
+            bias = nn.Dense(self.heads, use_bias=False, dtype=self.dtype,
+                            param_dtype=jnp.float32,
+                            name="edges_to_attn_bias")(edges)
             bias = bias.transpose(0, 3, 1, 2)  # (b, heads, i, j)
 
         drop = dict(dropout_rate=self.dropout if dropout_key is not None
@@ -504,27 +411,20 @@ class AxialAttention(nn.Module):
         if self.accept_edges and edges is not None:
             # (b, i, j, d) -> per-head bias (b, heads, i, j), tiled over the
             # folded axis (reference alphafold2.py:214-217, :246-248)
-            bias = Dense(self.heads, use_bias=False, dtype=self.dtype,
-                         param_dtype=jnp.float32,
-                         name="edges_to_attn_bias")(edges)
+            bias = nn.Dense(self.heads, use_bias=False, dtype=self.dtype,
+                            param_dtype=jnp.float32,
+                            name="edges_to_attn_bias")(edges)
             attn_bias = bias.transpose(0, 3, 1, 2)  # (b, heads, i, j)
 
         tie_dim = axial_dim if self.global_query_attn else None
 
-        from alphafold2_tpu.ops.block_sparse import (active_kernel_spec,
-                                                     kernel_context)
-        ctx = kernel_context(None) if (not self.sparse_kernel_ok
-                                       and active_kernel_spec()
-                                       is not None) \
-            else contextlib.nullcontext()
-        with ctx:
-            out = Attention(
-                dim=self.dim, heads=self.heads, dim_head=self.dim_head,
-                dropout=self.dropout, dtype=self.dtype, name="attn",
-            )(x_fold, mask=mask_fold, attn_bias=attn_bias,
-              tie_dim=tie_dim,
-              attn_bias_repeat=axial_dim if attn_bias is not None else 1,
-              deterministic=deterministic)
+        out = Attention(
+            dim=self.dim, heads=self.heads, dim_head=self.dim_head,
+            dropout=self.dropout, dtype=self.dtype, name="attn",
+        )(x_fold, mask=mask_fold, attn_bias=attn_bias,
+          tie_dim=tie_dim,
+          attn_bias_repeat=axial_dim if attn_bias is not None else 1,
+          deterministic=deterministic)
 
         if self.col_attn:
             out = out.reshape(b, width, height, d).swapaxes(1, 2)
@@ -552,7 +452,7 @@ class TriangleMultiplicativeModule(nn.Module):
         assert x.shape[1] == x.shape[2], "feature map must be square"
         hidden = self.hidden_dim or self.dim
 
-        dense = lambda features, name, **kw: Dense(
+        dense = lambda features, name, **kw: nn.Dense(
             features, dtype=self.dtype, param_dtype=jnp.float32,
             name=name, **kw)
 
@@ -610,10 +510,10 @@ class OuterMean(nn.Module):
     def __call__(self, x, mask=None):
         hidden = self.hidden_dim or self.dim
         x = LayerNorm(dtype=self.dtype)(x)
-        left = Dense(hidden, dtype=self.dtype, param_dtype=jnp.float32,
-                     name="left_proj")(x)
-        right = Dense(hidden, dtype=self.dtype, param_dtype=jnp.float32,
-                      name="right_proj")(x)
+        left = nn.Dense(hidden, dtype=self.dtype, param_dtype=jnp.float32,
+                        name="left_proj")(x)
+        right = nn.Dense(hidden, dtype=self.dtype, param_dtype=jnp.float32,
+                         name="right_proj")(x)
 
         if mask is not None:
             m = mask.astype(x.dtype)  # (b, m, n)
@@ -631,5 +531,5 @@ class OuterMean(nn.Module):
             outer = jnp.einsum("bmid,bmjd->bijd", left, right)
             outer = outer / x.shape[1]
 
-        return Dense(self.dim, dtype=self.dtype, param_dtype=jnp.float32,
-                     name="proj_out")(outer)
+        return nn.Dense(self.dim, dtype=self.dtype, param_dtype=jnp.float32,
+                        name="proj_out")(outer)

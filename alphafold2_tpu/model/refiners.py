@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from alphafold2_tpu.model.primitives import Dense, LayerNorm, zeros_init
+from alphafold2_tpu.model.primitives import LayerNorm, zeros_init
 
 
 def _safe_norm2(v, eps=1e-8):
@@ -60,10 +60,10 @@ class EGNNLayer(nn.Module):
             feats.append(edges)
         msg_in = jnp.concatenate(feats, axis=-1)
 
-        msg = Dense(hidden, param_dtype=jnp.float32, name="edge_mlp_in")(
+        msg = nn.Dense(hidden, param_dtype=jnp.float32, name="edge_mlp_in")(
             msg_in)
         msg = jax.nn.silu(msg)
-        msg = Dense(hidden, param_dtype=jnp.float32, name="edge_mlp_out")(
+        msg = nn.Dense(hidden, param_dtype=jnp.float32, name="edge_mlp_out")(
             msg)
         msg = jax.nn.silu(msg)
 
@@ -76,8 +76,8 @@ class EGNNLayer(nn.Module):
 
         # equivariant coordinate update, zero-init scale so the layer starts
         # as identity on coordinates
-        coor_w = Dense(1, param_dtype=jnp.float32, use_bias=False,
-                       kernel_init=zeros_init(), name="coor_mlp")(msg)
+        coor_w = nn.Dense(1, param_dtype=jnp.float32, use_bias=False,
+                          kernel_init=zeros_init(), name="coor_mlp")(msg)
         coor_w = jnp.tanh(coor_w) * self.coor_clamp
         denom = jnp.maximum(
             (mask.astype(x.dtype).sum(-1) - 1.0)[:, None, None]
@@ -87,10 +87,10 @@ class EGNNLayer(nn.Module):
         # invariant feature update
         agg = msg.sum(axis=2) / denom
         h_in = jnp.concatenate([h, agg], axis=-1)
-        dh = Dense(hidden, param_dtype=jnp.float32, name="node_mlp_in")(
+        dh = nn.Dense(hidden, param_dtype=jnp.float32, name="node_mlp_in")(
             h_in)
         dh = jax.nn.silu(dh)
-        dh = Dense(self.dim, param_dtype=jnp.float32, name="node_mlp_out")(
+        dh = nn.Dense(self.dim, param_dtype=jnp.float32, name="node_mlp_out")(
             dh)
         return h + dh, x
 
@@ -114,23 +114,23 @@ class EnAttentionLayer(nn.Module):
         inner = hd * nh
 
         hn = LayerNorm(name="norm")(h)
-        q = Dense(inner, use_bias=False, param_dtype=jnp.float32,
-                  name="to_q")(hn).reshape(b, n, nh, hd)
-        k = Dense(inner, use_bias=False, param_dtype=jnp.float32,
-                  name="to_k")(hn).reshape(b, n, nh, hd)
-        v = Dense(inner, use_bias=False, param_dtype=jnp.float32,
-                  name="to_v")(hn).reshape(b, n, nh, hd)
+        q = nn.Dense(inner, use_bias=False, param_dtype=jnp.float32,
+                     name="to_q")(hn).reshape(b, n, nh, hd)
+        k = nn.Dense(inner, use_bias=False, param_dtype=jnp.float32,
+                     name="to_k")(hn).reshape(b, n, nh, hd)
+        v = nn.Dense(inner, use_bias=False, param_dtype=jnp.float32,
+                     name="to_v")(hn).reshape(b, n, nh, hd)
 
         rel = x[:, :, None, :] - x[:, None, :, :]
         dist2 = _safe_norm2(rel)
 
         logits = jnp.einsum("bihd,bjhd->bhij", q, k) * (hd ** -0.5)
         # distance-aware bias (+ optional pair-rep edge bias)
-        dist_bias = Dense(nh, param_dtype=jnp.float32,
-                          name="dist_to_bias")(jnp.log(dist2))
+        dist_bias = nn.Dense(nh, param_dtype=jnp.float32,
+                             name="dist_to_bias")(jnp.log(dist2))
         logits = logits + dist_bias.transpose(0, 3, 1, 2)
         if edges is not None:
-            logits = logits + Dense(
+            logits = logits + nn.Dense(
                 nh, use_bias=False, param_dtype=jnp.float32,
                 name="edge_to_bias")(edges).transpose(0, 3, 1, 2)
 
@@ -141,13 +141,13 @@ class EnAttentionLayer(nn.Module):
         attn = jax.nn.softmax(logits, axis=-1)              # (b, h, i, j)
 
         out = jnp.einsum("bhij,bjhd->bihd", attn, v).reshape(b, n, inner)
-        h = h + Dense(self.dim, param_dtype=jnp.float32,
-                      kernel_init=zeros_init(), bias_init=zeros_init(),
-                      name="to_out")(out)
+        h = h + nn.Dense(self.dim, param_dtype=jnp.float32,
+                         kernel_init=zeros_init(), bias_init=zeros_init(),
+                         name="to_out")(out)
 
         # equivariant coordinate update weighted by mean attention
-        coor_w = Dense(1, use_bias=False, param_dtype=jnp.float32,
-                       kernel_init=zeros_init(), name="coor_mlp")(
+        coor_w = nn.Dense(1, use_bias=False, param_dtype=jnp.float32,
+                          kernel_init=zeros_init(), name="coor_mlp")(
                               attn.mean(1)[..., None])
         coor_w = jnp.tanh(coor_w) * self.coor_clamp
         x = x + (rel / jnp.sqrt(dist2) * coor_w).sum(axis=2) / max(n - 1, 1)
@@ -235,24 +235,24 @@ class SparseEGNNLayer(nn.Module):
         msg_in = jnp.concatenate(
             [jnp.broadcast_to(h[:, :, None, :], (b, n_nodes, k, d)),
              h_j, dist2], axis=-1)
-        msg = jax.nn.silu(Dense(hidden, param_dtype=jnp.float32,
-                                name="edge_mlp_in")(msg_in))
-        msg = jax.nn.silu(Dense(hidden, param_dtype=jnp.float32,
-                                name="edge_mlp_out")(msg))
+        msg = jax.nn.silu(nn.Dense(hidden, param_dtype=jnp.float32,
+                                   name="edge_mlp_in")(msg_in))
+        msg = jax.nn.silu(nn.Dense(hidden, param_dtype=jnp.float32,
+                                   name="edge_mlp_out")(msg))
         msg = msg * live
 
-        coor_w = Dense(1, param_dtype=jnp.float32, use_bias=False,
-                       kernel_init=zeros_init(), name="coor_mlp")(msg)
+        coor_w = nn.Dense(1, param_dtype=jnp.float32, use_bias=False,
+                          kernel_init=zeros_init(), name="coor_mlp")(msg)
         coor_w = jnp.tanh(coor_w) * self.coor_clamp * live
         denom = jnp.maximum(live.sum(axis=2), 1.0)       # (b, N, 1)
         x = x + (rel / jnp.sqrt(dist2) * coor_w).sum(axis=2) / denom
 
         agg = msg.sum(axis=2) / denom
-        dh = jax.nn.silu(Dense(hidden, param_dtype=jnp.float32,
-                               name="node_mlp_in")(
+        dh = jax.nn.silu(nn.Dense(hidden, param_dtype=jnp.float32,
+                                  name="node_mlp_in")(
             jnp.concatenate([h, agg], axis=-1)))
-        dh = Dense(self.dim, param_dtype=jnp.float32,
-                   name="node_mlp_out")(dh)
+        dh = nn.Dense(self.dim, param_dtype=jnp.float32,
+                      name="node_mlp_out")(dh)
         if mask is not None:
             dh = dh * mask[:, :, None]
         return h + dh, x
@@ -294,8 +294,8 @@ class AtomEGNNRefiner(nn.Module):
             cloud = cloud * mask[..., None].astype(cloud.dtype)
 
         atom_tok = scn_atom_embedd(seq)                 # (b, L, 14)
-        h_atom = Dense(self.dim, param_dtype=jnp.float32,
-                       name="res_to_atom")(h_res)[:, :, None, :] + \
+        h_atom = nn.Dense(self.dim, param_dtype=jnp.float32,
+                          name="res_to_atom")(h_res)[:, :, None, :] + \
             nn.Embed(constants.NUM_ATOM_TOKENS, self.dim,
                      param_dtype=jnp.float32,
                      name="atom_id_embed")(atom_tok)

@@ -30,7 +30,7 @@ from flax import linen as nn
 
 from alphafold2_tpu.core.quaternion import quaternion_multiply as quat_multiply
 from alphafold2_tpu.core.rigid import Rigid
-from alphafold2_tpu.model.primitives import (MASK_VALUE, Dense, LayerNorm,
+from alphafold2_tpu.model.primitives import (MASK_VALUE, LayerNorm,
                                               zeros_init)
 
 
@@ -54,7 +54,7 @@ class InvariantPointAttention(nn.Module):
         h = self.heads
         x = single_repr
 
-        dense = lambda features, name, use_bias=True: Dense(
+        dense = lambda features, name, use_bias=True: nn.Dense(
             features, use_bias=use_bias, param_dtype=jnp.float32, name=name)
 
         # --- scalar qkv ---------------------------------------------------
@@ -100,8 +100,8 @@ class InvariantPointAttention(nn.Module):
 
         logits = scalar_logits + point_logits
         if pairwise_repr is not None:
-            pair_bias = Dense(h, use_bias=False, param_dtype=jnp.float32,
-                              name="pairwise_to_bias")(pairwise_repr)
+            pair_bias = nn.Dense(h, use_bias=False, param_dtype=jnp.float32,
+                                 name="pairwise_to_bias")(pairwise_repr)
             logits = logits + pair_bias.transpose(0, 3, 1, 2)
         logits = logits * w_l
 
@@ -131,9 +131,9 @@ class InvariantPointAttention(nn.Module):
         out = jnp.concatenate(outputs, axis=-1)
         # zero-init final projection (reference zero-inits ipa attn to_out,
         # alphafold2.py:615)
-        return Dense(self.dim, param_dtype=jnp.float32,
-                     kernel_init=zeros_init(), bias_init=zeros_init(),
-                     name="to_out")(out)
+        return nn.Dense(self.dim, param_dtype=jnp.float32,
+                        kernel_init=zeros_init(), bias_init=zeros_init(),
+                        name="to_out")(out)
 
 
 class IPABlock(nn.Module):
@@ -158,11 +158,11 @@ class IPABlock(nn.Module):
         hidden = self.dim * self.ff_mult
         ff = x
         for i in range(self.ff_num_layers - 1):
-            ff = Dense(hidden, param_dtype=jnp.float32,
-                       name=f"ff_{i}")(ff)
+            ff = nn.Dense(hidden, param_dtype=jnp.float32,
+                          name=f"ff_{i}")(ff)
             ff = jax.nn.relu(ff)
-        ff = Dense(self.dim, param_dtype=jnp.float32,
-                   name=f"ff_{self.ff_num_layers - 1}")(ff)
+        ff = nn.Dense(self.dim, param_dtype=jnp.float32,
+                      name=f"ff_{self.ff_num_layers - 1}")(ff)
         x = x + ff
         return LayerNorm(name="ff_norm")(x)
 
@@ -187,8 +187,8 @@ class StructureModule(nn.Module):
         b, n, _ = single_repr.shape
 
         block = IPABlock(dim=self.dim, heads=self.heads, name="ipa_block")
-        to_update = Dense(6, param_dtype=jnp.float32,
-                          name="to_quaternion_update")
+        to_update = nn.Dense(6, param_dtype=jnp.float32,
+                             name="to_quaternion_update")
         init = Rigid.identity((b, n), dtype=jnp.float32)
         quaternions, translations = init.quaternions, init.translations
 
@@ -218,8 +218,8 @@ class StructureModule(nn.Module):
             translations = translations + jnp.einsum(
                 "...c,...cd->...d", dt, frames.rotations)
 
-        points_local = Dense(3, param_dtype=jnp.float32,
-                             name="to_points")(x)
+        points_local = nn.Dense(3, param_dtype=jnp.float32,
+                                name="to_points")(x)
         frames = Rigid(quaternions, translations)
         coords = frames.apply_single(points_local)
 

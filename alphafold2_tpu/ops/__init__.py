@@ -7,11 +7,6 @@ from alphafold2_tpu.ops.attention import (  # noqa: F401
     use_pallas_attention,
 )
 from alphafold2_tpu.ops.block_sparse import (  # noqa: F401
-    KernelSpec,
-    active_kernel_spec,
     block_sparse_attention,
-    contact_block_pattern,
-    contact_probs_from_distogram,
-    kernel_context,
     plan_block_pattern,
 )

@@ -682,11 +682,9 @@ def xla_attention(q, k, v, bias=None, q_mask=None, k_mask=None, *,
     """The attention the model runs where the fused kernel does not apply,
     and under differentiation: (b, h, n, dh) operands, q pre-scaled,
     logits materialized in the activation dtype."""
-    from alphafold2_tpu.ops.cpu_gemm import (amx_attention_dots,
-                                             amx_attention_out)
-    attn = attention_weights(amx_attention_dots(q, k), bias, q_mask, k_mask,
-                             bias_repeat=bias_repeat)
-    return amx_attention_out(attn, v)
+    attn = attention_weights(jnp.einsum("bhid,bhjd->bhij", q, k), bias,
+                             q_mask, k_mask, bias_repeat=bias_repeat)
+    return jnp.einsum("bhij,bhjd->bhid", attn, v)
 
 
 def attention_reference(q, k, v, bias=None, q_mask=None, k_mask=None,

@@ -55,7 +55,6 @@ def fold(
     mask: Optional[jnp.ndarray] = None,
     msa_mask: Optional[jnp.ndarray] = None,
     num_recycles: int = DEFAULT_NUM_RECYCLES,
-    kernel=None,
     **extra,
 ) -> FoldResult:
     """Run the model with `num_recycles` recycling iterations.
@@ -63,13 +62,6 @@ def fold(
     `model` must be constructed with predict_coords=True. Jit-safe: wrap
     in jax.jit(partial(fold, model), static_argnames='num_recycles') or
     call under jit via a closure.
-
-    kernel: optional `ops.block_sparse.KernelSpec` — routes the trunk's
-    residue-axis self-attention through the block-skipping Pallas
-    kernel (or its masked-dense fallback) for this trace (ISSUE 12).
-    STATIC: bake it into the jitted closure like num_recycles; the
-    serving executor keys executables by its label. None (default) is
-    byte-for-byte the dense path.
     """
     assert model.predict_coords, "fold() needs predict_coords=True"
 
@@ -78,7 +70,7 @@ def fold(
         # (fold_init/fold_step) trace, so the step-loop == scan
         # exactness contract cannot drift between two call sites
         return _one_pass(model, params, seq, msa, mask, msa_mask,
-                         recyclables, extra, kernel=kernel)
+                         recyclables, extra)
 
     # first pass has no recyclables (params cover both traces via the
     # init-time branch coverage)
@@ -109,7 +101,7 @@ def fold(
 
 
 def _one_pass(model, params, seq, msa, mask, msa_mask, recyclables,
-              extra, kernel=None):
+              extra):
     """One trunk+structure pass — THE call fold()'s closure and the
     step-mode entry points (fold_init/fold_step) all trace, so the
     step-loop == scan exactness contract cannot drift between call
@@ -117,26 +109,12 @@ def _one_pass(model, params, seq, msa, mask, msa_mask, recyclables,
     split_rngs give each layer an INDEPENDENT FAVOR+ projection at
     inference (per-layer estimator errors average out instead of
     adding coherently); unused collections are harmless for models
-    without Performer layers.
-
-    `kernel` (a static ops.block_sparse.KernelSpec) activates the
-    serving kernel-selection context for exactly this trace: the
-    model's residue-axis self-attention reads it at trace time and
-    dispatches to the block-sparse kernel; the spec never reaches
-    model.apply as an argument, so the params/trace signature is
-    unchanged."""
-    import contextlib
-
-    from alphafold2_tpu.ops.block_sparse import kernel_context
-
-    ctx = kernel_context(kernel) if kernel is not None \
-        else contextlib.nullcontext()
-    with ctx:
-        return model.apply(
-            params, seq, msa=msa, mask=mask, msa_mask=msa_mask,
-            recyclables=recyclables, return_aux_logits=True,
-            return_recyclables=True,
-            rngs={"performer": jax.random.PRNGKey(0)}, **extra)
+    without Performer layers."""
+    return model.apply(
+        params, seq, msa=msa, mask=mask, msa_mask=msa_mask,
+        recyclables=recyclables, return_aux_logits=True,
+        return_recyclables=True,
+        rngs={"performer": jax.random.PRNGKey(0)}, **extra)
 
 
 def _step_state(coords, ret) -> FoldStepState:
@@ -145,7 +123,7 @@ def _step_state(coords, ret) -> FoldStepState:
 
 
 def fold_init(model, params, seq, msa=None, mask=None, msa_mask=None,
-              kernel=None, **extra) -> FoldStepState:
+              **extra) -> FoldStepState:
     """The embed+first-pass executable of step-mode folding: exactly
     fold(..., num_recycles=0), but returning a FoldStepState whose
     `recyclables` seed `fold_step`. Jit-safe the same way fold() is.
@@ -162,12 +140,12 @@ def fold_init(model, params, seq, msa=None, mask=None, msa_mask=None,
     is not covered."""
     assert model.predict_coords, "fold_init() needs predict_coords=True"
     coords, ret = _one_pass(model, params, seq, msa, mask, msa_mask,
-                            None, extra, kernel=kernel)
+                            None, extra)
     return _step_state(coords, ret)
 
 
 def fold_init_rows(model, params, seq, row_mask, state: FoldStepState,
-                   msa=None, mask=None, msa_mask=None, kernel=None,
+                   msa=None, mask=None, msa_mask=None,
                    **extra) -> FoldStepState:
     """Row-masked init: the continuous-batching admission program
     (ISSUE 11). Rows where `row_mask` is True are (re)initialized from
@@ -188,7 +166,7 @@ def fold_init_rows(model, params, seq, row_mask, state: FoldStepState,
     state: the carried FoldStepState whose non-admitted rows survive.
     """
     fresh = fold_init(model, params, seq, msa=msa, mask=mask,
-                      msa_mask=msa_mask, kernel=kernel, **extra)
+                      msa_mask=msa_mask, **extra)
 
     def sel(new, old):
         m = jnp.reshape(row_mask, row_mask.shape
@@ -249,17 +227,13 @@ def restore_step_state(snapshot):
 
 
 def fold_step(model, params, seq, recyclables: Recyclables, msa=None,
-              mask=None, msa_mask=None, kernel=None,
-              **extra) -> FoldStepState:
+              mask=None, msa_mask=None, **extra) -> FoldStepState:
     """One recycle iteration: the `lax.scan` body of fold() as its own
     executable. Feed it the previous state's `recyclables` (from
-    fold_init or an earlier fold_step). `kernel` may DIFFER from the
-    init pass's spec — the contact-prior flow (ISSUE 12) re-plans the
-    block mask from the recycle-1 pair activations and runs the
-    remaining recycles under the re-lowered step executable."""
+    fold_init or an earlier fold_step)."""
     assert model.predict_coords, "fold_step() needs predict_coords=True"
     coords, ret = _one_pass(model, params, seq, msa, mask, msa_mask,
-                            recyclables, extra, kernel=kernel)
+                            recyclables, extra)
     return _step_state(coords, ret)
 
 

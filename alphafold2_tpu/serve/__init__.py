@@ -25,13 +25,6 @@ The serving stack, bottom-up:
              `parallel.mesh`), concurrent disjoint-slice execution, and
              the analytic-HBM admission guard (README "Multi-chip
              serving")
-- kernelpolicy: KernelPolicy — pass `Scheduler(kernel_policy=
-             KernelPolicy.from_buckets(...))` and each length bucket
-             routes onto its own attention kernel: short buckets dense,
-             long buckets the block-skipping Pallas kernel
-             (ops/block_sparse.py), with optional per-target
-             contact-prior masks re-planned from recycle-1 pair
-             activations (README "Kernel selection")
 - recycle:   RecyclePolicy — pass `Scheduler(recycle_policy=
              RecyclePolicy(converge_tol=...))` and the scheduler owns
              the recycle loop: early-exit converged folds, preempt
@@ -117,8 +110,6 @@ from alphafold2_tpu.serve.features import (FeaturePool,  # noqa: F401
                                            express_featurize,
                                            featurize_raw,
                                            featurizer_config_digest)
-from alphafold2_tpu.ops.block_sparse import KernelSpec  # noqa: F401
-from alphafold2_tpu.serve.kernelpolicy import KernelPolicy  # noqa: F401
 from alphafold2_tpu.serve.meshpolicy import (AdmissionDecision,  # noqa: F401
                                              AdmissionPricer,
                                              DeviceSliceAllocator,

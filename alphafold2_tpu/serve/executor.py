@@ -67,20 +67,13 @@ from alphafold2_tpu.serve.meshpolicy import MeshShape, factor_chips, \
     mesh_label
 
 # (bucket_len, batch_size, msa_depth, num_recycles, mesh_shape,
-#  model_tag, variant, kernel) — variant in ("fold", "init", "step",
+#  model_tag, variant) — variant in ("fold", "init", "step",
 #  "init_rows"); init_rows (ISSUE 11) is the row-masked admission
 #  program of the continuous batcher, warmed alongside the init+step
 #  pair so a mid-loop row admission never pays a serving-path compile.
-#  kernel (ISSUE 12, see MIGRATING) names WHICH attention kernel the
-#  executable was lowered with: "dense" (the classic path) or a
-#  KernelSpec.label ("bs128x16-s1a2b3c4d" — block size, pattern
-#  content, and backend all in the digest), so a kernel-policy flip or
-#  a contact-prior re-plan re-lowers instead of serving a stale
-#  program — the same staleness invariant mesh_shape/model_tag carry.
-ExecKey = Tuple[int, int, int, int, MeshShape, str, str, str]
+ExecKey = Tuple[int, int, int, int, MeshShape, str, str]
 
 _SINGLE: MeshShape = (1, 1)
-_DENSE = "dense"
 _BATCH_INPUTS = ("seq", "mask", "msa", "msa_mask")
 
 
@@ -159,31 +152,23 @@ class FoldExecutor:
                             faults=self.faults,
                             model_tag=self.model_tag)
 
-    def _build(self, num_recycles: int, kernel=None):
-        def run(params, seq, mask, msa, msa_mask) -> FoldResult:
-            return fold(self.model, params, seq, msa=msa, mask=mask,
-                        msa_mask=msa_mask, num_recycles=num_recycles,
-                        kernel=kernel)
-
-        return jax.jit(run)
-
-    def _builder(self, variant: str, num_recycles: int, kernel=None):
+    def _builder(self, variant: str, num_recycles: int):
         """The jitted callable for one ExecKey variant: "fold" is the
         opaque all-recycles program, "init"/"step" the two halves of
         the scheduler-owned recycle loop (predict.fold_init/fold_step —
         the scan body as its own executable, so step-mode numerics
-        match the scan path exactly). `kernel` (a static
-        ops.block_sparse.KernelSpec, or None = dense) closes into the
-        program — it is part of WHAT gets compiled, which is why its
-        label lives in the ExecKey."""
+        match the scan path exactly)."""
         if variant == "fold":
-            return self._build(num_recycles, kernel=kernel)
+            def run(params, seq, mask, msa, msa_mask) -> FoldResult:
+                return fold(self.model, params, seq, msa=msa, mask=mask,
+                            msa_mask=msa_mask, num_recycles=num_recycles)
+
+            return jax.jit(run)
         if variant == "init":
             def run_init(params, seq, mask, msa,
                          msa_mask) -> FoldStepState:
                 return fold_init(self.model, params, seq, msa=msa,
-                                 mask=mask, msa_mask=msa_mask,
-                                 kernel=kernel)
+                                 mask=mask, msa_mask=msa_mask)
 
             return jax.jit(run_init)
         if variant == "init_rows":
@@ -191,7 +176,7 @@ class FoldExecutor:
                               row_mask, state) -> FoldStepState:
                 return fold_init_rows(self.model, params, seq, row_mask,
                                       state, msa=msa, mask=mask,
-                                      msa_mask=msa_mask, kernel=kernel)
+                                      msa_mask=msa_mask)
 
             return jax.jit(run_init_rows)
         if variant != "step":
@@ -200,13 +185,12 @@ class FoldExecutor:
         def run_step(params, seq, mask, msa, msa_mask,
                      recyclables) -> FoldStepState:
             return fold_step(self.model, params, seq, recyclables,
-                             msa=msa, mask=mask, msa_mask=msa_mask,
-                             kernel=kernel)
+                             msa=msa, mask=mask, msa_mask=msa_mask)
 
         return jax.jit(run_step)
 
     def _compile(self, cache_key: tuple, num_recycles: int, args,
-                 mesh=None, variant: str = "fold", kernel=None):
+                 mesh=None, variant: str = "fold"):
         """AOT-compile the key's executable OUTSIDE the cache lock (an
         XLA compile can take seconds; holding the lock would stall
         concurrent hit lookups) and insert it. Falls back to the lazily
@@ -214,7 +198,7 @@ class FoldExecutor:
         lowering refuses the argument structure. `mesh` (multi-chip
         slices only) is entered during lowering so the model's sharding
         constraints bake into the executable."""
-        jitted = self._builder(variant, num_recycles, kernel=kernel)
+        jitted = self._builder(variant, num_recycles)
         ctx = use_mesh(mesh) if mesh is not None \
             else contextlib.nullcontext()
         try:
@@ -246,7 +230,7 @@ class FoldExecutor:
 
     def key_for(self, batch: dict, num_recycles: int,
                 mesh_shape: Optional[MeshShape] = None,
-                variant: str = "fold", kernel=None) -> ExecKey:
+                variant: str = "fold") -> ExecKey:
         b, n = batch["seq"].shape
         shape = _SINGLE if mesh_shape is None \
             else tuple(int(x) for x in mesh_shape)
@@ -255,26 +239,25 @@ class FoldExecutor:
         # configured depth instead of minting one per config
         recycles = int(num_recycles) if variant == "fold" else 0
         return (int(n), int(b), msa_depth_of(batch), recycles,
-                shape, self.model_tag, variant,
-                _DENSE if kernel is None else kernel.label)
+                shape, self.model_tag, variant)
 
     def _normalize_key(self, key) -> ExecKey:
         """Accept legacy 4-tuple (len, batch, msa_depth, recycles),
-        5-tuple (+ mesh_shape), 6-tuple (+ model_tag), and 7-tuple
-        (+ variant) keys alongside the full 8-tuple — `warmup()`
-        callers predate the mesh/model_tag/variant/kernel elements."""
+        5-tuple (+ mesh_shape) and 6-tuple (+ model_tag) keys alongside
+        the full 7-tuple: `warmup()` and `profile()` callers predate the
+        mesh/model_tag/variant elements. Any other length is refused,
+        not cut: an element this executor does not know named a program
+        it does not build."""
         key = tuple(key)
-        if len(key) == 4:
-            return key + (_SINGLE, self.model_tag, "fold", _DENSE)
-        if len(key) == 5:
-            return key[:4] + (tuple(key[4]), self.model_tag, "fold",
-                              _DENSE)
-        if len(key) == 6:
-            return key[:4] + (tuple(key[4]), key[5], "fold", _DENSE)
-        if len(key) == 7:
-            return key[:4] + (tuple(key[4]),) + tuple(key[5:7]) \
-                + (_DENSE,)
-        return key[:4] + (tuple(key[4]),) + tuple(key[5:8])
+        if not 4 <= len(key) <= 7:
+            raise ValueError(
+                f"an ExecKey has 4 to 7 elements (bucket_len, batch, "
+                f"msa_depth, num_recycles[, mesh_shape[, model_tag[, "
+                f"variant]]]), got {len(key)}: {key!r}")
+        mesh_shape = tuple(key[4]) if len(key) > 4 else _SINGLE
+        model_tag = key[5] if len(key) > 5 else self.model_tag
+        variant = key[6] if len(key) > 6 else "fold"
+        return key[:4] + (mesh_shape, model_tag, variant)
 
     # -- device-slice plumbing -------------------------------------------
 
@@ -317,8 +300,7 @@ class FoldExecutor:
 
     def run(self, batch: dict, num_recycles: int,
             trace=NULL_TRACE, devices: Optional[Sequence] = None,
-            mesh_shape: Optional[MeshShape] = None,
-            kernel=None) -> FoldResult:
+            mesh_shape: Optional[MeshShape] = None) -> FoldResult:
         """Fold one assembled batch; blocks until device results land so
         the caller's latency measurement is honest. `trace` (a Trace /
         MultiTrace; NULL_TRACE default is zero-cost) gets a `compile`
@@ -330,42 +312,32 @@ class FoldExecutor:
         `mesh_shape` (i, j) factorizes it (default: squarest face); the
         trace additionally gets a `shard` span covering params/input
         placement and the fold span is tagged with the mesh label.
-
-        kernel: optional ops.block_sparse.KernelSpec (ISSUE 12) — the
-        attention kernel this batch's executable runs. Part of the
-        ExecKey, so dense and block-sparse executables for the same
-        bucket coexist in the LRU; fold spans are tagged with the
-        kernel label. None (default) is byte-for-byte the dense path.
         """
         if devices:
             return self._run_on_slice(batch, num_recycles, trace,
-                                      list(devices), mesh_shape, kernel)
-        key = self.key_for(batch, num_recycles, kernel=kernel)
+                                      list(devices), mesh_shape)
+        key = self.key_for(batch, num_recycles)
         args = (self.params, batch["seq"], batch["mask"], batch["msa"],
                 batch["msa_mask"])
         cache_key = key + ((),)
-        ktag = {} if kernel is None else {"kernel": kernel.label}
         fn = self._lookup(cache_key)
         if fn is None:
             with trace.span("compile", bucket_len=key[0],
                             batch_size=key[1], msa_depth=key[2],
-                            num_recycles=key[3], **ktag):
-                fn = self._compile(cache_key, key[3], args,
-                                   kernel=kernel)
-        with trace.span("fold", bucket_len=key[0], **ktag):
+                            num_recycles=key[3]):
+                fn = self._compile(cache_key, key[3], args)
+        with trace.span("fold", bucket_len=key[0]):
             return self._invoke(fn, args, batch, trace=trace)
 
     def _run_on_slice(self, batch: dict, num_recycles: int, trace,
-                      devices, mesh_shape, kernel=None) -> FoldResult:
+                      devices, mesh_shape) -> FoldResult:
         if mesh_shape is None:
             mesh_shape = factor_chips(len(devices))
         mesh_shape = tuple(int(x) for x in mesh_shape)
         label = mesh_label(mesh_shape)
-        key = self.key_for(batch, num_recycles, mesh_shape=mesh_shape,
-                           kernel=kernel)
+        key = self.key_for(batch, num_recycles, mesh_shape=mesh_shape)
         dev_ids = tuple(int(d.id) for d in devices)
         cache_key = key + (dev_ids,)
-        ktag = {} if kernel is None else {"kernel": kernel.label}
         with trace.span("shard", mesh=label, devices=len(devices)):
             mesh, params = self._placed_params(devices, mesh_shape)
             args = (params,) + self._place_inputs(batch, mesh, devices)
@@ -373,10 +345,9 @@ class FoldExecutor:
         if fn is None:
             with trace.span("compile", bucket_len=key[0],
                             batch_size=key[1], msa_depth=key[2],
-                            num_recycles=key[3], mesh=label, **ktag):
-                fn = self._compile(cache_key, key[3], args, mesh=mesh,
-                                   kernel=kernel)
-        with trace.span("fold", bucket_len=key[0], mesh=label, **ktag):
+                            num_recycles=key[3], mesh=label):
+                fn = self._compile(cache_key, key[3], args, mesh=mesh)
+        with trace.span("fold", bucket_len=key[0], mesh=label):
             # the lazy-compile fallback traces on first call, so the
             # mesh context must be live during invocation too
             ctx = use_mesh(mesh) if mesh is not None \
@@ -388,24 +359,20 @@ class FoldExecutor:
 
     def run_init(self, batch: dict, trace=NULL_TRACE,
                  devices: Optional[Sequence] = None,
-                 mesh_shape: Optional[MeshShape] = None,
-                 kernel=None) -> FoldStepState:
+                 mesh_shape: Optional[MeshShape] = None) -> FoldStepState:
         """The embed+first-pass executable: recycle iteration 0 of the
         scheduler-owned loop (`serve.recycle.RecyclePolicy`). Blocks
         until the device result lands. Spans: `compile` when the
         init-variant signature is built fresh, `fold` for the execution
         itself (the obs checker's accelerator-time rule keys off a
-        non-zero fold span, and this IS the fold's first pass).
-        `kernel` — see run()."""
+        non-zero fold span, and this IS the fold's first pass)."""
         return self._run_stepmode("init", batch, (), trace, devices,
-                                  mesh_shape, span="fold", attrs={},
-                                  kernel=kernel)
+                                  mesh_shape, span="fold", attrs={})
 
     def run_init_rows(self, batch: dict, state: FoldStepState,
                       row_mask, trace=NULL_TRACE,
                       devices: Optional[Sequence] = None,
                       mesh_shape: Optional[MeshShape] = None,
-                      kernel=None,
                       span_attrs: Optional[dict] = None) -> FoldStepState:
         """Row-masked admission init (continuous batching, ISSUE 11):
         rows where `row_mask` is True restart at iteration 0 from the
@@ -424,14 +391,13 @@ class FoldExecutor:
             attrs.update(span_attrs)
         return self._run_stepmode(
             "init_rows", batch, (mask_arr, state), trace, devices,
-            mesh_shape, span="admit", attrs=attrs, kernel=kernel)
+            mesh_shape, span="admit", attrs=attrs)
 
     def run_step(self, batch: dict, state: FoldStepState,
                  recycle_index: int, trace=NULL_TRACE,
                  devices: Optional[Sequence] = None,
                  mesh_shape: Optional[MeshShape] = None,
-                 span_attrs: Optional[dict] = None,
-                 kernel=None) -> FoldStepState:
+                 span_attrs: Optional[dict] = None) -> FoldStepState:
         """One recycle iteration: feeds `state.recyclables` (from
         run_init or a previous run_step on the same slice) through the
         step executable. Span: `recycle`, tagged with the iteration
@@ -443,19 +409,17 @@ class FoldExecutor:
             attrs.update(span_attrs)
         return self._run_stepmode(
             "step", batch, (state.recyclables,), trace, devices,
-            mesh_shape, span="recycle", attrs=attrs, kernel=kernel)
+            mesh_shape, span="recycle", attrs=attrs)
 
     def _run_stepmode(self, variant: str, batch: dict, extra_args,
                       trace, devices, mesh_shape, span: str,
-                      attrs: dict, kernel=None):
+                      attrs: dict):
         """Shared lookup/compile/execute path for the init/step
         variants, covering both the single-chip and device-slice
         cases. `extra_args` (the step's carried recyclables) ride after
         the placed batch inputs; they are prior outputs of this very
         slice, so they are already resident where the executable
         expects them."""
-        if kernel is not None:
-            attrs = dict(attrs, kernel=kernel.label)
         if devices:
             devices = list(devices)
             if mesh_shape is None:
@@ -463,7 +427,7 @@ class FoldExecutor:
             mesh_shape = tuple(int(x) for x in mesh_shape)
             label = mesh_label(mesh_shape)
             key = self.key_for(batch, 0, mesh_shape=mesh_shape,
-                               variant=variant, kernel=kernel)
+                               variant=variant)
             dev_ids = tuple(int(d.id) for d in devices)
             cache_key = key + (dev_ids,)
             # the batch inputs are identical across a step loop's
@@ -484,8 +448,7 @@ class FoldExecutor:
             attrs = dict(attrs, mesh=label)
         else:
             mesh = None
-            key = self.key_for(batch, 0, variant=variant,
-                               kernel=kernel)
+            key = self.key_for(batch, 0, variant=variant)
             cache_key = key + ((),)
             args = (self.params, batch["seq"], batch["mask"],
                     batch["msa"], batch["msa_mask"]) + tuple(extra_args)
@@ -494,10 +457,10 @@ class FoldExecutor:
             with trace.span("compile", bucket_len=key[0],
                             batch_size=key[1], msa_depth=key[2],
                             variant=variant,
-                            **{k: attrs[k] for k in ("mesh", "kernel")
-                               if k in attrs}):
+                            **({"mesh": attrs["mesh"]} if "mesh" in attrs
+                               else {})):
                 fn = self._compile(cache_key, 0, args, mesh=mesh,
-                                   variant=variant, kernel=kernel)
+                                   variant=variant)
         with trace.span(span, bucket_len=key[0], **attrs):
             ctx = use_mesh(mesh) if mesh is not None \
                 else contextlib.nullcontext()
@@ -532,8 +495,7 @@ class FoldExecutor:
                timer=None, devices: Optional[Sequence] = None,
                mesh_shape: Optional[MeshShape] = None,
                step_mode: bool = False,
-               continuous: bool = False,
-               kernel=None) -> int:
+               continuous: bool = False) -> int:
         """Compile (and discard) each key's signature with a zero batch.
         Keys may be legacy 4-tuples (len, batch, msa_depth, recycles) or
         full ExecKeys; `devices`/`mesh_shape` warm the slice-bound
@@ -545,9 +507,6 @@ class FoldExecutor:
         (step_mode only) additionally warms the row-masked `init_rows`
         admission program, so a continuous batcher's first mid-loop row
         admission never triggers a mid-serving compile (ISSUE 11).
-        `kernel` (a KernelSpec, ISSUE 12) warms the kernel-variant
-        executable the kernel policy will actually route to this
-        bucket — the scheduler passes each bucket's own spec.
         Returns the number of fresh compiles. Optional `timer` is a
         profiling.StepTimer measuring each warmup (== compile+first-run)
         wall time."""
@@ -558,30 +517,22 @@ class FoldExecutor:
             before = self.misses
             batch = _zero_batch(bucket_len, batch_size, msa_depth)
 
-            # a spec only covers its own bucket length: warming a key
-            # of another bucket under it would label a dense program
-            # with a sparse key — guard here so one warmup() call may
-            # mix kernel'd and plain keys safely
-            k_spec = kernel if (kernel is not None
-                                and kernel.covers(bucket_len)) else None
-
             def _one():
                 if step_mode:
                     state = self.run_init(batch, devices=devices,
-                                          mesh_shape=mesh_shape,
-                                          kernel=k_spec)
+                                          mesh_shape=mesh_shape)
                     if continuous:
                         # shape-only warm: the mask values never change
                         # the compiled program, only which rows reinit
                         mask0 = jnp.zeros((batch_size,), bool)
                         state = self.run_init_rows(
                             batch, state, mask0, devices=devices,
-                            mesh_shape=mesh_shape, kernel=k_spec)
+                            mesh_shape=mesh_shape)
                     self.run_step(batch, state, 0, devices=devices,
-                                  mesh_shape=mesh_shape, kernel=k_spec)
+                                  mesh_shape=mesh_shape)
                 else:
                     self.run(batch, num_recycles, devices=devices,
-                             mesh_shape=mesh_shape, kernel=k_spec)
+                             mesh_shape=mesh_shape)
 
             if timer is not None:
                 with timer.measure():

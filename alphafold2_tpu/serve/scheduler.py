@@ -267,17 +267,15 @@ class _StepCheckpoint:
     the survivors at their checkpointed ages instead of requeueing the
     loop to recycle 0."""
 
-    __slots__ = ("state", "host", "rows", "ages", "active", "step",
-                 "kernel")
+    __slots__ = ("state", "host", "rows", "ages", "active", "step")
 
-    def __init__(self, state, host, rows, ages, active, step, kernel):
+    def __init__(self, state, host, rows, ages, active, step):
         self.state = state
         self.host = host
         self.rows = rows
         self.ages = ages
         self.active = active
         self.step = step
-        self.kernel = kernel
 
 
 class Scheduler:
@@ -335,17 +333,6 @@ class Scheduler:
         one step at a time: early-exit on convergence, preemption
         between recycles, progressive results — see the module
         docstring and serve/recycle.py.
-    kernel_policy: optional serve.kernelpolicy.KernelPolicy (OFF when
-        None — the default, byte-for-byte the dense-only serving
-        path). Per-bucket attention-kernel routing (ISSUE 12): short
-        buckets compile the dense path, long buckets the block-sparse
-        Pallas kernel with a static banded+global mask; with
-        `contact_priors=True` under a recycle policy, each batch
-        re-plans its mask from its own recycle-1 distogram and the
-        remaining recycles run the re-lowered step executable. The
-        kernel choice is an ExecKey element, so a policy flip can
-        never serve a stale executable, and warmup() pre-compiles each
-        bucket's chosen kernel.
     slo: optional obs.slo.SLOEngine (OFF when None — the default,
         which keeps serve_stats() keys and the registry metric-name
         set byte-identical). Declarative per-QoS-class objectives
@@ -381,7 +368,6 @@ class Scheduler:
                  mesh_policy: Optional[MeshPolicy] = None,
                  recycle_policy: Optional[RecyclePolicy] = None,
                  feature_pool=None,
-                 kernel_policy=None,
                  slo=None,
                  key_log=None,
                  bulk=None,
@@ -649,20 +635,6 @@ class Scheduler:
                 executor.max_entries = max(
                     executor.max_entries,
                     per_bucket * len(self.buckets.edges))
-        # per-bucket attention-kernel routing (ISSUE 12) — nothing below
-        # touches the serving path when the policy is None
-        self.kernel_policy = kernel_policy
-        self._kernel_served: Dict[Tuple[str, int], int] = {}
-        self._kernel_batches: Dict[Tuple[str, int], int] = {}
-        if kernel_policy is not None:
-            self._c_kernel_folds = reg.counter(
-                "serve_kernel_folds_total",
-                "requests served, by attention kernel and bucket",
-                ("kernel", "bucket"))
-            self._c_kernel_replans = reg.counter(
-                "serve_kernel_contact_replans_total",
-                "step loops whose block mask was re-planned from "
-                "recycle-1 contact priors (re-lowered step executable)")
         if self.config.parked_bytes_budget > 0 or cache is not None:
             self._c_parked_admits = reg.counter(
                 "serve_parked_admits_total",
@@ -1019,16 +991,8 @@ class Scheduler:
         step_mode = self._use_step_loop()
         continuous = self._use_continuous()
         if self._allocator is None:
-            if self.kernel_policy is None:
-                return self.executor.warmup(keys, step_mode=step_mode,
-                                            continuous=continuous)
-            # per-bucket kernel routing (ISSUE 12): warm the executable
-            # each bucket will ACTUALLY serve — a sparse-routed bucket
-            # compiled dense here would still pay its kernel compile on
-            # the first real request
-            return sum(self.executor.warmup(
-                [key], step_mode=step_mode, continuous=continuous,
-                kernel=self._kernel_spec_for(key[0])) for key in keys)
+            return self.executor.warmup(keys, step_mode=step_mode,
+                                        continuous=continuous)
         fresh = 0
         for key in keys:
             if not self.mesh_policy.admits(
@@ -1038,12 +1002,10 @@ class Scheduler:
                 continue     # the guard rejects this bucket at submit;
                 #              compiling it would be the OOM we prevent
             shape = self.mesh_policy.shape_for(key[0])
-            k_kw = {} if self.kernel_policy is None else \
-                {"kernel": self._kernel_spec_for(key[0])}
             for devices in self._allocator.slices(shape):
                 fresh += self.executor.warmup(
                     [key], devices=devices, mesh_shape=shape,
-                    step_mode=step_mode, continuous=continuous, **k_kw)
+                    step_mode=step_mode, continuous=continuous)
         return fresh
 
     def _use_step_loop(self) -> bool:
@@ -1070,33 +1032,6 @@ class Scheduler:
         counting on mid-loop row admission to top it up. Only
         meaningful when admission can actually run."""
         return self._use_continuous() and self.recycle_policy.eager_form
-
-    # -- kernel selection (ISSUE 12) -------------------------------------
-
-    def _kernel_spec_for(self, bucket_len: int):
-        """The static first-pass KernelSpec this bucket serves under
-        the kernel policy (None = dense / policy off)."""
-        if self.kernel_policy is None:
-            return None
-        return self.kernel_policy.spec_for(bucket_len)
-
-    def _record_kernel_batch(self, bucket_len: int, spec, n_served: int,
-                             contact: bool = False):
-        """Per-(kernel, bucket) accounting for one executed batch.
-        No-op without a policy — `serve_stats()` stays byte-identical."""
-        if self.kernel_policy is None:
-            return
-        kind = "dense" if spec is None else "blocksparse"
-        if contact:
-            kind += "-contact"
-        key = (kind, bucket_len)
-        with self._cond:
-            self._kernel_served[key] = \
-                self._kernel_served.get(key, 0) + n_served
-            self._kernel_batches[key] = \
-                self._kernel_batches.get(key, 0) + 1
-        self._c_kernel_folds.inc(n_served, kernel=kind,
-                                 bucket=str(bucket_len))
 
     # -- submission ------------------------------------------------------
 
@@ -2452,16 +2387,6 @@ class Scheduler:
                 # when the feature is off so baselines compare)
                 cross_bucket_admissions=self._n_cross_admissions,
                 cross_bucket_refusals=self._n_cross_refusals)
-        if self.kernel_policy is not None:
-            with self._cond:
-                folds = {f"{kind}:{bucket}":
-                         {"batches": self._kernel_batches.get(
-                             (kind, bucket), 0),
-                          "served": served}
-                         for (kind, bucket), served
-                         in sorted(self._kernel_served.items())}
-            stats["kernel"] = dict(self.kernel_policy.snapshot(),
-                                   folds=folds)
         if self.feature_pool is not None:
             stats["featurize"] = self.feature_pool.snapshot()
         if self.key_log is not None:
@@ -2891,9 +2816,7 @@ class Scheduler:
                 batch, waste = self.buckets.assemble(
                     [e.request for e in entries], bucket_len,
                     cfg.max_batch_size, msa_depth=cfg.msa_depth)
-            kspec = self._kernel_spec_for(bucket_len)
-            result = self._run_executor(batch, batch_trace, lease,
-                                        kernel=kspec)
+            result = self._run_executor(batch, batch_trace, lease)
             t_ran = time.monotonic()
             with self.tracer.annotate("fetch"):
                 coords = np.asarray(result.coords)
@@ -2929,7 +2852,6 @@ class Scheduler:
         now, real_tokens = resolved
         if lease is not None:
             self._c_mesh_folds.inc(mesh=lease.label)
-        self._record_kernel_batch(bucket_len, kspec, len(entries))
         with self._cond:
             if lease is not None:
                 self._mesh_batches[lease.label] = \
@@ -3107,14 +3029,6 @@ class Scheduler:
                 batch, waste = self.buckets.assemble(
                     [e.request for e in entries], bucket_len,
                     cfg.max_batch_size, msa_depth=cfg.msa_depth)
-            # kernel routing (ISSUE 12): the init pass always runs the
-            # bucket's STATIC first-pass spec (warmup pre-compiled it);
-            # step_kernel is what the remaining recycles run — the
-            # contact-prior flow below may re-plan it per target
-            kspec = self._kernel_spec_for(bucket_len)
-            init_kw = {} if kspec is None else {"kernel": kspec}
-            step_kernel = kspec
-            contact_planned = False
             state = None
             while active:
                 try:
@@ -3122,7 +3036,7 @@ class Scheduler:
                     state = self._run_step_guarded(
                         lambda: self.executor.run_init(
                             batch, trace=batch_trace, devices=devices,
-                            mesh_shape=mesh_shape, **init_kw))
+                            mesh_shape=mesh_shape))
                     break
                 except Exception as exc:
                     # per-row poison isolation at the FIRST pass: a
@@ -3138,9 +3052,8 @@ class Scheduler:
             if state is None:
                 # every founder was isolated poison: nothing to fold
                 self._finish_step_batch(bucket_len, entries,
-                                        all_members, lease, kspec,
-                                        contact_planned, any_nonfinite,
-                                        waste, t0)
+                                        all_members, lease,
+                                        any_nonfinite, waste, t0)
                 return
             # durable resume (ISSUE 18): a founder whose fold died
             # with a spilled checkpoint (this process's previous life,
@@ -3152,43 +3065,6 @@ class Scheduler:
                 state = self._resume_from_spill(
                     state, active, rows, ages, range(len(active)))
 
-            def _plan_contact(st, members):
-                """Re-plan the step mask from the batch's OWN pair
-                activations (the recycle-1 distogram st carries): the
-                remaining recycles run a re-lowered step executable
-                under the planned pattern — or DENSE when the plan
-                degenerates to nearly-all-live. Planning trouble keeps
-                the static mask (an observability loss, never a
-                serving one). Per-row REAL lengths ride along (via the
-                live position->row map) so dead rows — and the padding
-                region of a shorter admitted fold (ISSUE 13) — plan as
-                dead blocks, never as garbage-live ones."""
-                try:
-                    row_lengths = [0] * cfg.max_batch_size
-                    for pos in range(len(active)):
-                        row_lengths[rows[pos]] = \
-                            active[pos].request.length
-                    planned = self.kernel_policy.contact_spec_for(
-                        bucket_len, np.asarray(st.distogram),
-                        lengths=row_lengths)
-                except Exception:
-                    return kspec, False
-                self._c_kernel_replans.inc()
-                for e in members:
-                    e.trace.event(
-                        "kernel_contact_replan",
-                        kernel=("dense" if planned is None
-                                else planned.label),
-                        live_frac=(1.0 if planned is None
-                                   else round(planned.live_fraction,
-                                              4)))
-                return planned, True
-
-            if self.kernel_policy is not None \
-                    and self.kernel_policy.contact_priors \
-                    and kspec is not None:
-                step_kernel, contact_planned = _plan_contact(state,
-                                                             active)
             # the per-step device-to-host fetch exists for convergence
             # deltas and streaming (and the per-step non-finite scan of
             # row isolation); a preemption-only policy needs none of
@@ -3209,7 +3085,7 @@ class Scheduler:
                 # checkpoint 0: a failure at the very first step already
                 # resumes at the init state instead of requeueing
                 ckpt = self._checkpoint_loop(state, batch, active, rows,
-                                             ages, 0, step_kernel)
+                                             ages, 0)
             # every surviving row has age < num_recycles (full-depth
             # rows retire inside the loop), so the condition only
             # gates entry: num_recycles == 0 skips straight to the
@@ -3247,8 +3123,6 @@ class Scheduler:
                             step_kw["span_attrs"] = {
                                 "rows_live": len(active),
                                 "rows_total": cfg.max_batch_size}
-                        if step_kernel is not None:
-                            step_kw["kernel"] = step_kernel
                         t_step = time.monotonic()
                         t_attempt = t_step
                         state = self._run_step_guarded(
@@ -3448,22 +3322,7 @@ class Scheduler:
                             batch, state, admitted = self._admit_rows(
                                 bucket_len, batch, state, active, rows,
                                 ages, all_members, devices, mesh_shape,
-                                inline=lease is None, gap=r,
-                                kernel=kspec)
-                            if admitted and contact_planned:
-                                # admitted rows' first pass just landed
-                                # in the distogram: re-plan so the mask
-                                # covers THEIR contacts too, not just
-                                # the founders'. A FAILED re-plan keeps
-                                # the current contact spec (still valid
-                                # for survivor rows) rather than
-                                # silently widening back to the static
-                                # mask while the batch stays accounted
-                                # as contact-planned.
-                                new_kernel, ok = _plan_contact(state,
-                                                               admitted)
-                                if ok:
-                                    step_kernel = new_kernel
+                                inline=lease is None, gap=r)
                             if admitted and fetch_steps:
                                 # refresh the prev snapshot NOW: an
                                 # admitted row's first delta must
@@ -3493,8 +3352,8 @@ class Scheduler:
                             # fold (ISSUE 18)
 
                             ckpt = self._checkpoint_loop(
-                                state, batch, active, rows, ages, r,
-                                step_kernel) or ckpt
+                                state, batch, active, rows, ages,
+                                r) or ckpt
                     break     # loop drained clean: leave the envelope
                 except Exception as exc:
                     scrubbed = self._isolate_poison_rows(
@@ -3512,8 +3371,8 @@ class Scheduler:
                         step_done = True
                         if ckpt_every and active:
                             ckpt = self._checkpoint_loop(
-                                state, batch, active, rows, ages, r,
-                                step_kernel) or ckpt
+                                state, batch, active, rows, ages,
+                                r) or ckpt
                         continue
                     outcome = self._resume_or_requeue(
                         exc, ckpt, all_members, bucket_len, resumes,
@@ -3525,8 +3384,7 @@ class Scheduler:
                         return    # survivors re-enter via the queue
                     resumes += 1
                     resume_probe = self._breaker is not None
-                    (state, batch, active, rows, ages,
-                     step_kernel) = payload
+                    state, batch, active, rows, ages = payload
                     r = ckpt.step
                     step_done = True
                     coords_np = conf_np = None
@@ -3561,16 +3419,14 @@ class Scheduler:
                     attempts=e.attempts))
             return
         self._finish_step_batch(bucket_len, entries, all_members, lease,
-                                kspec, contact_planned, any_nonfinite,
-                                waste, t0)
+                                any_nonfinite, waste, t0)
 
     def _finish_step_batch(self, bucket_len: int, entries: List[_Entry],
                            all_members: List[_Entry],
-                           lease: Optional[SliceLease], kspec,
-                           contact_planned: bool, any_nonfinite: bool,
-                           waste: float, t0: float):
+                           lease: Optional[SliceLease],
+                           any_nonfinite: bool, waste: float, t0: float):
         """Success-path accounting for one completed step loop (breaker
-        health, mesh/kernel counters, the batch JSONL record) — shared
+        health, mesh counters, the batch JSONL record) — shared
         by the normal drain and the all-founders-isolated early exit."""
         cfg = self.config
         if self._breaker is not None:
@@ -3580,8 +3436,6 @@ class Scheduler:
              else self._breaker.record_success)()
         if lease is not None:
             self._c_mesh_folds.inc(mesh=lease.label)
-        self._record_kernel_batch(bucket_len, kspec, len(all_members),
-                                  contact=contact_planned)
         with self._cond:
             if lease is not None:
                 self._mesh_batches[lease.label] = \
@@ -3897,8 +3751,7 @@ class Scheduler:
     def _admit_rows(self, bucket_len: int, batch: dict, state,
                     active: List[_Entry], rows: List[int],
                     ages: List[int], all_members: List[_Entry],
-                    devices, mesh_shape, inline: bool, gap: int,
-                    kernel=None):
+                    devices, mesh_shape, inline: bool, gap: int):
         """Refill free batch rows mid-recycle (continuous batching,
         ISSUE 11). Candidates come off the pending queue in deadline/
         priority order and pass the same front submit() runs: a result-
@@ -4123,10 +3976,7 @@ class Scheduler:
             row_mask[row] = True
         admit_trace = (MultiTrace([e.trace for e in admitted])
                        if self.tracer.enabled else NULL_TRACE)
-        # admission runs the bucket's STATIC first-pass spec (the one
-        # warmup pre-compiled) — a contact-planned step spec describes
-        # the founders' contacts, not a newly admitted target's
-        admit_kw = {} if kernel is None else {"kernel": kernel}
+        admit_kw = {}
         if self._use_cross_bucket():
             # admit spans tagged with the admitted rows' native buckets
             # (ISSUE 13 obs): only under a cross-bucket policy, where
@@ -4262,7 +4112,7 @@ class Scheduler:
     # -- step-loop fault domains (ISSUE 14) ------------------------------
 
     def _checkpoint_loop(self, state, batch, active, rows, ages,
-                         step: int, kernel) -> Optional[_StepCheckpoint]:
+                         step: int) -> Optional[_StepCheckpoint]:
         """Snapshot the running loop to host memory: the carry (with
         shardings, so a mesh-sharded state re-uploads onto its slice),
         a COPY of the batch host mirror (later admission rounds mutate
@@ -4283,8 +4133,7 @@ class Scheduler:
         if self._ckpt_store is not None:
             self._spill_rows(snap_state, active, rows, ages)
         return _StepCheckpoint(snap_state, snap_host, list(rows),
-                               list(ages), list(active), int(step),
-                               kernel)
+                               list(ages), list(active), int(step))
 
     def _spill_rows(self, snap_state, active: List[_Entry],
                     rows: List[int], ages: List[int]):
@@ -4496,7 +4345,7 @@ class Scheduler:
           deterministic failure, resume budget spent, stopping, or the
           breaker is already open) — the caller re-raises into the
           classic handler, byte-for-byte the PR-5 recovery;
-        - ("resumed", (state, batch, active, rows, ages, kernel)): the
+        - ("resumed", (state, batch, active, rows, ages)): the
           checkpoint re-uploaded; survivors continue at their
           checkpointed ages (bounded progress loss — the steps between
           checkpoint and failure, counted in
@@ -4629,7 +4478,7 @@ class Scheduler:
             time.sleep(delay)
         return ("resumed", (state, batch, survivors,
                             [ckpt.rows[i] for i in keep],
-                            [ckpt.ages[i] for i in keep], ckpt.kernel))
+                            [ckpt.ages[i] for i in keep]))
 
     def _maybe_preempt(self, active: List[_Entry],
                        lease: Optional[SliceLease], gap: int,
@@ -4837,11 +4686,11 @@ class Scheduler:
     # -- resilience: worker side -----------------------------------------
 
     def _run_executor(self, batch: dict, batch_trace,
-                      lease: Optional[SliceLease] = None, kernel=None):
+                      lease: Optional[SliceLease] = None):
         """executor.run with the optional per-batch watchdog deadline.
-        The trace/devices/kernel kwargs are only passed when in use, so
-        alternate executors (tests) needn't know about obs, meshes, or
-        kernel policies; `self.executor` is read inside the closure so
+        The trace/devices kwargs are only passed when in use, so
+        alternate executors (tests) needn't know about obs or
+        meshes; `self.executor` is read inside the closure so
         a rebuild between batches takes effect immediately."""
         kw = {}
         if batch_trace is not NULL_TRACE:
@@ -4849,8 +4698,6 @@ class Scheduler:
         if lease is not None:
             kw["devices"] = lease.devices
             kw["mesh_shape"] = lease.shape
-        if kernel is not None:
-            kw["kernel"] = kernel
         if kw:
             call = lambda: self.executor.run(  # noqa: E731
                 batch, self.config.num_recycles, **kw)

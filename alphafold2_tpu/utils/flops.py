@@ -142,40 +142,21 @@ def forward_flops(fn, *args, **kwargs) -> float:
     return count_jaxpr_flops(closed.jaxpr)
 
 
-def _pure_trace_context():
-    """Disable every custom-kernel routing for a counting trace, returning
-    a restore callable. Counting must see plain dot_general — the AMX FFI
-    and Pallas calls hide their contractions behind opaque primitives."""
-    from alphafold2_tpu.ops import cpu_gemm
-    from alphafold2_tpu.ops import attention as pallas_attn
-
-    prev_amx = cpu_gemm._enabled
-    prev_pallas = pallas_attn.pallas_attention_enabled()
-    cpu_gemm.use_amx_dense(False)
-    pallas_attn.use_pallas_attention(False)
-
-    def restore():
-        cpu_gemm._enabled = prev_amx
-        pallas_attn.use_pallas_attention(prev_pallas)
-
-    return restore
-
-
 def train_step_flops(model, params, batch, rng=None) -> float:
     """Analytic FLOPs of one training step of `model` on `batch`:
     3 x forward contraction FLOPs of the composite loss (fwd 1x, bwd 2x).
     Optimizer update FLOPs (~10 x n_params elementwise) are excluded as
     negligible and non-contraction."""
+    from alphafold2_tpu.ops.attention import pallas_attention
     from alphafold2_tpu.train.loop import compute_loss
 
     rng = jax.random.PRNGKey(0) if rng is None else rng
-    restore = _pure_trace_context()
-    try:
+    # counting must see plain dot_general: a Pallas call hides its
+    # contractions behind an opaque primitive
+    with pallas_attention(False):
         fwd = forward_flops(
             lambda p, b: compute_loss(model, p, b, rng, train=True)[0],
             params, batch)
-    finally:
-        restore()
     return 3.0 * fwd
 
 

@@ -8,9 +8,9 @@ Prints ONE JSON line:
   {"metric": ..., "value": N, "unit": "ms", "vs_baseline": N, ...}
 
 `vs_baseline` is the speedup ratio vs the reference implementation's
-matched-config training step (torch-CPU, measured by
-tools/measure_reference_baseline.py into tools/reference_baseline.json —
-the reference publishes no numbers of its own, see BASELINE.md).
+matched-config training step (torch-CPU, as recorded in
+tools/reference_baseline.json — the reference publishes no numbers of its
+own, see BASELINE.md).
 
 The line also reports achieved TFLOP/s and MFU vs the chip's bf16 peak
 (SURVEY.md §6). FLOPs are ANALYTIC (3x the forward contraction count from

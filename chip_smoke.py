@@ -58,7 +58,8 @@ from alphafold2_tpu import Alphafold2, constants, predict, serve
 from alphafold2_tpu.data.synthetic import synthetic_batch
 from alphafold2_tpu.ops.attention import (MASK_VALUE, attention_reference,
                                           fused_attention)
-from alphafold2_tpu.ops.block_sparse import KernelSpec, block_sparse_attention
+from alphafold2_tpu.ops.block_sparse import (banded_block_pattern,
+                                             block_sparse_attention)
 from alphafold2_tpu.parallel import make_mesh, shard_pytree_tp_zero, use_mesh
 from alphafold2_tpu.runtime import enable_compile_cache, on_tpu
 from alphafold2_tpu.serve.meshpolicy import factor_chips
@@ -209,12 +210,11 @@ def kernel_diffs(*, n: int, d: int, block: int, heads: int, seed: int,
     bias = jax.random.normal(kb, (heads, n, n), jnp.float32)
     k_mask = jnp.broadcast_to(jnp.arange(n) < n - n // 8, (fold_axis, n))
 
-    # a pattern with dead blocks: the diagonal plus the first block column
-    nb = n // block
-    pattern = np.eye(nb, dtype=bool)
-    pattern[:, 0] = True
-    masked = KernelSpec.from_pattern(pattern, block, backend="masked")
-    fill = jnp.where(jnp.asarray(masked.token_mask()), 0.0,
+    # a pattern with dead blocks: the diagonal plus the first block row and
+    # column; the reference takes it as a bias over the tokens
+    pattern = banded_block_pattern(n // block, window=0, num_global=1)
+    live = np.repeat(np.repeat(pattern, block, 0), block, 1)
+    fill = jnp.where(jnp.asarray(live), 0.0,
                      MASK_VALUE).astype(jnp.float32)[None]
 
     dense = jax.jit(functools.partial(
@@ -246,11 +246,8 @@ def kernel_diffs(*, n: int, d: int, block: int, heads: int, seed: int,
 
 def phase_kernels(*, n: int, d: int, block: int, heads: int, seed: int,
                   tol: float) -> dict:
-    spec = KernelSpec.banded(n, block)
-    require(spec.resolve_backend() == "pallas" and not spec.interpret(),
-            "kernel dispatch would interpret or fall back to masked-dense")
     out = kernel_diffs(n=n, d=d, block=block, heads=heads, seed=seed,
-                       interpret=spec.interpret())
+                       interpret=False)
     require(all(out["tpu_custom_call"].values()), out["tpu_custom_call"])
     problems = [f"{name} kernel is {out[f'{name}_max_abs_diff']:.4g} from "
                 f"masked-dense, tol {tol}"

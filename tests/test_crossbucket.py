@@ -517,35 +517,6 @@ class TestEagerForm:
         assert ("init_rows", [2]) in stub.calls
 
 
-class TestContactPlanLengths:
-    def test_padding_plans_as_dead_blocks(self):
-        """Per-element lengths zero contact contributions beyond each
-        row's real residues before the batch reduce — a shorter
-        admitted row's padding region (and a dead row's garbage) can
-        never mark a block live (ISSUE 13)."""
-        from alphafold2_tpu.ops.block_sparse import \
-            contact_probs_from_distogram
-
-        n, nb = 16, 37
-        logits = np.zeros((2, n, n, nb), np.float32)
-        # both elements firmly non-contact everywhere...
-        logits[:, :, :, -1] = 50.0
-        # ...except element 1 screams "contact" in the far corner —
-        # entirely inside the region beyond its real length
-        logits[1, 12:, 12:, :] = 0.0
-        logits[1, 12:, 12:, 0] = 50.0
-        full = contact_probs_from_distogram(logits)
-        masked = contact_probs_from_distogram(logits,
-                                              lengths=[16, 8])
-        assert full[12:, 12:].max() > 0.9
-        assert masked[12:, 12:].max() < 0.1
-        # a dead row (length 0) contributes nothing at all
-        dead = contact_probs_from_distogram(logits, lengths=[0, 0])
-        assert dead.max() == 0.0
-        with pytest.raises(ValueError):
-            contact_probs_from_distogram(logits, lengths=[16])
-
-
 class TestLoadtestFlags:
     def test_cross_bucket_flags_fast(self, tmp_path, capsys):
         """Tier-1 flag-rot tripwire: the --cross-bucket /

@@ -1,263 +1,249 @@
-"""Numerical parity vs the reference implementation: copy the reference's
-torch module weights into the flax modules and require matching outputs.
+"""Block-level parity with the repository's own plain reference: each flax
+block of the program against `benchmark/reference.py`'s function of the same
+name, on the module's own parameter tree, forward and `jax.grad`.
 
-This is value-level parity evidence the reference's own test suite never
-had (SURVEY.md §4: "crash tests, not value tests"). Component-level on
-purpose: the one documented semantic deviation (OuterMean's masked-mean
-fix, primitives.py docstring) is excluded by testing OuterMean maskless.
+The reference is float32 `jax.numpy` with no padding and no masks (masks are
+`test_ops.py`'s); it imports nothing of the program. A case runs at a real
+length, the smallest the fused kernel admits (`MIN_FUSED_LENGTH` positions,
+heads of 8), so that the same inputs go through both doors of
+`Attention.__call__`: XLA's einsum + softmax + einsum (what the CPU suite
+takes) and the fused kernel, interpreted (what a TPU takes). Through the
+kernel the tolerances are the ones `test_ops.py` holds its float32 cases
+to; everywhere else 1e-5 of the tensor's scale.
 
-Requires /root/reference and torch (CPU); skipped otherwise.
+These are the tests a PR on one kernel runs first: a block, seconds, an
+independent forward and an independent gradient.
 """
 
-import os
-import sys
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-jnp = pytest.importorskip("jax.numpy")
-import jax  # noqa: E402
+from alphafold2_tpu.core.rigid import Rigid
+from alphafold2_tpu.model import evoformer, primitives, structure
+from alphafold2_tpu.ops import attention as ops_attn
+from benchmark import reference
 
-REFERENCE = "/root/reference"
-TOOLS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools")
+N = ops_attn.MIN_FUSED_LENGTH       # residues: the attended axis
+ROWS = 4                            # alignment rows, or rows of a folded axis
+DIM, HEADS, DIM_HEAD = 32, 2, 8
+DEPTH = 2                           # of the trunk and the structure module
+NX = reference.Numerics("f32")
 
-if not os.path.isdir(REFERENCE):  # pragma: no cover
-    pytest.skip("reference not mounted", allow_module_level=True)
-
-torch = pytest.importorskip("torch")
-sys.path.insert(0, TOOLS)
-sys.path.insert(0, REFERENCE)
-import _reference_stubs  # noqa: F401,E402  (fills missing native deps)
-
-from alphafold2_pytorch import alphafold2 as ref  # noqa: E402
-
-from alphafold2_tpu.model import primitives as mine  # noqa: E402
-
-torch.manual_seed(0)
+TOL = 1e-5
+KERNEL_FORWARD_TOL = 1e-5           # test_ops.py, float32, forward
+KERNEL_BACKWARD_TOL = 1e-4          # test_ops.py, float32, backward
 
 
-def t2j(t):
-    return jnp.asarray(t.detach().cpu().numpy())
+def _axial(column: bool, edges: bool):
+    module = primitives.AxialAttention(
+        dim=DIM, heads=HEADS, dim_head=DIM_HEAD, row_attn=not column,
+        col_attn=column, accept_edges=edges)
+    shapes = [(N, ROWS, DIM) if column else (ROWS, N, DIM)]
+    if edges:
+        shapes.append((N, N, DIM))
+
+    def program(params, x, e=None):
+        return module.apply(params, x[None],
+                            edges=None if e is None else e[None])[0]
+
+    def plain(p, x, e=None):
+        att = functools.partial(reference._axial_attention, NX, p,
+                                edges=e, heads=HEADS, dim_head=DIM_HEAD)
+        return att(x.swapaxes(0, 1)).swapaxes(0, 1) if column else att(x)
+    return module, shapes, program, plain
 
 
-def linear(params_leaf, torch_linear):
-    """Fill a flax Dense param dict from a torch Linear."""
-    out = {"kernel": t2j(torch_linear.weight).T}
-    if torch_linear.bias is not None:
-        out["bias"] = t2j(torch_linear.bias)
-    return out
+def _triangle(outgoing: bool):
+    module = primitives.TriangleMultiplicativeModule(
+        dim=DIM, mix="outgoing" if outgoing else "ingoing")
+    return (module, [(N, N, DIM)],
+            lambda params, x: module.apply(params, x[None])[0],
+            lambda p, x: reference._triangle_multiply(NX, p, x, outgoing))
 
 
-def layernorm(torch_ln):
-    return {"LayerNorm_0": {"scale": t2j(torch_ln.weight),
-                            "bias": t2j(torch_ln.bias)}}
+def _feed_forward():
+    module = primitives.FeedForward(dim=DIM)
+    return (module, [(ROWS, N, DIM)],
+            lambda params, x: module.apply(params, x[None])[0],
+            lambda p, x: reference._feed_forward(NX, p, x))
 
 
-def attention_params(ta: "ref.Attention"):
-    return {
-        "to_q": linear(None, ta.to_q),
-        "to_kv": linear(None, ta.to_kv),
-        "to_out": linear(None, ta.to_out),
-        "gating": linear(None, ta.gating),
-    }
+def _outer_mean():
+    module = primitives.OuterMean(dim=DIM)
+    return (module, [(ROWS, N, DIM)],
+            lambda params, m: module.apply(params, m[None])[0],
+            lambda p, m: reference._outer_mean(NX, p, m))
 
 
-def rand_t(*shape):
-    return torch.randn(*shape)
+def _pair_and_msa(module, plain):
+    def program(params, x, m):
+        x, m = module.apply(params, x[None], m[None])
+        return x[0], m[0]
+    return module, [(N, N, DIM), (ROWS, N, DIM)], program, plain
 
 
-class TestAttentionParity:
-    def test_basic(self):
-        dim, heads, dh, n = 32, 4, 8, 10
-        ta = ref.Attention(dim=dim, heads=heads, dim_head=dh).eval()
-        ja = mine.Attention(dim=dim, heads=heads, dim_head=dh)
-        x = rand_t(2, n, dim)
-        with torch.no_grad():
-            want = ta(x)
-        params = {"params": attention_params(ta)}
-        got = ja.apply(params, t2j(x))
-        assert np.allclose(np.asarray(got), want.numpy(), atol=1e-5)
-
-    def test_with_bias_and_mask(self):
-        dim, heads, dh, n = 32, 4, 8, 12
-        ta = ref.Attention(dim=dim, heads=heads, dim_head=dh).eval()
-        ja = mine.Attention(dim=dim, heads=heads, dim_head=dh)
-        x = rand_t(2, n, dim)
-        bias = rand_t(2, heads, n, n)
-        mask = torch.ones(2, n).bool()
-        mask[:, -3:] = False
-        with torch.no_grad():
-            want = ta(x, mask=mask, attn_bias=bias)
-        got = ja.apply({"params": attention_params(ta)}, t2j(x),
-                       mask=t2j(mask), attn_bias=t2j(bias))
-        assert np.allclose(np.asarray(got)[:, :-3], want.numpy()[:, :-3],
-                           atol=1e-5)
-
-    def test_tie_dim_global_query(self):
-        dim, heads, dh, n, r = 32, 2, 8, 6, 3
-        ta = ref.Attention(dim=dim, heads=heads, dim_head=dh).eval()
-        ja = mine.Attention(dim=dim, heads=heads, dim_head=dh)
-        x = rand_t(2 * r, n, dim)
-        with torch.no_grad():
-            want = ta(x, tie_dim=r)
-        got = ja.apply({"params": attention_params(ta)}, t2j(x), tie_dim=r)
-        assert np.allclose(np.asarray(got), want.numpy(), atol=1e-5)
+def _evoformer_block():
+    return _pair_and_msa(
+        evoformer.EvoformerBlock(dim=DIM, heads=HEADS, dim_head=DIM_HEAD),
+        lambda p, x, m: reference._evoformer_block(NX, p, x, m, HEADS,
+                                                   DIM_HEAD))
 
 
-class TestAxialParity:
-    @pytest.mark.parametrize("row_attn,col_attn", [(True, False),
-                                                   (False, True)])
-    def test_axial(self, row_attn, col_attn):
-        dim, heads, dh = 32, 2, 8
-        ta = ref.AxialAttention(dim=dim, heads=heads, dim_head=dh,
-                                row_attn=row_attn, col_attn=col_attn,
-                                accept_edges=True).eval()
-        ja = mine.AxialAttention(dim=dim, heads=heads, dim_head=dh,
-                                 row_attn=row_attn, col_attn=col_attn,
-                                 accept_edges=True)
-        x = rand_t(1, 7, 7, dim)
-        edges = rand_t(1, 7, 7, dim)
-        with torch.no_grad():
-            want = ta(x, edges=edges)
-        params = {"params": {
-            "LayerNorm_0": layernorm(ta.norm),
-            "attn": attention_params(ta.attn),
-            "edges_to_attn_bias": linear(None, ta.edges_to_attn_bias[0]),
-        }}
-        got = ja.apply(params, t2j(x), edges=t2j(edges))
-        assert np.allclose(np.asarray(got), want.numpy(), atol=1e-5)
+def _trunk():
+    return _pair_and_msa(
+        evoformer.Evoformer(dim=DIM, depth=DEPTH, heads=HEADS,
+                            dim_head=DIM_HEAD),
+        lambda p, x, m: reference._trunk(NX, p, x, m, HEADS, DIM_HEAD,
+                                         remat=False))
 
 
-class TestTriangleParity:
-    @pytest.mark.parametrize("mix", ["outgoing", "ingoing"])
-    def test_triangle_multiplicative(self, mix):
-        dim, n = 32, 9
-        tm = ref.TriangleMultiplicativeModule(dim=dim, mix=mix).eval()
-        jm = mine.TriangleMultiplicativeModule(dim=dim, mix=mix)
-        x = rand_t(1, n, n, dim)
-        mask = torch.ones(1, n, n).bool()
-        with torch.no_grad():
-            want = tm(x, mask=mask)
-        params = {"params": {
-            "LayerNorm_0": layernorm(tm.norm),
-            "left_proj": linear(None, tm.left_proj),
-            "right_proj": linear(None, tm.right_proj),
-            "left_gate": linear(None, tm.left_gate),
-            "right_gate": linear(None, tm.right_gate),
-            "out_gate": linear(None, tm.out_gate),
-            "LayerNorm_1": layernorm(tm.to_out_norm),
-            "to_out": linear(None, tm.to_out),
-        }}
-        got = jm.apply(params, t2j(x), mask=t2j(mask))
-        assert np.allclose(np.asarray(got), want.numpy(), atol=1e-4)
+def _ipa():
+    module = structure.InvariantPointAttention(dim=DIM, heads=1,
+                                               pairwise_repr_dim=DIM)
+
+    def program(params, s, pair, quats, trans):
+        frames = Rigid(quats[None], trans[None])
+        return module.apply(params, s[None], pair[None], frames)[0]
+
+    def plain(p, s, pair, quats, trans):
+        return reference._ipa(NX, p, s, pair, reference._rotations(quats),
+                              trans)
+    return module, [(N, DIM), (N, N, DIM), (N, 4), (N, 3)], program, plain
 
 
-class TestFeedForwardParity:
-    def test_geglu_ff(self):
-        dim = 32
-        tf = ref.FeedForward(dim=dim).eval()
-        jf = mine.FeedForward(dim=dim)
-        x = rand_t(2, 5, dim)
-        with torch.no_grad():
-            want = tf(x)
-        params = {"params": {
-            "LayerNorm_0": layernorm(tf.norm),
-            "Dense_0": linear(None, tf.net[0]),
-            "Dense_1": linear(None, tf.net[3]),
-        }}
-        got = jf.apply(params, t2j(x))
-        assert np.allclose(np.asarray(got), want.numpy(), atol=1e-5)
+def _structure_module():
+    module = structure.StructureModule(dim=DIM, depth=DEPTH, heads=1)
+
+    def program(params, s, pair):
+        coords, single = module.apply(params, s[None], pair[None])
+        return coords[0], single[0]
+    return (module, [(N, DIM), (N, N, DIM)], program,
+            lambda p, s, pair: reference._structure_module(NX, p, s, pair,
+                                                           DEPTH))
 
 
-class TestWholeModelParity:
-    """Full-model weight porting (tools/port_weights.py; VERDICT round-1
-    item #5): a reference Alphafold2's weights run here and produce the
-    same trunk outputs. The flax model runs with
-    `outer_mean_reference_scale=True` because the reference synthesizes an
-    all-ones msa_mask (alphafold2.py:703), putting its OuterMean in the
-    double-dividing masked branch (alphafold2.py:347) on every forward."""
-
-    CFG = dict(dim=32, depth=2, heads=2, dim_head=16, max_seq_len=64,
-               extra_msa_evoformer_layers=1, predict_angles=True)
-
-    def _models(self):
-        from alphafold2_tpu import Alphafold2
-        from port_weights import port_alphafold2
-
-        tmodel = ref.Alphafold2(**self.CFG).eval()
-        model = Alphafold2(**self.CFG, outer_mean_reference_scale=True)
-        seq = jnp.zeros((1, 8), dtype=jnp.int32)
-        template = model.init(jax.random.PRNGKey(0), seq)
-        params, unported = port_alphafold2(tmodel, template)
-        # everything except the framework-only projection banks and the
-        # (non-portable, external-package) IPA internals must be ported
-        for k in unported:
-            assert k.startswith(("seq_embed_project", "msa_embed_project",
-                                 "structure_module")), k
-        return tmodel, model, params
-
-    def test_distogram_and_angles_match(self):
-        tmodel, model, params = self._models()
-        n, m = 16, 3
-        seq_t = torch.randint(0, 21, (1, n))
-        msa_t = torch.randint(0, 21, (1, m, n))
-        with torch.no_grad():
-            want = tmodel(seq=seq_t, msa=msa_t)
-        got = model.apply(params, t2j(seq_t).astype(jnp.int32),
-                          msa=t2j(msa_t).astype(jnp.int32))
-        assert np.allclose(np.asarray(got.distance),
-                           want.distance.numpy(), atol=2e-4), \
-            float(np.abs(np.asarray(got.distance)
-                         - want.distance.numpy()).max())
-        # the reference assigns ad-hoc *_logits attributes and leaves the
-        # declared dataclass fields None (alphafold2.py:32-35 vs :816-836)
-        assert np.allclose(np.asarray(got.theta),
-                           want.theta_logits.numpy(), atol=2e-4)
-        assert np.allclose(np.asarray(got.phi),
-                           want.phi_logits.numpy(), atol=2e-4)
-        assert np.allclose(np.asarray(got.omega),
-                           want.omega_logits.numpy(), atol=2e-4)
-
-    def test_recycling_embeds_match(self):
-        tmodel, model, params = self._models()
-        n, m = 12, 3
-        seq_t = torch.randint(0, 21, (1, n))
-        msa_t = torch.randint(0, 21, (1, m, n))
-        rec_msa = torch.randn(1, n, 32)
-        rec_pair = torch.randn(1, n, n, 32)
-        rec_coords = torch.randn(1, n, 3) * 5
-
-        t_rec = ref.Recyclables(rec_coords, rec_msa, rec_pair)
-        with torch.no_grad():
-            want = tmodel(seq=seq_t, msa=msa_t, recyclables=t_rec)
-
-        from alphafold2_tpu.model.alphafold2 import Recyclables
-        j_rec = Recyclables(coords=t2j(rec_coords),
-                            single_msa_repr_row=t2j(rec_msa),
-                            pairwise_repr=t2j(rec_pair))
-        got = model.apply(params, t2j(seq_t).astype(jnp.int32),
-                          msa=t2j(msa_t).astype(jnp.int32),
-                          recyclables=j_rec)
-        assert np.allclose(np.asarray(got.distance),
-                           want.distance.numpy(), atol=2e-4)
+# block -> (module, input shapes, program(params, *inputs),
+#           plain(params["params"], *inputs)); inputs and outputs unbatched
+TRUNK_BLOCKS = {
+    "feed_forward": _feed_forward,
+    "axial_row": functools.partial(_axial, False, False),
+    "axial_row_edges": functools.partial(_axial, False, True),
+    "axial_column": functools.partial(_axial, True, False),
+    "axial_column_edges": functools.partial(_axial, True, True),
+    "triangle_multiply_outgoing": functools.partial(_triangle, True),
+    "triangle_multiply_ingoing": functools.partial(_triangle, False),
+    "outer_mean": _outer_mean,
+    "evoformer_block": _evoformer_block,
+}
+BLOCKS = dict(TRUNK_BLOCKS, trunk=_trunk, ipa=_ipa,
+              structure_module=_structure_module)
+HOLD_AN_ATTENTION = [name for name in TRUNK_BLOCKS
+                     if name.startswith(("axial", "evoformer"))]
 
 
-class TestOuterMeanParity:
-    def test_maskless(self):
-        # maskless only: the reference's masked branch double-divides
-        # (alphafold2.py:347) — our fix is the documented deviation
-        dim = 32
-        to = ref.OuterMean(dim=dim).eval()
-        jo = mine.OuterMean(dim=dim)
-        x = rand_t(1, 4, 6, dim)
-        with torch.no_grad():
-            want = to(x)
-        params = {"params": {
-            "LayerNorm_0": layernorm(to.norm),
-            "left_proj": linear(None, to.left_proj),
-            "right_proj": linear(None, to.right_proj),
-            "proj_out": linear(None, to.proj_out),
-        }}
-        got = jo.apply(params, t2j(x))
-        assert np.allclose(np.asarray(got), want.numpy(), atol=1e-5)
+def _draw(tree, key):
+    """Every leaf of the module's own tree drawn anew, well-conditioned: the
+    modules initialise their output projections to zero and their gates to
+    pass-through, which would hide most of a block's arithmetic."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    drawn = []
+    for k, (path, leaf) in zip(jax.random.split(key, len(leaves)), leaves):
+        noise = jax.random.normal(k, leaf.shape, jnp.float32)
+        name = path[-1].key
+        if name in ("kernel", "embedding"):
+            # fan-in is the axis before last (the trunk stacks its layers)
+            drawn.append(noise / np.sqrt(leaf.shape[-2]))
+        else:
+            drawn.append(0.1 * noise + (1.0 if name == "scale" else 0.0))
+    return treedef.unflatten(drawn)
+
+
+def _case(name):
+    module, shapes, program, plain = BLOCKS[name]()
+    keys = jax.random.split(jax.random.PRNGKey(len(name)), len(shapes) + 2)
+    inputs = tuple(jax.random.normal(k, s, jnp.float32)
+                   for k, s in zip(keys, shapes))
+    batched = [t[None] for t in inputs]
+    if name == "ipa":
+        batched = batched[:2] + [Rigid(*batched[2:])]
+    params = _draw(module.init(keys[-2], *batched), keys[-1])
+    return params, inputs, program, plain
+
+
+def _as_tuple(out):
+    return out if isinstance(out, tuple) else (out,)
+
+
+def _assert_close(got, want, tol, what):
+    got_leaves, got_def = jax.tree_util.tree_flatten_with_path(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten_with_path(want)
+    assert got_def == want_def, what
+    for (path, a), (_, w) in zip(got_leaves, want_leaves):
+        where = f"{what}{jax.tree_util.keystr(path)}"
+        a, w = np.asarray(a), np.asarray(w)
+        assert a.shape == w.shape and a.dtype == w.dtype == np.float32, where
+        assert np.isfinite(a).all() and np.abs(w).max() > 0, where
+        scale = max(1.0, float(np.abs(w).max()))
+        assert float(np.abs(a - w).max()) <= tol * scale, where
+
+
+@pytest.fixture
+def door(request, monkeypatch):
+    """Which attention `Attention.__call__` takes: "xla", as on the CPU, or
+    "kernel", the fused kernel (interpreted), as on a TPU; and afterwards,
+    that it was the one taken."""
+    calls = []
+    interpreted = functools.partial(ops_attn.fused_attention_merged,
+                                    interpret=True)
+
+    def spy(*args, **kwargs):
+        calls.append(args[0].shape)
+        return interpreted(*args, **kwargs)
+
+    monkeypatch.setattr(ops_attn, "fused_attention_merged", spy)
+    with ops_attn.pallas_attention(request.param == "kernel"):
+        yield request.param
+    assert bool(calls) == (request.param == "kernel"), calls
+
+
+def _doors(names):
+    """Every block through XLA's attention, and those that hold an attention
+    through the kernel as well."""
+    return [pytest.param(name, door, id=f"{name}-{door}")
+            for door in ("xla", "kernel") for name in names
+            if door == "xla" or name in HOLD_AN_ATTENTION]
+
+
+@pytest.mark.parametrize("name,door", _doors(BLOCKS), indirect=["door"])
+def test_block_forward_matches_reference(name, door):
+    params, inputs, program, plain = _case(name)
+    got = jax.jit(program)(params, *inputs)
+    want = jax.jit(plain)(params["params"], *inputs)
+    _assert_close(_as_tuple(got), _as_tuple(want),
+                  KERNEL_FORWARD_TOL if door == "kernel" else TOL, name)
+
+
+@pytest.mark.parametrize("name,door", _doors(TRUNK_BLOCKS),
+                         indirect=["door"])
+def test_block_gradient_matches_reference(name, door):
+    """`jax.grad` of one scalar of the block's output, with respect to the
+    parameters and the inputs, against the reference's own gradient: with
+    the door open the backward kernel answers to it."""
+    params, inputs, program, plain = _case(name)
+    outs = _as_tuple(jax.eval_shape(program, params, *inputs))
+    weights = [jax.random.normal(jax.random.PRNGKey(7 + i), o.shape)
+               for i, o in enumerate(outs)]
+
+    def scalar_of(fn):
+        return lambda p, *xs: sum(jnp.sum(o * w) for o, w in
+                                  zip(_as_tuple(fn(p, *xs)), weights))
+    argnums = tuple(range(len(inputs) + 1))
+    got = jax.jit(jax.grad(scalar_of(program), argnums))(params, *inputs)
+    want = jax.jit(jax.grad(scalar_of(plain), argnums))(params["params"],
+                                                       *inputs)
+    _assert_close((got[0]["params"],) + got[1:], want,
+                  KERNEL_BACKWARD_TOL if door == "kernel" else TOL, name)

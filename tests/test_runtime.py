@@ -20,13 +20,13 @@ def test_platform_predicate_is_false_on_cpu():
 
 
 def test_kernel_dispatch_follows_the_predicate(monkeypatch):
-    from alphafold2_tpu.ops import block_sparse
-    from alphafold2_tpu.ops.block_sparse import KernelSpec
+    from alphafold2_tpu.model import attention_variants
+    from alphafold2_tpu.model.attention_variants import BlockSparseAttention
 
-    spec = KernelSpec.banded(256, 128)
-    assert spec.resolve_backend() == "masked" and spec.interpret()
-    monkeypatch.setattr(block_sparse, "on_tpu", lambda: True)
-    assert spec.resolve_backend() == "pallas" and not spec.interpret()
+    sparse = BlockSparseAttention(dim=32, heads=2, dim_head=16)
+    assert sparse._kernel_available() is False
+    monkeypatch.setattr(attention_variants, "on_tpu", lambda: True)
+    assert sparse._kernel_available() is True
 
 
 @pytest.fixture

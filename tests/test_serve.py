@@ -183,7 +183,12 @@ class TestStatsSatellites:
 
 
 class TestExecutor:
-    def test_cache_hit_miss_counts(self, model_and_params):
+    @pytest.mark.parametrize("differs", ["num_recycles", "batch_size",
+                                         "msa_depth"])
+    def test_cache_hit_miss_counts(self, model_and_params, differs):
+        """A signature compiles once and then hits; one that differs in
+        any one element of the key is a different executable: it compiles
+        fresh, then hits, and leaves the first resident."""
         ex = FoldExecutor(*model_and_params, max_entries=4)
         policy = BucketPolicy((16,))
         batch, _ = policy.assemble(requests_of((8, 12)), 16, 2)
@@ -193,8 +198,20 @@ class TestExecutor:
         stats = ex.stats()
         assert stats["hits"] == 1 and stats["misses"] == 1
         assert r1.coords.shape == r2.coords.shape == (2, 16, 3)
-        # a different num_recycles is a different executable
-        assert ex.key_for(batch, 1) != ex.key_for(batch, 0)
+        other, recycles = batch, 0
+        if differs == "num_recycles":
+            recycles = 1
+        elif differs == "batch_size":
+            other, _ = policy.assemble(requests_of((8, 12)), 16, 4)
+        else:
+            other, _ = policy.assemble(requests_of((8, 12)), 16, 2,
+                                       msa_depth=MSA_DEPTH - 1)
+        assert ex.key_for(other, recycles) != ex.key_for(batch, 0)
+        for hits, misses in ((1, 2), (2, 2)):
+            ex.run(other, num_recycles=recycles)
+            assert ex.stats() == dict(ex.stats(), hits=hits, misses=misses)
+        ex.run(batch, num_recycles=0)
+        assert ex.stats() == dict(ex.stats(), hits=3, misses=2, resident=2)
 
     def test_lru_eviction_bounds_resident_set(self, model_and_params):
         ex = FoldExecutor(*model_and_params, max_entries=1)
@@ -205,14 +222,31 @@ class TestExecutor:
         ex.run(b32, 0)                       # evicts the 16-bucket entry
         stats = ex.stats()
         assert stats["evictions"] == 1 and stats["resident"] == 1
-        # ExecKey grew (mesh_shape, model_tag) in ISSUE 7, the variant
-        # element in ISSUE 9, and the kernel element in ISSUE 12 (see
-        # MIGRATING): single-chip untagged opaque-fold dense executors
-        # key as (1,1)/""/"fold"/"dense"
+        # ExecKey grew (mesh_shape, model_tag) in ISSUE 7 and the variant
+        # element in ISSUE 9 (see MIGRATING): single-chip untagged
+        # opaque-fold executors key as (1,1)/""/"fold"
         assert stats["keys"] == [(32, 1, MSA_DEPTH, 0, (1, 1), "",
-                                  "fold", "dense")]
+                                  "fold")]
         ex.run(b16, 0)                       # cold again after eviction
         assert ex.stats()["misses"] == 3
+
+    @pytest.mark.parametrize("length", [4, 5, 6, 7, 8])
+    def test_normalize_key(self, model_and_params, length):
+        """`warmup()` and `profile()` callers pass the key at the length
+        it had when they were written: the elements they leave out are
+        this executor's defaults. An eight-element key (it carried the
+        serving block-sparse route's kernel label) is refused, not cut."""
+        ex = FoldExecutor(*model_and_params, model_tag="v1")
+        full = (16, 2, MSA_DEPTH, 1, [2, 2], "v0", "step", "dense")
+        defaults = (16, 2, MSA_DEPTH, 1, (1, 1), "v1", "fold")
+        if length == 8:
+            with pytest.raises(ValueError, match="4 to 7 elements"):
+                ex._normalize_key(full)
+            return
+        got = ex._normalize_key(full[:length])
+        want = (16, 2, MSA_DEPTH, 1, (2, 2), "v0", "step")
+        assert got == want[:length] + defaults[length:]
+        assert isinstance(got[4], tuple)
 
     def test_warmup_precompiles(self, model_and_params):
         ex = FoldExecutor(*model_and_params, max_entries=4)

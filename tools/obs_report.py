@@ -218,37 +218,6 @@ def mesh_fold_stats(records: List[dict]) -> dict:
             for mesh, durs in sorted(by_mesh.items())}
 
 
-def kernel_fold_stats(records: List[dict]) -> dict:
-    """Per-attention-kernel accelerator-span latency (ISSUE 12):
-    {kernel_label: {count, p50_s, p99_s}} over fold/recycle/admit spans
-    (the three accelerator stages a kernel choice governs). Spans
-    without a `kernel` attr (every pre-kernel-policy trace, and every
-    dense fold under a policy-less scheduler) group under "dense" —
-    mirrors mesh_fold_stats' "1x1" convention, so a mixed trace file
-    still separates dense from block-sparse executions. Empty when no
-    accelerator spans exist."""
-    by_kernel = {}
-    for rec in records:
-        for span in rec.get("spans", ()):
-            if span.get("name") not in ("fold", "recycle", "admit"):
-                continue
-            kern = (span.get("attrs") or {}).get("kernel", "dense")
-            by_kernel.setdefault(str(kern), []).append(
-                float(span.get("dur_s", 0.0)))
-    return {kern: {"count": len(durs),
-                   "p50_s": percentile(durs, 50),
-                   "p99_s": percentile(durs, 99)}
-            for kern, durs in sorted(by_kernel.items())}
-
-
-def render_kernel_folds(stats: dict) -> str:
-    lines = [f"{'kernel':>20}  {'spans':>6}  {'p50':>9}  {'p99':>9}"]
-    for kern, s in stats.items():
-        lines.append(f"{kern:>20}  {s['count']:>6}  {s['p50_s']:>9.4f}  "
-                     f"{s['p99_s']:>9.4f}")
-    return "\n".join(lines)
-
-
 def rows_occupied_stats(records: List[dict]) -> Optional[dict]:
     """Row-occupancy read back from recycle spans' rows_live/rows_total
     attrs (the continuous batcher tags every step, ISSUE 11): the
@@ -392,7 +361,6 @@ def main(argv=None) -> int:
         out = summarize(records)
         out["stages"] = stage_stats(records)
         out["mesh_folds"] = mesh_fold_stats(records)
-        out["kernel_folds"] = kernel_fold_stats(records)
         out["rows_occupied"] = rows_occupied_stats(records)
         out["problems"] = problems[:20]
         print(json.dumps(out))
@@ -406,10 +374,6 @@ def main(argv=None) -> int:
         if len(mesh) > 1 or any(m != "1x1" for m in mesh):
             print("\n-- fold latency by mesh shape --")
             print(render_mesh_folds(mesh))
-        kern = kernel_fold_stats(records)
-        if len(kern) > 1 or any(k != "dense" for k in kern):
-            print("\n-- accelerator latency by attention kernel --")
-            print(render_kernel_folds(kern))
         occ = rows_occupied_stats(records)
         if occ is not None:
             print(f"\nrows occupied (continuous batching): "

@@ -251,38 +251,6 @@ def parse_args(argv=None):
     ap.add_argument("--no-preempt", action="store_true",
                     help="disable between-recycle preemption "
                          "(isolates the early-exit effect)")
-    ap.add_argument("--kernel-policy", default="",
-                    help="per-bucket attention-kernel routing "
-                         "(ISSUE 12, serve.KernelPolicy.parse): "
-                         "'dense' | 'blocksparse' | 'auto' | "
-                         "'64=dense,512=blocksparse'. auto routes a "
-                         "bucket sparse when its static banded mask's "
-                         "live fraction <= --sparse-live-frac. Empty "
-                         "(default) = feature off, byte-identical "
-                         "serving. The report adds a 'kernel' section "
-                         "(per-kernel folds/hour, mask live-fraction "
-                         "histogram, interpret-mode numerics check)")
-    ap.add_argument("--sparse-live-frac", type=float, default=0.5,
-                    help="auto-policy threshold: route a bucket onto "
-                         "the block-sparse kernel when its static "
-                         "banded+global pattern's live fraction is <= "
-                         "this (tpu_blocksparse.json: ~parity at 0.53 "
-                         "live, 1.15x at 0.29)")
-    ap.add_argument("--sparse-block", type=int, default=128,
-                    help="sparse pattern block size (128 = TPU lane "
-                         "width; small CPU smokes use 8/16)")
-    ap.add_argument("--sparse-window", type=int, default=1,
-                    help="banded-mask half-width in blocks")
-    ap.add_argument("--sparse-global", type=int, default=1,
-                    help="global blocks of the static mask")
-    ap.add_argument("--kernel-backend", default="auto",
-                    help="auto (Pallas on TPU, masked-dense on CPU) | "
-                         "pallas (force; interpret off-TPU) | masked")
-    ap.add_argument("--kernel-contact", action="store_true",
-                    help="contact-prior masks (needs --recycle-sched): "
-                         "re-plan each batch's block mask from its own "
-                         "recycle-1 distogram, re-lowering the step "
-                         "executable for the remaining recycles")
     ap.add_argument("--feature-latency-ms", type=float, default=0.0,
                     help="FEATURE-PIPELINE mode (ISSUE 10): synthetic "
                          "featurize latency per execution, standing in "
@@ -521,59 +489,6 @@ def _build_recycle_policy(args):
                          cross_bucket_max_pad_frac=getattr(
                              args, "cross_bucket_max_pad_frac", 0.75),
                          eager_form=getattr(args, "eager_form", False))
-
-
-def _build_kernel_policy(args, policy):
-    """serve.KernelPolicy (or None) from --kernel-policy, via the
-    shared `KernelPolicy.parse` surface."""
-    from alphafold2_tpu.serve import KernelPolicy
-
-    return KernelPolicy.parse(
-        args.kernel_policy, policy.edges, block=args.sparse_block,
-        sparse_live_frac=args.sparse_live_frac,
-        backend=args.kernel_backend, window=args.sparse_window,
-        num_global=args.sparse_global,
-        contact_priors=args.kernel_contact)
-
-
-def _kernel_numerics_check(kernel_policy, policy, dim_head=16,
-                           batch=4) -> dict:
-    """Interpret-mode numerics check for every sparse-routed bucket:
-    the block-skipping kernel vs the dense+mask reference on the EXACT
-    pattern being served (random q/k/v at the serving length). Cheap on
-    CPU (one tiny interpret compile per sparse bucket) and honest —
-    the pattern, block size, and length are the production ones, so a
-    planning/kernel regression fails the smoke here even when the
-    serving path runs the masked-dense fallback."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from alphafold2_tpu.ops.attention import (MASK_VALUE,
-                                              attention_reference)
-    from alphafold2_tpu.ops.block_sparse import block_sparse_attention
-    from alphafold2_tpu.runtime import on_tpu
-
-    out = {}
-    for edge in policy.edges:
-        spec = kernel_policy.spec_for(edge)
-        if spec is None:
-            continue
-        rng = np.random.default_rng(edge)
-        q, k, v = (jnp.asarray(rng.normal(size=(batch, edge, dim_head)),
-                               jnp.float32) for _ in range(3))
-        # on a TPU the check must exercise the COMPILED Mosaic kernel,
-        # not the interpreter
-        sparse = block_sparse_attention(
-            q, k, v, spec.pattern_array(), block=spec.block,
-            interpret=not on_tpu())
-        bias = jnp.where(jnp.asarray(spec.token_mask()), 0.0,
-                         MASK_VALUE)[None]
-        ref = attention_reference(
-            q * dim_head ** -0.5, k, v,
-            bias=jnp.broadcast_to(bias, (batch, edge, edge)))
-        out[str(edge)] = float(
-            np.abs(np.asarray(sparse) - np.asarray(ref)).max())
-    return out
 
 
 def _calibrate_converge_tol(args, executor, policy, pool):
@@ -865,7 +780,6 @@ def main(argv=None) -> int:
         args.converge_tol = calibrated_tol = _calibrate_converge_tol(
             args, executor, policy, pool)
     recycle_policy = _build_recycle_policy(args)
-    kernel_policy = _build_kernel_policy(args, policy)
     metrics = serve.ServeMetrics(args.metrics_path)
     config = serve.SchedulerConfig(
         max_batch_size=args.max_batch, max_wait_ms=args.max_wait_ms,
@@ -926,7 +840,6 @@ def main(argv=None) -> int:
                                 tracer=tracer, retry=retry,
                                 mesh_policy=mesh_policy,
                                 recycle_policy=recycle_policy,
-                                kernel_policy=kernel_policy,
                                 cascade=cascade_policy)
 
     warmup_timer = StepTimer()
@@ -1103,33 +1016,6 @@ def main(argv=None) -> int:
         report["devices"] = len(jax.devices())
         report["mesh"] = snap.get("mesh")
         report["too_large"] = snap.get("too_large", 0)
-    if kernel_policy is not None:
-        ksnap = snap["kernel"]
-        # per-kernel folds/hour over the same serving wall clock the
-        # headline number uses, plus a mask live-fraction histogram
-        # weighted by executed batches
-        per_kernel_fph = {}
-        for key, v in ksnap["folds"].items():
-            kind = key.split(":")[0]
-            per_kernel_fph[kind] = per_kernel_fph.get(kind, 0) \
-                + v["served"]
-        per_kernel_fph = {
-            k: round(v / serving_wall * 3600.0, 1)
-            for k, v in per_kernel_fph.items()}
-        hist = {}
-        for key, v in ksnap["folds"].items():
-            kind, _, bucket = key.partition(":")
-            b = ksnap["buckets"].get(bucket, {})
-            frac = 1.0 if b.get("live_frac") is None else b["live_frac"]
-            lo = int(frac * 10) / 10.0
-            bin_label = f"{lo:.1f}-{min(lo + 0.1, 1.0):.1f}"
-            hist[bin_label] = hist.get(bin_label, 0) + v["batches"]
-        report["kernel"] = dict(
-            ksnap,
-            folds_per_hour_by_kernel=per_kernel_fph,
-            live_frac_hist=dict(sorted(hist.items())),
-            numerics_max_diff=_kernel_numerics_check(kernel_policy,
-                                                     policy))
     if args.cascade:
         from alphafold2_tpu.utils.profiling import percentile
         casc = dict(snap["cascade"])
@@ -1268,35 +1154,6 @@ def main(argv=None) -> int:
                       f"clamped to the {n_dev}-device pool; "
                       "sharded-execution assertions skipped",
                       file=sys.stderr)
-        if kernel_policy is not None:
-            sparse_routed = [e for e in policy.edges
-                             if kernel_policy.kernel_for(e)
-                             == "blocksparse"]
-            if sparse_routed:
-                sparse_served = sum(
-                    v["served"] for k, v in
-                    snap["kernel"]["folds"].items()
-                    if k.startswith("blocksparse"))
-                sparse_keys = [k for k in snap["executor"]["keys"]
-                               if len(k) >= 8 and k[7] != "dense"]
-                if sparse_served == 0 or not sparse_keys:
-                    # a policy that routes buckets sparse but never
-                    # executes a sparse-keyed executable is dead weight
-                    print(f"SMOKE FAIL: kernel policy routes buckets "
-                          f"{sparse_routed} blocksparse but sparse "
-                          f"executables never served (folds "
-                          f"{snap['kernel']['folds']}, keys "
-                          f"{snap['executor']['keys']})",
-                          file=sys.stderr)
-                    return 1
-                bad_num = {b: d for b, d in
-                           report["kernel"]["numerics_max_diff"].items()
-                           if d > 1e-3}
-                if bad_num:
-                    print(f"SMOKE FAIL: block-sparse kernel numerics "
-                          f"diverge from the dense+mask reference: "
-                          f"{bad_num}", file=sys.stderr)
-                    return 1
         if recycle_policy is not None and args.converge_tol > 0:
             rec = snap["recycle"]
             if rec["recycles_skipped"] == 0 and rec["retired_early"] == 0:
@@ -1352,9 +1209,6 @@ def main(argv=None) -> int:
                  if cache_on else "")
         if mesh_policy is not None:
             extra += f", mesh folds {(snap.get('mesh') or {}).get('folds')}"
-        if kernel_policy is not None:
-            extra += (f", kernel folds "
-                      f"{(snap.get('kernel') or {}).get('folds')}")
         if args.cascade:
             extra += (f", cascade "
                       f"{snap['cascade']['draft_accepted']} accepted / "

@@ -93,19 +93,7 @@
 #      tests/test_continuous.py), and obs_report --check is clean over
 #      the continuous traces with admit spans present in the
 #      waterfall. The continuous-batching tripwire.
-#  11. per-bucket kernel selection (--kernel-policy,
-#      serve.KernelPolicy): the identical long-bucket step-scheduled
-#      workload run TWICE — dense baseline, then a blocksparse policy
-#      routing the bucket onto the block-skipping attention kernel.
-#      FAILS unless the sparse arm actually served through
-#      sparse-keyed ExecKey executables with kernel-tagged fold/
-#      recycle spans in its traces, the interpret-mode numerics check
-#      (kernel vs dense+mask reference on the served pattern) stays
-#      within 1e-3, and every request resolves ok in both arms; on a
-#      real TPU it additionally fails when the sparse arm loses
-#      folds/hour (skipped when clamped to CPU, where the masked-dense
-#      fallback serves and only routing + numerics are meaningful).
-#      The kernel-selection tripwire.
+#  (no 11: phase numbers are handles for SMOKE_PHASES and stay put)
 #  12. cross-bucket continuous batching (--cross-bucket --eager-form,
 #      RecyclePolicy(cross_bucket)): a skewed mixed-bucket workload
 #      (3:1 short vs flagship-bucket) with measured skewed
@@ -219,7 +207,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 DURATION="${SMOKE_DURATION_S:-30}"
-PHASES="${SMOKE_PHASES:-1,2,3,4,5,6,7,8,9,10,11,12,13,14,15,16,17,18}"
+PHASES="${SMOKE_PHASES:-1,2,3,4,5,6,7,8,9,10,12,13,14,15,16,17,18}"
 
 phase_on() {
     case ",${PHASES}," in
@@ -697,104 +685,6 @@ print(f"CONTINUOUS SMOKE OK: rows occupied "
       f"{base['rows_dead_steps']}), {spans['admit']} admit spans",
       file=sys.stderr)
 EOF
-fi
-
-# phase 11: per-bucket kernel selection (ISSUE 12) — the identical
-# long-bucket workload run TWICE: the dense baseline, then with a
-# blocksparse kernel policy routing the bucket onto the block-skipping
-# attention kernel. serve_loadtest --smoke fails in-process if the
-# sparse arm never executes a sparse-keyed ExecKey or its kernel
-# diverges from the dense+mask reference in the interpret-mode
-# numerics check; the compare below additionally fails on any bad
-# outcome, on missing kernel-tagged fold spans, and — on a real TPU —
-# on the sparse arm losing folds/hour (the speed gate is skipped when
-# the run is clamped to CPU, where the masked-dense fallback serves
-# and only routing + numerics are meaningful).
-if phase_on 11; then
-rm -f /tmp/serve_smoke_kernel_traces.jsonl
-
-kernel_phase() {  # $1 = report path, extra args follow
-    local out="$1"; shift
-    timeout -k 10 600 env JAX_PLATFORMS=cpu \
-        python tools/serve_loadtest.py \
-        --smoke \
-        --requests 32 \
-        --lengths 48,56,64 \
-        --buckets 64 \
-        --msa-depth 3 \
-        --max-batch 2 \
-        --concurrency 4 \
-        --deadline-s 120 \
-        --num-recycles 2 \
-        --recycle-sched \
-        "$@" > "$out"
-    cat "$out"
-}
-
-kernel_phase /tmp/serve_smoke_kernel_base.json \
-    --metrics-path /tmp/serve_smoke_kernel_base.jsonl
-kernel_phase /tmp/serve_smoke_kernel.json \
-    --kernel-policy blocksparse --sparse-block 8 \
-    --metrics-path /tmp/serve_smoke_kernel.jsonl \
-    --trace-path /tmp/serve_smoke_kernel_traces.jsonl \
-    --prom-path /tmp/serve_smoke_kernel.prom
-
-timeout -k 10 120 env JAX_PLATFORMS=cpu \
-    python tools/obs_report.py /tmp/serve_smoke_kernel_traces.jsonl \
-    --check --prom /tmp/serve_smoke_kernel.prom
-
-python - <<'EOF2'
-import json, sys
-base = json.load(open("/tmp/serve_smoke_kernel_base.json"))
-sparse = json.load(open("/tmp/serve_smoke_kernel.json"))
-problems = []
-kern = sparse.get("kernel") or {}
-bs_served = sum(v["served"] for k, v in kern.get("folds", {}).items()
-                if k.startswith("blocksparse"))
-if bs_served == 0:
-    problems.append("the sparse arm never served through a "
-                    "blocksparse executable")
-bad_num = {b: d for b, d in kern.get("numerics_max_diff", {}).items()
-           if d > 1e-3}
-if bad_num:
-    problems.append(f"kernel numerics diverge: {bad_num}")
-for rep in (base, sparse):
-    bad = rep["shed"] + rep["errors"] + rep["rejected"] + \
-        len(rep["failures"])
-    if bad or rep["served"] == 0:
-        problems.append(f"{bad} bad outcomes / {rep['served']} served "
-                        f"in {'sparse' if rep is sparse else 'base'} "
-                        "run")
-# kernel-tagged accelerator spans must be present and orphan-free
-# (obs --check above proved orphan-free; presence is checked here)
-tagged = 0
-for line in open("/tmp/serve_smoke_kernel_traces.jsonl"):
-    try:
-        rec = json.loads(line)
-    except ValueError:
-        continue
-    for s in rec.get("spans", ()):
-        if s.get("name") in ("fold", "recycle") and \
-                (s.get("attrs") or {}).get("kernel"):
-            tagged += 1
-if tagged == 0:
-    problems.append("no kernel-tagged fold/recycle spans in the "
-                    "sparse arm's traces")
-speed_gate = sparse.get("platform") != "cpu"
-if speed_gate and sparse["folds_per_hour"] < base["folds_per_hour"]:
-    problems.append(f"sparse folds/hour {sparse['folds_per_hour']} < "
-                    f"dense baseline {base['folds_per_hour']} on TPU")
-if problems:
-    print("KERNEL SMOKE FAIL: " + "; ".join(problems), file=sys.stderr)
-    sys.exit(1)
-note = "" if speed_gate else \
-    " (CPU masked-dense fallback: speed gate skipped)"
-print(f"KERNEL SMOKE OK: {bs_served} folds through blocksparse "
-      f"executables, {tagged} kernel-tagged spans, numerics "
-      f"{kern.get('numerics_max_diff')}, folds/hour "
-      f"{sparse['folds_per_hour']} vs dense {base['folds_per_hour']}"
-      f"{note}", file=sys.stderr)
-EOF2
 fi
 
 # phase 12: cross-bucket continuous batching (ISSUE 13) — a skewed
