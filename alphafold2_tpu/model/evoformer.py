@@ -8,23 +8,34 @@ residual), `Evoformer` = depth x block.
 
 TPU-first: instead of the reference's `checkpoint_sequential` (alphafold2.py:
 466), the stack runs under `nn.scan` over depth with per-layer remat
-(`nn.remat`) — constant compile time at depth 48 and O(1) stored activations
-per block, with XLA re-materializing each block's interior in the backward
-pass. Pair/MSA activations carry sharding constraints so the stack runs
-identically under a pjit mesh (see alphafold2_tpu/parallel).
+(`nn.remat`): constant compile time at depth 48. The scan stacks every
+block's carry (the pair and MSA tensors) for the backward pass, which makes
+each block's interior again, except what `remat_names` chooses to keep from
+the forward pass as well (`remat_block`'s policy, by name: the attention
+kernels' outputs first): values dear to make again, as many as the shapes,
+the depth and the device's memory allow; none where the trace sees no device
+memory (the CPU) or a mesh of several devices. Pair/MSA activations carry
+sharding constraints so the stack runs identically under a pjit mesh (see
+alphafold2_tpu/parallel).
 """
 
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from alphafold2_tpu import runtime
 from alphafold2_tpu.model.attention_variants import (
     DEFAULT_CONV_MSA_KERNELS,
     DEFAULT_CONV_SEQ_KERNELS,
     MultiKernelConvBlock,
 )
 from alphafold2_tpu.model.primitives import (
+    KEPT_ATTENTION,
+    KEPT_ATTENTION_OUT,
     AxialAttention,
     FeedForward,
     OuterMean,
@@ -303,9 +314,107 @@ class EvoformerBlock(nn.Module):
         return x, m
 
 
+def remat_block(names=()):
+    """`EvoformerBlock` rematerialised, keeping the marked values `names`
+    from the forward pass (none: the backward makes the whole block again).
+    The scanned trunk and the pipeline both build their block here."""
+    policy = jax.checkpoint_policies.save_only_these_names(*names) \
+        if names else None
+    return nn.remat(EvoformerBlock, static_argnums=(5,), prevent_cse=False,
+                    policy=policy)
+
+
+def device_bytes_limit():
+    """The memory of the device the trace is made for, as it reports it (a
+    v5e: 15.75 GiB), or None where the trace cannot see one: off the TPU, and
+    on a backend that reports no `bytes_limit`."""
+    if not runtime.on_tpu():
+        return None
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+GIB = 2 ** 30
+# What `remat_names` leaves of the device's limit: 1.5 GiB free, whatever it
+# keeps, and 1 GiB for what a training process holds beside the step's own
+# program (the benchmark's: 0.82 GiB over the compiler's peak with every set
+# tried, my chip runs, PR 34).
+REMAT_HEADROOM = 2.5 * GIB
+
+
+def remat_name_bytes(pair_shape, msa_shape, dtype, inner):
+    """((name, bytes a block keeps of it), ...) in the order `remat_names`
+    takes them, dearest to make again per byte first: both triangle and both
+    MSA attentions' kernel outputs (`inner` = heads x dim_head wide), then
+    their `to_out` outputs. Of the crop-256 step's 475.7 ms the first buys
+    20.4 for 192 MiB a block and the second 8.2 more for 96 (my chip runs,
+    PR 34; PERF.md section 6 has the names that cost more than they saved)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    positions = math.prod(pair_shape[:-1]) + math.prod(msa_shape[:-1])
+    return ((KEPT_ATTENTION, 2 * positions * inner * itemsize),
+            (KEPT_ATTENTION_OUT, 2 * positions * pair_shape[-1] * itemsize))
+
+
+def remat_names(pair_shape, msa_shape, depth, dtype, bytes_limit, *,
+                inner, param_bytes=0):
+    """Which marked values of a block the scanned trunk's backward keeps from
+    the forward pass instead of making them again: a function of the shapes,
+    the depth, the dtype, the device's memory and the active mesh alone.
+
+    The names are taken in `remat_name_bytes`' order, each while its bytes x
+    (depth + 1) still fit under `bytes_limit` less `REMAT_HEADROOM` less what
+    the step needs with nothing kept (`_need_with_nothing_kept`;
+    `param_bytes`: the trunk's parameters). Without a limit
+    (`device_bytes_limit`: the CPU, a described topology) or under a mesh of
+    more than one device nothing is kept, and the program is the one
+    `nn.remat` without a policy gives.
+    """
+    from alphafold2_tpu.parallel.sharding import active_mesh
+
+    mesh = active_mesh()
+    if bytes_limit is None or (mesh is not None and mesh.size > 1):
+        return ()
+    itemsize = jnp.dtype(dtype).itemsize
+    room = bytes_limit - REMAT_HEADROOM - _need_with_nothing_kept(
+        math.prod(pair_shape) * itemsize, math.prod(msa_shape) * itemsize,
+        depth, param_bytes)
+    names = []
+    for name, block_bytes in remat_name_bytes(pair_shape, msa_shape, dtype,
+                                              inner):
+        # every block's in the scan's stack, and the one the backward reads
+        room -= block_bytes * (depth + 1)
+        if room < 0:
+            break
+        names.append(name)
+    return tuple(names)
+
+
+def _need_with_nothing_kept(pair_bytes, msa_bytes, depth, param_bytes):
+    """The bytes a training step holds at its peak with nothing kept, state
+    and all, reckoned from the pair and MSA tensors' bytes, the depth and the
+    trunk's parameter bytes. Calibrated on what the v5e's compiler reports
+    (`memory_analysis().peak_memory_in_bytes`, what it holds against the
+    limit itself; Adam, batch 1, MSA 128, dim 256, bf16; PERF.md section 6,
+    PR 34) for crop / depth 256 / 12: 4.589 GiB, 256 / 24: 6.196, 256 / 48:
+    9.414, 384 / 12: 8.073, 384 / 48: 14.585, which this reproduces to 0.01:
+
+    - a layer costs its carry (the pair and MSA tensors the scan stacks) and
+      4.5 times its parameters (Adam's two moments, the parameters, their
+      gradient, a bf16 copy): 0.134 and 0.181 GiB a layer at 256 and 384;
+    - one block's backward, whatever the depth: 70.4 pair tensors and 21.8
+      MSA tensors (2.54 and 5.46 GiB);
+    - 0.44 GiB for the rest of that model (embeddings, heads, the structure
+      module, the extra-MSA stack and their state), which the trunk cannot
+      see: taken as it is.
+    """
+    return (70.4 * pair_bytes + 21.8 * msa_bytes + 0.44 * GIB
+            + depth * (pair_bytes + msa_bytes) + 4.5 * param_bytes)
+
+
 class Evoformer(nn.Module):
     """depth x EvoformerBlock under scan + remat (reference alphafold2.py:
-    448-467; memory scaling via checkpoint_sequential there, jax.remat here).
+    448-467; memory scaling via checkpoint_sequential there, jax.remat with
+    `remat_names`' policy here).
     """
 
     dim: int
@@ -390,6 +499,15 @@ class Evoformer(nn.Module):
                 "kv_compress_ratio per layer"
             variants.append(picks[0] if picks else "full")
         return tuple(variants), cr
+
+    def _kept_names(self, x, m):
+        """`remat_names` for this trunk at this trace's shapes."""
+        params = self.variables.get("params", {})
+        return remat_names(
+            x.shape, m.shape, self.depth, self.dtype, device_bytes_limit(),
+            inner=self.heads * self.dim_head,
+            param_bytes=sum(p.size * p.dtype.itemsize
+                            for p in jax.tree.leaves(params)))
 
     def _pipeline_ready(self, deterministic):
         """The active mesh if the pipeline path applies, else None."""
@@ -481,8 +599,8 @@ class Evoformer(nn.Module):
         boundary_dtype = jnp.float32 \
             if (on_cpu and act_dtype == jnp.bfloat16) else act_dtype
 
-        block = nn.remat(EvoformerBlock, static_argnums=(5,),
-                         prevent_cse=False)(**stage_kwargs, parent=None)
+        block = remat_block(self._kept_names(x, m))(**stage_kwargs,
+                                                    parent=None)
 
         def stage_fn(stage_params, act):
             xi, mi, pmask, mmask = act[:4]
@@ -604,13 +722,17 @@ class Evoformer(nn.Module):
                 block_kwargs["kv_compress_ratio"] = ratios[0]
 
         if self.use_scan and self.depth > 1 and uniform:
+            pp = self._pipeline_ready(deterministic)
+            if pp is not None and not self.is_initializing():
+                # params were created by the scan path at init; regroup
+                # the (depth, ...) stack into pp stages and run GPipe
+                return self._pipeline_forward(
+                    pp, block_kwargs, x, m, mask, msa_mask, deterministic)
+
             # remat each block, stack parameters along a scanned depth axis:
-            # constant compile time and one block of live activations.
-            block_cls = nn.remat(
-                EvoformerBlock,
-                static_argnums=(5,),
-                prevent_cse=False,
-            )
+            # constant compile time; the backward holds one block's interior
+            # and, of every block, its carry and what `remat_names` keeps.
+            block_cls = remat_block(self._kept_names(x, m))
 
             class ScanBody(nn.Module):
                 dtype: jnp.dtype = self.dtype
@@ -621,13 +743,6 @@ class Evoformer(nn.Module):
                     x, m = block_cls(**block_kwargs, name="block")(
                         x, m, mask, msa_mask, deterministic)
                     return (x, m), None
-
-            pp = self._pipeline_ready(deterministic)
-            if pp is not None and not self.is_initializing():
-                # params were created by the scan path at init; regroup
-                # the (depth, ...) stack into pp stages and run GPipe
-                return self._pipeline_forward(
-                    pp, block_kwargs, x, m, mask, msa_mask, deterministic)
 
             scan = nn.scan(
                 ScanBody,

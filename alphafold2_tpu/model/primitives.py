@@ -44,6 +44,7 @@ from typing import Optional
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import nn as jnn
+from jax.ad_checkpoint import checkpoint_name
 
 from alphafold2_tpu import runtime
 
@@ -51,6 +52,17 @@ from alphafold2_tpu import runtime
 # Large-negative fill for masked logits; -finfo.max in the reference
 # (alphafold2.py:165). A fixed large constant is safer in bf16.
 MASK_VALUE = -1e9
+
+# Marks (`jax.ad_checkpoint.checkpoint_name`) on the two values of a block
+# that are dear to make again and cheap to keep: what `model/evoformer.py`'s
+# remat policy may save from the forward pass, by name. A mark lowers to
+# nothing; outside a rematerialised, differentiated trace it is the value
+# itself. Tried on the chip and dropped, each for costing more in the scan's
+# stack than it saved (PERF.md section 6, PR 34): a transition's output, a
+# triangle multiply's contraction and its `to_out` output, the outer product
+# mean's product and output, the attention gate's pre-activation.
+KEPT_ATTENTION = "fused_attention_out"      # the fused kernel's output
+KEPT_ATTENTION_OUT = "attention_to_out"     # an attention's `to_out` output
 
 
 def zeros_init():
@@ -187,7 +199,7 @@ class Attention(nn.Module):
         XLA's and the ring's."""
         if self.gating:
             out_merged = out_merged * jnn.sigmoid(self._gating(x))
-        return self._to_out(out_merged)
+        return checkpoint_name(self._to_out(out_merged), KEPT_ATTENTION_OUT)
 
     def __call__(
         self,
@@ -247,7 +259,8 @@ class Attention(nn.Module):
             out = fused.fused_attention_merged(
                 q_merged, kv_merged, bias=attn_bias, q_mask=mask,
                 k_mask=cmask, heads=h, bias_repeat=attn_bias_repeat)
-            return self._gate_and_project(out, x)
+            return self._gate_and_project(
+                checkpoint_name(out, KEPT_ATTENTION), x)
 
         # everything else is XLA's einsum + softmax + einsum on
         # (b, h, n, dh): context, tied-row, dropped-out and meshed

@@ -71,12 +71,27 @@ _BY_COMPONENT = dict(KERNELS)
 FUSED_SCOPE = "fused_attention"
 
 
+def _parts(op_name: Optional[str]) -> list:
+    """The path components of an instruction's own `op_name` (XLA joins the
+    names of instructions it merged with ";": the first is its own)."""
+    return op_name.split(";", 1)[0].split("/") if op_name else []
+
+
 def is_fused(opcode: str, op_name: Optional[str]) -> bool:
     """Whether a device operation is the fused attention kernel itself (a
     custom call under `FUSED_SCOPE`), not the XLA attention a differentiated
     trace runs under the same scope."""
-    return opcode == "custom-call" and op_name is not None \
-        and FUSED_SCOPE in op_name.split(";", 1)[0].split("/")
+    return opcode == "custom-call" and FUSED_SCOPE in _parts(op_name)
+
+
+# The component `jax.checkpoint` puts on what a backward pass makes again
+REMAT_SCOPE = "rematted_computation"
+
+
+def is_remat(op_name: Optional[str]) -> bool:
+    """Whether a device operation is part of a forward pass run again under
+    `jax.checkpoint` (`nn.remat`) for the backward pass."""
+    return REMAT_SCOPE in _parts(op_name)
 
 
 # The scheduler worker's intervals that tile its time (serve/scheduler.py):
@@ -102,11 +117,7 @@ _INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
 
 def kernel_of(op_name: Optional[str]) -> str:
     """The kernel an `op_name` belongs to (`other` for none or no match)."""
-    if not op_name:
-        return "other"
-    # XLA joins the names of instructions it merged with ";": the first is
-    # the instruction's own
-    parts = op_name.split(";", 1)[0].split("/")
+    parts = _parts(op_name)
     if _SUBTREE in parts:
         return _BY_COMPONENT[_SUBTREE]
     for part in reversed(parts):
@@ -117,18 +128,14 @@ def kernel_of(op_name: Optional[str]) -> str:
 
 
 # components that say how the compiler got there, not where in the model
-_SCAFFOLD = ("while", "body", "closed_call", "checkpoint",
-             "rematted_computation")
+_SCAFFOLD = ("while", "body", "closed_call", "checkpoint", REMAT_SCOPE)
 
 
 def op_name_tail(op_name: Optional[str], components: int = 4) -> str:
     """The last few components of an `op_name`, loop and remat scaffolding
     left out: enough to tell where an instruction came from, short enough
     for a table."""
-    if not op_name:
-        return ""
-    parts = [p for p in op_name.split(";", 1)[0].split("/")
-             if p not in _SCAFFOLD]
+    parts = [p for p in _parts(op_name) if p not in _SCAFFOLD]
     return "/".join(parts[-components:])
 
 
@@ -266,11 +273,14 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     last. Returns
 
     - `window_s`, `busy_s` (the union of the operations' intervals), `events`;
-    - `kernels`: {kernel: {"seconds", "events", "fused_s"}} over
+    - `kernels`: {kernel: {"seconds", "events", "fused_s", "remat_s"}} over
       `KERNEL_NAMES`, containers left out: the kernels' seconds sum to
       `busy_s`; `fused_s` is the part of `seconds` spent in the fused
       attention kernel's custom calls (`is_fused`): the counter of a
-      mechanism that engages when the program is traced;
+      mechanism that engages when the program is traced; `remat_s` the part
+      spent making a forward pass again for the backward (`is_remat`): what
+      the trunk's remat policy (`model/evoformer.py`) buys back with memory;
+      `remat_s`, at the top: its sum over the kernels;
       `unnamed_s`: the part of `other` that had no `op_name`; `xla_flops` /
       `xla_bytes` per kernel where the profiler's events carry XLA's own
       counts (this installation's do not);
@@ -285,8 +295,8 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     """
     op_names = op_names or {}
     devices = _device_lines(profile_data)
-    kernels = {k: {"seconds": 0.0, "events": 0, "fused_s": 0.0}
-               for k in KERNEL_NAMES}
+    kernels = {k: {"seconds": 0.0, "events": 0, "fused_s": 0.0,
+                   "remat_s": 0.0} for k in KERNEL_NAMES}
     by_instr: Dict[str, float] = {}
     unnamed_ns = events = 0
     busy = []                    # each device's merged (start, end) intervals
@@ -309,6 +319,8 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
             entry["events"] += 1
             if is_fused(opcode, named):
                 entry["fused_s"] += e.duration_ns / 1e9
+            if is_remat(named):
+                entry["remat_s"] += e.duration_ns / 1e9
             events += 1
             if named is None:
                 unnamed_ns += e.duration_ns
@@ -328,6 +340,7 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     for entry in kernels.values():
         entry["seconds"] /= n
         entry["fused_s"] /= n
+        entry["remat_s"] /= n
 
     wanted, annotations, seen = set(spans), [], {}
     for plane in profile_data.planes:
@@ -348,6 +361,7 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
             "devices": [name for name, _ in devices],
             "kernels": kernels,
             "unnamed_s": unnamed_ns / n / 1e9,
+            "remat_s": sum(e["remat_s"] for e in kernels.values()),
             "top": [["%" + instr, kernel_of(op_names.get(instr)),
                      op_name_tail(op_names.get(instr)), seconds / n]
                     for instr, seconds in top],
@@ -364,9 +378,10 @@ def profile(executable, call: Callable[[], object],
     when the device has finished. Runs `call` once unprofiled, then `repeats`
     times under `jax.profiler` (python tracer off; the capture goes to a
     temporary directory that is removed), and returns `reduce`'s kernels
-    (`seconds` and `fused_s`), `unnamed_s`, `busy_s` and `top` PER EXECUTION, with `repeats` and the
-    `window_s` of all of them. Raises where the capture holds no device
-    operation (the CPU backend has no device plane).
+    (`seconds`, `fused_s` and `remat_s`), `unnamed_s`, `remat_s`, `busy_s`
+    and `top` PER EXECUTION, with `repeats` and the `window_s` of all of them.
+    Raises where the capture holds no device operation (the CPU backend has
+    no device plane).
     """
     import jax
 
@@ -395,7 +410,7 @@ def profile(executable, call: Callable[[], object],
     for entry in reduced["kernels"].values():
         entry["seconds"] = per(entry["seconds"])
         entry["events"] //= repeats
-        for key in ("fused_s", "xla_flops", "xla_bytes"):
+        for key in ("fused_s", "remat_s", "xla_flops", "xla_bytes"):
             if key in entry:
                 entry[key] = per(entry[key])
     return {"repeats": repeats, "window_s": reduced["window_s"],
@@ -403,4 +418,5 @@ def profile(executable, call: Callable[[], object],
             "events": reduced["events"] // repeats,
             "kernels": reduced["kernels"],
             "unnamed_s": per(reduced["unnamed_s"]),
+            "remat_s": per(reduced["remat_s"]),
             "top": [row[:3] + [per(row[3])] for row in reduced["top"]]}

@@ -246,20 +246,29 @@ def test_scan_fold_compiles_for_v5e(rule, one_chip, no_persistent_cache,
         "row_attn": {"msa_row_attention"}}, booked
 
 
+@pytest.mark.parametrize("sees", ("no_limit", "a_v5es_limit"))
 def test_train_step_books_every_fused_call_to_its_attention(
-        one_chip, no_persistent_cache, monkeypatch):
+        sees, one_chip, no_persistent_cache, monkeypatch):
     """A tiny training step (scan + remat, 64 residues, 64 alignment rows:
     the shortest every attention's rule admits) with the platform predicate
-    saying TPU: each of the four attentions of a block is three Mosaic custom
-    calls (the forward, remat's forward again, the backward), every one under
-    the fused scope and booked by `obs.device` to its attention's kernel,
-    none to `other`; and no tensor of the logits' shape is left."""
+    saying TPU. Where the trace sees no device memory (here, as on a
+    described topology) the remat policy keeps nothing and each of the four
+    attentions of a block is three Mosaic custom calls (the forward, remat's
+    forward again, the backward); handed a v5e's limit the trunk's rule
+    (`model/evoformer.py`) keeps the kernels' outputs and there are two, none
+    under `rematted_computation`. Either way every one is under the fused
+    scope and booked by `obs.device` to its attention's kernel, none to
+    `other`, and no tensor of the logits' shape is left."""
     import re
 
     from alphafold2_tpu import runtime, train
+    from alphafold2_tpu.model import evoformer
     from alphafold2_tpu.obs import device
 
     monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        evoformer, "device_bytes_limit",
+        lambda: int(15.75 * 2 ** 30) if sees == "a_v5es_limit" else None)
     n, heads = 64, 2
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     model = Alphafold2(dim=32, depth=2, heads=heads, dim_head=16,
@@ -288,13 +297,14 @@ def test_train_step_books_every_fused_call_to_its_attention(
             assert device.is_fused("custom-call", op_name), op_name
             site = [p for p in op_name.split("/")
                     if p in device._BY_COMPONENT][-1]
-            part = "backward" if "transpose(" in op_name \
-                and "rematted_computation" not in op_name else "forward"
+            part = "again" if device.is_remat(op_name) else \
+                "backward" if "transpose(" in op_name else "forward"
             booked.setdefault((site, device.kernel_of(op_name)),
                               []).append(part)
+    calls = ["again", "backward", "forward"] if sees == "no_limit" \
+        else ["backward", "forward"]
     assert {k: sorted(v) for k, v in booked.items()} == {
-        (site, kernel): ["backward", "forward", "forward"]
-        for site, kernel in (
+        (site, kernel): calls for site, kernel in (
             ("triangle_attention_outgoing", "triangle_attention"),
             ("triangle_attention_ingoing", "triangle_attention"),
             ("row_attn", "msa_row_attention"),

@@ -237,6 +237,65 @@ def test_reduce_books_the_fused_kernels_time_beside_its_kernels():
                if k not in ("triangle_attention", "msa_row_attention"))
 
 
+REMAT = ("jit(step)/transpose(jvp(Alphafold2))/net/while/body/closed_call/"
+         "layers/checkpoint/rematted_computation/block/attn/"
+         "triangle_multiply_outgoing/to_out/dot_general")
+
+
+@pytest.mark.parametrize("op_name,remat", [
+    (REMAT, True),
+    (REMAT + ";jit(step)/jvp(Alphafold2)/net/reshape", True),
+    # the same module in the forward pass and in the backward proper
+    (REMAT.replace("checkpoint/rematted_computation/", "checkpoint/"), False),
+    # merged INTO an instruction of the backward: that one's name decides
+    ("jit(step)/transpose(jvp(Alphafold2))/net/mul;" + REMAT, False),
+    (None, False),
+])
+def test_is_remat(op_name, remat):
+    assert device.is_remat(op_name) is remat
+
+
+def test_reduce_books_the_forward_made_again_beside_its_kernels():
+    """`remat_s`, what a remat policy moves: the device seconds of operations
+    under `rematted_computation`, in all and within the `seconds` of the
+    kernel each belongs to; the same module's forward and backward are not
+    counted."""
+    from types import SimpleNamespace as NS
+    forward = REMAT.replace("checkpoint/rematted_computation/", "checkpoint/")
+    table = {"fusion.1": REMAT, "fusion.2": forward,
+             "fused_attention.3": FUSED.replace(
+                 "net/block", "net/checkpoint/rematted_computation/block"),
+             "fusion.4": REMAT.replace("attn/triangle_multiply_outgoing",
+                                       "msa_ff")}
+    durations = {"fusion.1": 2_000_000, "fusion.2": 3_000_000,
+                 "fused_attention.3": 1_000_000, "fusion.4": 500_000,
+                 "fusion.5": 250_000}       # no name in the table: `other`
+    start, events = 10_000, []
+    for instr, ns in durations.items():
+        opcode = "custom-call" if instr.startswith("fused") else "fusion"
+        events.append(NS(name=f"%{instr} = bf16[8,64,128] {opcode}(bf16[8] "
+                         "%a)", start_ns=start, duration_ns=ns, stats=()))
+        start += ns + 100
+    data = NS(planes=[NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=events)])])
+    reduced = device.reduce(data, table)
+    kernels = reduced["kernels"]
+    assert kernels["triangle_multiply"]["remat_s"] == pytest.approx(2e-3)
+    assert kernels["triangle_multiply"]["seconds"] == pytest.approx(5e-3)
+    assert kernels["triangle_attention"]["remat_s"] == pytest.approx(1e-3) \
+        == pytest.approx(kernels["triangle_attention"]["fused_s"])
+    assert kernels["transition"]["remat_s"] == pytest.approx(5e-4)
+    assert kernels["other"]["remat_s"] == 0 < kernels["other"]["seconds"]
+    assert reduced["remat_s"] == pytest.approx(3.5e-3)
+    assert all(k["remat_s"] <= k["seconds"] for k in kernels.values())
+
+
+def test_capture_of_a_fold_books_no_forward_made_again(capture):
+    _, reduced = capture     # a fold is not differentiated
+    assert reduced["remat_s"] == 0
+    assert all(k["remat_s"] == 0 for k in reduced["kernels"].values())
+
+
 def test_capture_of_the_xla_attention_books_no_fused_time(capture):
     _, reduced = capture     # recorded before the kernel took the folds
     assert all(k["fused_s"] == 0 for k in reduced["kernels"].values())
