@@ -314,14 +314,16 @@ class EvoformerBlock(nn.Module):
         return x, m
 
 
-def remat_block(names=()):
+def remat_block(names=(), block=None, static_argnums=(5,),
+                prevent_cse=False):
     """`EvoformerBlock` rematerialised, keeping the marked values `names`
     from the forward pass (none: the backward makes the whole block again).
-    The scanned trunk and the pipeline both build their block here."""
+    The scanned trunk and the pipeline both build their block here; the token
+    decoder hands in its own layer (`block`, unrolled: `prevent_cse`)."""
     policy = jax.checkpoint_policies.save_only_these_names(*names) \
         if names else None
-    return nn.remat(EvoformerBlock, static_argnums=(5,), prevent_cse=False,
-                    policy=policy)
+    return nn.remat(block or EvoformerBlock, static_argnums=static_argnums,
+                    prevent_cse=prevent_cse, policy=policy)
 
 
 def device_bytes_limit():

@@ -13,7 +13,8 @@ its time, not its `op_name`; the executable's own text has both, so only the
 program can join them. Three parts, one clock (the profiler's):
 
 - `KERNELS` / `kernel_of`: the vocabulary, from path components of an
-  `op_name` to eight kernel names;
+  `op_name` to the kernels' names (the folder's seven, the token decoder's
+  six, `other`);
 - `profile(executable, call)`: run one compiled program under the profiler
   and return its device seconds per execution by kernel;
 - `reduce(profile_data, op_names)`: the reduction itself, also of a capture
@@ -40,9 +41,15 @@ from typing import Callable, Dict, Optional
 # Ordered: the kernels as every table prints them. `other` holds embeddings,
 # the recycling embedder, heads, loss, optimizer, glue, and whatever has no
 # `op_name` at all (reported apart as `unnamed_s`).
-KERNEL_NAMES = ("triangle_multiply", "triangle_attention",
-                "msa_row_attention", "msa_col_attention",
-                "outer_product_mean", "transition", "structure", "other")
+FOLD_KERNEL_NAMES = ("triangle_multiply", "triangle_attention",
+                     "msa_row_attention", "msa_col_attention",
+                     "outer_product_mean", "transition", "structure")
+# the causal token decoder's (`model/decoder.py`): its modules' own names.
+# `expert_router` holds the scores, the choice, the rows' indices and both
+# gathers; `lm_head` the embedding, the head and the token loss
+DECODER_KERNEL_NAMES = ("mla_attention", "expert_router", "expert_mlp",
+                        "shared_expert", "dense_mlp", "lm_head")
+KERNEL_NAMES = FOLD_KERNEL_NAMES + DECODER_KERNEL_NAMES + ("other",)
 
 # Path component (a flax module name, so a key of the parameter tree) ->
 # kernel. A component may stand anywhere in the path: backward passes
@@ -62,6 +69,10 @@ KERNELS = (
     ("outer_mean", "outer_product_mean"),
     ("ff", "transition"),
     ("msa_ff", "transition"),
+) + tuple((name, name) for name in DECODER_KERNEL_NAMES) + (
+    # what the expert layer does outside its named parts (the sum of the
+    # cotangents of its normed input) is the routing's glue
+    ("moe", "expert_router"),
 )
 _SUBTREE = KERNELS[0][0]
 _BY_COMPONENT = dict(KERNELS)
@@ -153,11 +164,19 @@ def instruction_op_names(hlo_text: str) -> Dict[str, str]:
             if head and " = " not in line.split("(", 1)[0]:
                 current = computations.setdefault(head.group(1), [])
             continue
-        if line.startswith("}"):
+        if line.rstrip() == "}":
             current = None
             continue
-        m = _INSTRUCTION.match(line)
+        m = _INSTRUCTION.match(line) if line.startswith(" ") else None
         if not m:
+            # An instruction's text may run over several lines (a Pallas
+            # call of the blocked causal attention carries a JSON attribute
+            # with line breaks, and its `metadata` comes after it, on a line
+            # that begins "}}"): the name belongs to the instruction above.
+            named = _OP_NAME.search(line)
+            if named and current and current[-1][2] is None:
+                current[-1] = current[-1][:2] + (named.group(1),) \
+                    + current[-1][3:]
             continue
         rest = m.group(3)
         opcode = _OPCODE.search(rest)

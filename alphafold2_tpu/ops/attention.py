@@ -710,3 +710,90 @@ def attention_reference(q, k, v, bias=None, q_mask=None, k_mask=None,
         logits = jnp.where(valid, logits, MASK_VALUE)
     attn = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bnm,bmd->bnd", attn.astype(q.dtype), v)
+
+
+# -- causal attention for a token decoder -----------------------------------
+#
+# A decoder's self-attention over thousands of keys, with keys wider than
+# values (latent attention: 192-wide q and k, 128-wide v). Nothing above is
+# touched by it: the axial kernel is non-causal, takes one head width and a
+# whole row of keys. Here the blocked flash kernel that ships with JAX
+# (`jax.experimental.pallas.ops.tpu.splash_attention`) does the work: it takes
+# a key width and a value width of its own without padding v, visits only the
+# blocks on and under the diagonal (the block mask is static: the grid does
+# not follow the data), and differentiates through a `custom_vjp` of two more
+# Pallas kernels (dq; dk and dv) that make the logits again block by block.
+# Logits never reach HBM, forward or backward.
+
+CAUSAL_SCOPE = "causal_attention"
+# the mark on the kernel's output and its log-sum-exp: what a rematerialised
+# decoder layer keeps from its forward pass (`model/decoder.py`)
+KEPT_CAUSAL = "causal_attention_out"
+# query and key rows a grid step takes: the largest of these that divides n
+_CAUSAL_BLOCKS = (1024, 512, 256, 128)
+
+
+def causal_admits(n: int) -> bool:
+    """Whether the blocked kernel takes n positions (a multiple of its
+    smallest block)."""
+    return n % _CAUSAL_BLOCKS[-1] == 0
+
+
+def causal_attention_reference(q, k, v):
+    """Masked dense causal attention, float32 logits: the kernel's contract
+    (tests), and the path off the chip. q, k: (b, h, n, dk), q pre-scaled;
+    v: (b, h, n, dv)."""
+    n = q.shape[2]
+    logits = jnp.einsum("bhid,bhjd->bhij", q, k,
+                        preferred_element_type=jnp.float32)
+    visible = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+    attn = jax.nn.softmax(jnp.where(visible, logits, MASK_VALUE), axis=-1)
+    return jnp.einsum("bhij,bhjd->bhid", attn.astype(v.dtype), v)
+
+
+@functools.lru_cache(maxsize=None)
+def _causal_kernel(heads: int, n: int, interpret: bool):
+    """The splash kernel for `heads` heads of n positions under one causal
+    mask, built once a shape (its block tables are numpy, made on the host)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+        splash_attention_mask as masks,
+    )
+    # 1,024 rows of queries and of keys a grid step, the keys computed 512 at
+    # a time: the fastest of nine sets at 2 x 32 heads x 8,192 on the v5e that
+    # keep dq a kernel of its own, 14.5 ms forward, 56.7 with the backward
+    # (PERF.md, PR 35). The fused dq/dk/dv kernel took 49.3, but holds a
+    # float32 dq for every block of keys (3.2 GB there); 2,048 rows do not
+    # fit VMEM
+    block = next(b for b in _CAUSAL_BLOCKS if n % b == 0)
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=min(block, 512),
+        block_q_dkv=block, block_kv_dkv=block,
+        block_kv_dkv_compute=min(block, 512),
+        block_q_dq=block, block_kv_dq=block)
+    mask = masks.MultiHeadMask([masks.CausalMask((n, n))] * heads)
+    # built under `ensure_compile_time_eval`: the tables are constants of
+    # whichever trace asks first, not tracers of it
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(
+            mask, block_sizes=sizes, head_shards=1, q_seq_shards=1,
+            residual_checkpoint_name=KEPT_CAUSAL, interpret=interpret)
+
+
+@jax.named_scope(CAUSAL_SCOPE)
+def causal_attention(q, k, v, *, interpret: bool = False):
+    """softmax(q k^T + causal mask) v, blocked, forward and backward, logits
+    in VMEM only. q, k: (b, h, n, dk), q pre-scaled; v: (b, h, n, dv); the
+    output has v's width: (b, h, n, dv). n has to be a multiple of 128
+    (`causal_admits`)."""
+    batch, heads, n, _ = q.shape
+    if not causal_admits(n):
+        raise ValueError(f"causal_attention: {n} positions are no multiple "
+                         f"of {_CAUSAL_BLOCKS[-1]}")
+    # the batch folded into the heads (all under one mask), not `vmap`ped:
+    # a batched Pallas call loses its `op_name`, which the profile's reader
+    # (obs/device.py) goes by
+    fold = lambda t: t.reshape(batch * heads, n, t.shape[-1])
+    out = _causal_kernel(batch * heads, n, interpret)(fold(q), fold(k),
+                                                      fold(v))
+    return out.reshape(batch, heads, n, v.shape[-1])

@@ -3,6 +3,7 @@ from alphafold2_tpu.train.checkpoint import CheckpointManager  # noqa: F401
 from alphafold2_tpu.train.loop import (  # noqa: F401
     compute_loss,
     fit,
+    make_decoder_train_step,
     make_eval_step,
     make_recycled_train_step,
     make_train_step,

@@ -101,8 +101,10 @@ def compute_loss(model, params, batch, rng, train: bool = True,
     return loss, metrics
 
 
-def make_train_step(model):
-    """Build the jitted train step: state, batch -> state, metrics."""
+def _make_step(loss_fn):
+    """The one step builder: `loss_fn(params, batch, rng) -> (loss,
+    metrics)` becomes state, batch -> state, metrics (gradient, the
+    optimizer's update, the carried key split)."""
 
     def train_step(state: TrainState, batch):
         rng, new_rng = jax.random.split(state.rng)
@@ -111,15 +113,38 @@ def make_train_step(model):
         # outside the model, and the profiler's reader (obs/device.py)
         # goes by names
         @jax.named_scope("loss")
-        def loss_fn(params):
-            return compute_loss(model, params, batch, rng, train=True)
+        def scoped(params):
+            return loss_fn(params, batch, rng)
 
-        grads, metrics = jax.grad(loss_fn, has_aux=True)(state.params)
+        grads, metrics = jax.grad(scoped, has_aux=True)(state.params)
         with jax.named_scope("optimizer"):
             new_state = state.apply_gradients(grads=grads)
         return new_state.replace(rng=new_rng), metrics
 
     return train_step
+
+
+def make_train_step(model):
+    """Build the jitted train step: state, batch -> state, metrics."""
+    return _make_step(lambda params, batch, rng: compute_loss(
+        model, params, batch, rng, train=True))
+
+
+def make_decoder_train_step(model):
+    """The step of a causal token decoder (`model/decoder.CausalDecoder`):
+    `batch["tokens"]` is (b, n + 1); the model reads the first n and is
+    held to the last n (mean next-token cross-entropy over the vocabulary
+    held, float32 logits). The expert layers' counters (`expert_slots`,
+    `expert_overflow`, `expert_max_load`) come back beside the loss."""
+
+    def loss_fn(params, batch, rng):
+        tokens = batch["tokens"]
+        logits, counters = model.apply(params, tokens[:, :-1])
+        with jax.named_scope("lm_head"):
+            loss = losses.next_token_loss(logits, tokens[:, 1:])
+        return loss, dict(counters, loss=loss)
+
+    return _make_step(loss_fn)
 
 
 def make_recycled_train_step(model, max_recycles: int = 3):

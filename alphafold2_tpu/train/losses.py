@@ -100,3 +100,12 @@ def lddt_confidence_loss(
     pred = jax.nn.sigmoid(pred_confidence[..., 0].astype(jnp.float32))
     m = mask.astype(jnp.float32)
     return (((pred - target) ** 2) * m).sum() / jnp.maximum(m.sum(), 1.0)
+
+
+def next_token_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """Mean next-token cross-entropy of a causal decoder: logits (b, n, v)
+    at positions 0..n-1 against `targets` (b, n), the tokens at 1..n; float32,
+    as log-sum-exp less the target's logit (no (b, n, v) log-softmax is kept)."""
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)

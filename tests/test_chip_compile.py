@@ -333,7 +333,7 @@ def test_the_chips_program_names_its_kernels(program, one_chip,
         parts = op_name.split("/")
         if "net" in parts or "structure_module" in parts:
             kernels.add(device.kernel_of(op_name))
-    assert kernels == set(device.KERNEL_NAMES) - {"other"}
+    assert kernels == set(device.FOLD_KERNEL_NAMES)
 
     table = device.instruction_op_names(text)
     fused = set(re.findall(r"\bcalls=%?([\w.\-]+)", text))
@@ -348,3 +348,59 @@ def test_the_chips_program_names_its_kernels(program, one_chip,
             run += 1
             named += m.group(2) in table
     assert run > 100 and named >= 0.99 * run, (named, run)
+
+
+# -- the token decoder's causal attention ------------------------------------
+
+@pytest.mark.parametrize("n", [8192, 1152])
+def test_causal_attention_compiles_for_v5e(n, one_chip, no_persistent_cache):
+    """The blocked causal kernel at the published widths of the benchmark's
+    decoder (32 heads, 192-wide keys, 128-wide values, bf16), forward and
+    backward: three Mosaic calls (forward, dq, dk and dv), at the cell's
+    8,192 positions (blocks of 1,024) and at a length only the smallest
+    block divides."""
+    from alphafold2_tpu.ops.attention import causal_attention
+
+    heads, dk, dv = 32, 192, 128
+    shape = lambda width: jax.ShapeDtypeStruct(
+        (1, heads, n, width), jnp.bfloat16, sharding=one_chip)
+
+    def loss_gradients(q, k, v):
+        return jax.grad(lambda *a: causal_attention(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    # the suite's float32 default would ask Mosaic for float32 passes over
+    # bf16 operands; the chip's default asks for none
+    with jax.default_matmul_precision("default"):
+        text = _compiled_kernel_text(loss_gradients,
+                                     (shape(dk), shape(dk), shape(dv)))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 3, len(calls)
+
+
+def test_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The expert layer's grouped matmuls at the benchmark's decoder's own
+    sizes (a buffer of 6 x 16,384 slots and a tile for each of 16 held
+    experts, 2,048 -> 768 -> 2,048, bf16), forward and backward: six Mosaic
+    calls (y, dx and dw of each of two matmuls)."""
+    from alphafold2_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, dim, width, held, tile = 6 * 16384 + 16 * 512, 2048, 768, 16, 512
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dtype, sharding=one_chip)
+
+    def loss_gradients(x, w_in, w_out, tile_group):
+        def loss(x, w_in, w_out):
+            hidden = grouped_matmul(x, w_in, tile_group)
+            return grouped_matmul(hidden, w_out, tile_group).astype(
+                jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(x, w_in, w_out)
+
+    with jax.default_matmul_precision("default"):
+        text = _compiled_kernel_text(loss_gradients, (
+            shape(rows, dim), shape(held, dim, width),
+            shape(held, width, dim), shape(rows // tile, dtype=jnp.int32)))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 5, len(calls)

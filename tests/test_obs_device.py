@@ -23,7 +23,7 @@ from alphafold2_tpu.serve import (BucketPolicy, FoldRequest, Scheduler,
                                   SchedulerConfig, ServeMetrics)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-NAMED = set(device.KERNEL_NAMES) - {"other"}
+NAMED = set(device.FOLD_KERNEL_NAMES)       # the folder's: what these programs run
 
 
 # -- the vocabulary ----------------------------------------------------------
@@ -64,8 +64,11 @@ def test_kernel_of(op_name, kernel):
 
 
 def test_the_table_names_only_the_kernels():
-    assert {kernel for _, kernel in device.KERNELS} == NAMED
-    assert len(device.KERNEL_NAMES) == 8
+    assert {kernel for _, kernel in device.KERNELS} == \
+        set(device.KERNEL_NAMES) - {"other"}
+    assert NAMED < set(device.KERNEL_NAMES)
+    assert len(device.KERNEL_NAMES) == 14
+    assert len(device.FOLD_KERNEL_NAMES) == 7
 
 
 HLO = """HloModule jit_f, is_scheduled=true
@@ -409,3 +412,104 @@ def test_worker_counters_tile_its_time_in_service():
     assert snap["worker_idle_s"] > 0 and snap["worker_hold_s"] > 0
     assert snap["fetch_s"] + snap["resolve_s"] < snap["worker_busy_s"]
     assert snap["exec_busy_s"] <= snap["worker_busy_s"]
+
+
+# -- the token decoder's names, beside the folder's ---------------------------
+
+# the table as it stood before the decoder's names joined it (PR 34), and its
+# rule: everything under the structure module is the structure module's, else
+# the innermost component that the table maps
+FOLD_TABLE = {
+    "structure_module": "structure",
+    "triangle_multiply_outgoing": "triangle_multiply",
+    "triangle_multiply_ingoing": "triangle_multiply",
+    "triangle_attention_outgoing": "triangle_attention",
+    "triangle_attention_ingoing": "triangle_attention",
+    "row_attn": "msa_row_attention", "col_attn": "msa_col_attention",
+    "outer_mean": "outer_product_mean", "ff": "transition",
+    "msa_ff": "transition"}
+
+
+def _fold_verdict(op_name):
+    parts = op_name.split(";", 1)[0].split("/") if op_name else []
+    if "structure_module" in parts:
+        return "structure"
+    return next((FOLD_TABLE[p] for p in reversed(parts) if p in FOLD_TABLE),
+                "other")
+
+
+def test_the_fold_tables_verdicts_are_what_they_were():
+    """On the `op_name`s of a fold recorded on the v5e: each goes where the
+    folder's table alone sent it, none to a kernel of the decoder."""
+    with open(os.path.join(DATA, "worker_capture.json")) as f:
+        recorded = sorted(set(json.load(f)["op_names"].values()))
+    assert len(recorded) > 100
+    verdicts = {name: device.kernel_of(name) for name in recorded}
+    assert verdicts == {name: _fold_verdict(name) for name in recorded}
+    assert set(verdicts.values()) <= set(device.FOLD_KERNEL_NAMES) | {"other"}
+    assert len(set(verdicts.values())) >= 6
+    assert not set(FOLD_TABLE) & set(device.DECODER_KERNEL_NAMES)
+
+
+def test_every_decoder_instruction_lands_in_a_named_kernel():
+    """A tiny training step of the causal decoder, traced and compiled here:
+    every instruction whose `op_name` passes through the model belongs to one
+    of the decoder's kernels, forward, backward and made again, and every one
+    of the six has some."""
+    import jax
+    from benchmark import weights
+    from benchmark.drivers import train_steps
+    from benchmark.families import kanana2
+    with open(os.path.join(os.path.dirname(DATA), os.pardir, "benchmark",
+                           "configs", "kanana2_30b_a3b_ep8.json")) as f:
+        config = {**json.load(f), **kanana2.TINY}
+    traffic = dict(batch=2, tokens=16, learning_rate=3e-4)
+    model = kanana2.build_model(config)
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype)
+    shapes = kanana2.param_shapes(model)
+    _, step, args = train_steps.largest_program(model, shapes, config,
+                                                traffic, place)
+    # an executable from the persistent cache keeps the names of the trace
+    # that first compiled it: compile this one anew
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = step.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    table = device.instruction_op_names(text)
+    parts = lambda name: name.split(";", 1)[0].split("/")
+    inside = [name for name in table.values()        # `remat2`: the call
+              if "CausalDecoder" in parts(name)      # that holds a layer
+              and parts(name)[-1] != "remat2"]
+    assert len(inside) > 100
+    verdicts = {name: device.kernel_of(name) for name in inside}
+    astray = sorted(n for n, k in verdicts.items()
+                    if k not in device.DECODER_KERNEL_NAMES)
+    assert not astray, astray[:10]
+    assert set(verdicts.values()) == set(device.DECODER_KERNEL_NAMES)
+    assert any(device.is_remat(name) for name in inside)
+
+
+def test_an_instruction_over_several_lines_keeps_its_name_and_its_module():
+    """A Pallas call of the blocked causal attention carries an attribute
+    with line breaks; its `metadata` follows on a line that begins "}}". The
+    computation goes on after it, and the call takes its own name."""
+    text = "\n".join([
+        "HloModule jit_step, is_scheduled=true", "",
+        "ENTRY %main.9 (p0: bf16[8]) -> bf16[8] {",
+        "  %p0 = bf16[8]{0} parameter(0)",
+        '  %splash_mha_fwd.1 = bf16[8]{0} custom-call(%p0), '
+        'custom_call_target="tpu_custom_call", frontend_attributes={'
+        'kernel_metadata={', '"xprof_metadata":"{\\"block_q\\": 1024}"',
+        '}}, metadata={op_name="jit(step)/jvp(loss)/CausalDecoder/layers_0/'
+        'mla_attention/causal_attention/pallas_call" stack_frame_id=3}',
+        '  ROOT %add.2 = bf16[8]{0} add(%splash_mha_fwd.1, %p0), metadata={'
+        'op_name="jit(step)/jvp(loss)/CausalDecoder/layers_0/dense_mlp/add"}',
+        "}", ""])
+    table = device.instruction_op_names(text)
+    assert device.kernel_of(table["splash_mha_fwd.1"]) == "mla_attention"
+    assert device.kernel_of(table["add.2"]) == "dense_mlp"
