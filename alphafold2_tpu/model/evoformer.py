@@ -75,10 +75,10 @@ class PairwiseAttentionBlock(nn.Module):
 
         x = TriangleMultiplicativeModule(
             dim=self.dim, mix="outgoing", dtype=self.dtype,
-            name="triangle_multiply_outgoing")(x, mask=mask) + x
+            name="triangle_multiply_outgoing")(x, mask=mask, residual=x)
         x = TriangleMultiplicativeModule(
             dim=self.dim, mix="ingoing", dtype=self.dtype,
-            name="triangle_multiply_ingoing")(x, mask=mask) + x
+            name="triangle_multiply_ingoing")(x, mask=mask, residual=x)
         x = shard_pair(x)
         x = AxialAttention(
             dim=self.dim, heads=self.heads, dim_head=self.dim_head,

@@ -13,7 +13,8 @@ Behavioral parity with the reference blocks
   the off-axis into batch, with optional pair-edge -> per-head bias
   (alphafold2.py:192-255);
 - `TriangleMultiplicativeModule`: outgoing/ingoing triangle multiplicative
-  update with identity-initialized gates (alphafold2.py:257-317);
+  update with identity-initialized gates (alphafold2.py:257-317); the module
+  holds the parameter leaves and `ops/triangle_multiply.py` computes;
 - `OuterMean`: MSA -> pair outer-product mean (alphafold2.py:321-351).
 
 TPU notes: weights live in fp32; activations run in `dtype` (bf16 by default
@@ -35,11 +36,17 @@ the trace can see, with three outcomes and no switch a user sets:
   kernel (PR 32);
 - XLA's einsum + softmax + einsum otherwise: context, tied-row and meshed
   attention, and the kernel's reference in the tests.
+
+The triangle multiplicative update takes the same rule
+(`TriangleMultiplicativeModule.__call__`): the fused stages of
+`ops/triangle_multiply.py` on a TPU, on one device, for a shape they admit;
+`ops.triangle_multiply.triangle_multiply_xla` otherwise and under
+differentiation.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import jax.numpy as jnp
 from flax import linen as nn
@@ -446,12 +453,69 @@ class AxialAttention(nn.Module):
         return out
 
 
+class _DenseLeaves(nn.Module):
+    """The leaves of an `nn.Dense` under its name in the parameter tree
+    (`kernel`, `bias`, float32, its initialisers), for a caller that computes
+    with the arrays itself."""
+
+    features: int
+    kernel_init: Callable = nn.linear.default_kernel_init
+    bias_init: Callable = zeros_init()
+
+    @nn.compact
+    def __call__(self, width):
+        return {"kernel": self.param("kernel", self.kernel_init,
+                                     (width, self.features), jnp.float32),
+                "bias": self.param("bias", self.bias_init,
+                                   (self.features,), jnp.float32)}
+
+
+class _LayerNormLeaves(nn.Module):
+    """The leaves of `LayerNorm` (the wrapper above and the `nn.LayerNorm`
+    inside it: `<name>/LayerNorm_0/{scale, bias}`)."""
+
+    @nn.compact
+    def __call__(self, width):
+        return {"LayerNorm_0": _ScaleAndBias(name="LayerNorm_0")(width)}
+
+
+class _ScaleAndBias(nn.Module):
+    @nn.compact
+    def __call__(self, width):
+        return {"scale": self.param("scale", ones_init(), (width,),
+                                    jnp.float32),
+                "bias": self.param("bias", zeros_init(), (width,),
+                                   jnp.float32)}
+
+
 class TriangleMultiplicativeModule(nn.Module):
     """Triangle multiplicative update (reference alphafold2.py:257-317).
 
     mix='outgoing': out[i,j] = sum_k left[i,k] * right[j,k]
     mix='ingoing' : out[i,j] = sum_k left[k,j] * right[k,i]
-    The O(L^3 d) contraction is a batched matmul -> lands on the MXU.
+    The O(L^3 d) contraction is a batched matmul, a quarter of the update's
+    arithmetic; what bounds the update is memory. As layer norm, five Dense
+    layers, an einsum, a layer norm and a Dense layer XLA reads the pair
+    tensor six times and relays operands round the contraction in four
+    whole-tensor copies (6.39 GB a call at 640 where the mathematics needs
+    2.3; PERF.md section 5, PR 36).
+
+    Which path runs is decided here, from what the trace can see, the
+    attention's rule: on a TPU (or behind `ops.attention.
+    use_pallas_attention`, the CPU tests' door, interpreted), on one device
+    (GSPMD cannot partition the custom calls: the pair-sharded fold keeps
+    XLA's), for a shape `ops.triangle_multiply.admits` accepts (a side that
+    is a multiple of 64, a hidden width of whole lane tiles, a pair tensor
+    over 32 MiB: smaller ones XLA keeps on the chip), the update is
+    `ops.triangle_multiply.fused_triangle_multiply`: three Pallas stages that
+    read `x` once and write the result once, `residual` added in the last.
+    Everything else is `triangle_multiply_xla`, the one `jax.numpy`
+    formulation (also the kernels' reference and their backward). The module
+    holds the parameter leaves under the names the Dense layers and layer
+    norms gave them and hands the arrays to either.
+
+    Returns the update, + `residual` where one is given (the block passes
+    `x`: the fused path adds it where the result is written).
     """
 
     dim: int
@@ -460,43 +524,37 @@ class TriangleMultiplicativeModule(nn.Module):
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x, mask=None):
+    def __call__(self, x, mask=None, residual=None):
+        from alphafold2_tpu.ops import attention as fused_attention
+        from alphafold2_tpu.ops import triangle_multiply as fused
+        from alphafold2_tpu.parallel.sharding import active_mesh
+
         assert self.mix in ("ingoing", "outgoing")
         assert x.shape[1] == x.shape[2], "feature map must be square"
         hidden = self.hidden_dim or self.dim
 
-        dense = lambda features, name, **kw: nn.Dense(
-            features, dtype=self.dtype, param_dtype=jnp.float32,
-            name=name, **kw)
-
-        if mask is not None:
-            mask = mask[..., None].astype(x.dtype)
-
-        x = LayerNorm(dtype=self.dtype)(x)
-
-        left = dense(hidden, "left_proj")(x)
-        right = dense(hidden, "right_proj")(x)
-
-        if mask is not None:
-            left = left * mask
-            right = right * mask
-
         # gates initialized to identity (reference alphafold2.py:280-282)
-        gate = lambda name: jnn.sigmoid(
-            dense(hidden, name, kernel_init=zeros_init(),
-                  bias_init=ones_init())(x))
-        left = left * gate("left_gate")
-        right = right * gate("right_gate")
-        out_gate = gate("out_gate")
+        gate = dict(kernel_init=zeros_init(), bias_init=ones_init())
+        p = {"LayerNorm_0": _LayerNormLeaves(name="LayerNorm_0")(self.dim),
+             "LayerNorm_1": _LayerNormLeaves(name="LayerNorm_1")(hidden),
+             "to_out": _DenseLeaves(self.dim, name="to_out")(hidden)}
+        for name in fused.PROJECTIONS:
+            p[name] = _DenseLeaves(
+                hidden, name=name, **(gate if name.endswith("gate") else {})
+            )(self.dim)
 
-        if self.mix == "outgoing":
-            out = jnp.einsum("bikd,bjkd->bijd", left, right)
-        else:
-            out = jnp.einsum("bkjd,bkid->bijd", left, right)
-
-        out = LayerNorm(dtype=self.dtype)(out)
-        out = out * out_gate
-        return dense(self.dim, "to_out")(out)
+        mesh = active_mesh()
+        if ((mesh is None or mesh.size == 1)
+                and (runtime.on_tpu()
+                     or fused_attention.pallas_attention_enabled())
+                and fused.admits(x.shape[1], hidden, x.shape[0],
+                                 jnp.dtype(self.dtype).itemsize)):
+            return fused.fused_triangle_multiply(
+                p, x, mask, residual, mix=self.mix, dtype=self.dtype,
+                interpret=not runtime.on_tpu())
+        out = fused.triangle_multiply_xla(p, x, mask, mix=self.mix,
+                                          dtype=self.dtype)
+        return out if residual is None else out + residual
 
 
 class OuterMean(nn.Module):

@@ -77,9 +77,11 @@ KERNELS = (
 _SUBTREE = KERNELS[0][0]
 _BY_COMPONENT = dict(KERNELS)
 
-# The name scope `ops.attention.fused_attention` puts around its Pallas call:
-# a custom call whose `op_name` has this component ran the fused kernel
-FUSED_SCOPE = "fused_attention"
+# The name scopes the fused kernels put around their Pallas calls
+# (`ops.attention.fused_attention_merged`, `ops.triangle_multiply.
+# fused_triangle_multiply`): a custom call whose `op_name` has one of these
+# components ran a fused kernel
+FUSED_SCOPES = ("fused_attention", "fused_triangle_multiply")
 
 
 def _parts(op_name: Optional[str]) -> list:
@@ -89,10 +91,11 @@ def _parts(op_name: Optional[str]) -> list:
 
 
 def is_fused(opcode: str, op_name: Optional[str]) -> bool:
-    """Whether a device operation is the fused attention kernel itself (a
-    custom call under `FUSED_SCOPE`), not the XLA attention a differentiated
+    """Whether a device operation is a fused kernel itself (a custom call
+    under one of `FUSED_SCOPES`), not the XLA formulation a differentiated
     trace runs under the same scope."""
-    return opcode == "custom-call" and FUSED_SCOPE in _parts(op_name)
+    return opcode == "custom-call" and any(
+        scope in _parts(op_name) for scope in FUSED_SCOPES)
 
 
 # The component `jax.checkpoint` puts on what a backward pass makes again
@@ -295,7 +298,8 @@ def reduce(profile_data, op_names: Optional[Dict[str, str]] = None,
     - `kernels`: {kernel: {"seconds", "events", "fused_s", "remat_s"}} over
       `KERNEL_NAMES`, containers left out: the kernels' seconds sum to
       `busy_s`; `fused_s` is the part of `seconds` spent in the fused
-      attention kernel's custom calls (`is_fused`): the counter of a
+      kernels' custom calls (`is_fused`: the attention's, the triangle
+      multiply's): the counter of a
       mechanism that engages when the program is traced; `remat_s` the part
       spent making a forward pass again for the backward (`is_remat`): what
       the trunk's remat policy (`model/evoformer.py`) buys back with memory;

@@ -110,6 +110,100 @@ def test_fused_attention_compiles_for_v5e(n, batch, one_chip,
     _compiled_kernel_text(fwd, _merged_shapes(n, one_chip, batch, n))
 
 
+def _pair_sized_copies(text, elements, kernel=None):
+    """The `copy` instructions of an executable's text that move at least
+    `elements` elements (of `kernel`'s alone, by `obs.device`'s booking of
+    their `op_name`, where one is named)."""
+    import math
+    import re
+
+    from alphafold2_tpu.obs import device
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"copy\(", line)
+        if not m or not m.group(2):
+            continue
+        if math.prod(int(d) for d in m.group(2).split(",")) < elements:
+            continue
+        named = re.search(r'op_name="([^"]*)"', line)
+        if kernel is None or (named and device.kernel_of(
+                named.group(1)) == kernel):
+            found.append(m.group(1))
+    return found
+
+
+def _triangle_multiply_leaves(sds, dim, hidden):
+    """The shapes of `TriangleMultiplicativeModule`'s parameter leaves."""
+    from alphafold2_tpu.ops.triangle_multiply import PROJECTIONS
+    dense = lambda i, o: {"kernel": sds((i, o), jnp.float32),
+                          "bias": sds((o,), jnp.float32)}
+    norm = lambda w: {"LayerNorm_0": {"scale": sds((w,), jnp.float32),
+                                      "bias": sds((w,), jnp.float32)}}
+    return dict({name: dense(dim, hidden) for name in PROJECTIONS},
+                LayerNorm_0=norm(dim), LayerNorm_1=norm(hidden),
+                to_out=dense(hidden, dim))
+
+
+@pytest.mark.parametrize("n,batch", [(n, 1) for n in FUSED_LENGTHS]
+                         + [(n, 8) for n in FUSED_LENGTHS[:3]])
+def test_fused_triangle_multiply_compiles_for_v5e(n, batch, one_chip,
+                                                  no_persistent_cache):
+    """The fused update at the published widths (dim = hidden = 256, bf16,
+    a mask, the residual), both mixes in one program, at every bucket of the
+    three cells and the long-fold bucket: six Mosaic custom calls (three
+    stages a mix) and no pair-sized copy: nothing relays an operand between
+    the stages."""
+    from alphafold2_tpu.ops import triangle_multiply as tm
+    dim = hidden = 256
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    p = _triangle_multiply_leaves(sds, dim, hidden)
+
+    def both(p, x, mask):
+        for mix in ("outgoing", "ingoing"):
+            x = tm.fused_triangle_multiply(p, x, mask, x, mix=mix,
+                                           dtype=jnp.bfloat16)
+        return x
+
+    text = _compiled_kernel_text(both, (
+        p, sds((batch, n, n, dim), jnp.bfloat16),
+        sds((batch, n, n), jnp.bool_)))
+    calls = sum("tpu_custom_call" in line and " custom-call(" in line
+                for line in text.splitlines())
+    assert calls == 6, calls
+    assert not _pair_sized_copies(text, batch * n * n * hidden)
+
+
+def test_fused_triangle_multiply_gradient_is_xlas_for_v5e(
+        one_chip, no_persistent_cache):
+    """The differentiated path that was kept (PERF.md section 6, PR 36): a
+    trace under `jax.grad` runs `triangle_multiply_xla` forward and backward,
+    the program the training step had before the kernels: no Mosaic custom
+    call is left in it (the crop-256 step's `fused_s` reads 0 for
+    `triangle_multiply`), while the same function undifferentiated holds the
+    three."""
+    from alphafold2_tpu.ops import triangle_multiply as tm
+    n, dim = 256, 256
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    p = _triangle_multiply_leaves(sds, dim, dim)
+    shapes = (p, sds((1, n, n, dim), jnp.bfloat16),
+              sds((1, n, n), jnp.bool_))
+
+    def update(p, x, mask):
+        return tm.fused_triangle_multiply(p, x, mask, x, mix="ingoing",
+                                          dtype=jnp.bfloat16)
+
+    def loss(p, x, mask):
+        return jnp.sum(update(p, x, mask).astype(jnp.float32) ** 2)
+
+    count = lambda text: sum("tpu_custom_call" in line
+                             and " custom-call(" in line
+                             for line in text.splitlines())
+    assert count(jax.jit(update).lower(*shapes).compile().as_text()) == 3
+    assert count(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *shapes).compile().as_text()) == 0
+
+
 @pytest.mark.parametrize("n", LENGTHS)
 def test_block_sparse_attention_compiles_for_v5e(n, one_chip,
                                                  no_persistent_cache):
@@ -190,15 +284,22 @@ def test_scan_fold_compiles_for_v5e(rule, one_chip, no_persistent_cache,
     predicate saying TPU (`Attention.__call__`'s rule sees the CPU here, so
     the test steers it), both triangle attentions and the MSA row attention
     are Mosaic custom calls that `obs.device` books to their kernels, and
-    no tensor of the logits' shape is left in the program; with the
-    predicate as it is here the same detector finds the logits."""
+    no tensor of the logits' shape is left in the program; so are both
+    triangle multiplies (three stages each: `ops/triangle_multiply.py`),
+    booked to `triangle_multiply`, with no pair-sized copy left among that
+    kernel's instructions; with the predicate as it is here the same
+    detectors find the logits and the copies."""
     import re
 
     import chip_smoke
     from alphafold2_tpu import runtime
     from alphafold2_tpu.obs import device
+    from alphafold2_tpu.ops import triangle_multiply
 
     monkeypatch.setattr(runtime, "on_tpu", lambda: rule == "on_a_tpu")
+    # one 256 map is 32 MiB, what XLA keeps on the chip: the rule leaves it
+    # to XLA, and the test takes the size out of the rule
+    monkeypatch.setattr(triangle_multiply, "_MIN_PAIR_BYTES", 0)
     n, m = chip_smoke.BUCKET, chip_smoke.MSA_DEPTH
     model = Alphafold2(predict_coords=True, dtype=jnp.bfloat16,
                        **chip_smoke.FULL_MODEL)
@@ -229,10 +330,13 @@ def test_scan_fold_compiles_for_v5e(rule, one_chip, no_persistent_cache,
     calls = [op_name for line in text.splitlines()
              if "tpu_custom_call" in line
              for op_name in re.findall(r'op_name="([^"]*)"', line)]
+    pair = n * n * chip_smoke.FULL_MODEL["dim"]
+    copies = _pair_sized_copies(text, pair, "triangle_multiply")
     if rule == "off_the_chip":
-        assert logits and not calls
+        assert logits and copies and not calls
         return
     assert not logits, logits
+    assert not copies, copies
     booked = {}
     for op_name in calls:
         assert device.is_fused("custom-call", op_name), op_name
@@ -241,9 +345,14 @@ def test_scan_fold_compiles_for_v5e(rule, one_chip, no_persistent_cache,
     # the MSA column attention attends 5 alignment rows: under the rule's
     # lower bound, so it stays with XLA
     assert booked == {
+        "triangle_multiply_outgoing": {"triangle_multiply"},
+        "triangle_multiply_ingoing": {"triangle_multiply"},
         "triangle_attention_outgoing": {"triangle_attention"},
         "triangle_attention_ingoing": {"triangle_attention"},
         "row_attn": {"msa_row_attention"}}, booked
+    # three stages a mix, in each of the model's two (unrolled) blocks
+    assert sum("fused_triangle_multiply" in name for name in calls) \
+        == 6 * chip_smoke.FULL_MODEL["depth"]
 
 
 @pytest.mark.parametrize("sees", ("no_limit", "a_v5es_limit"))

@@ -193,6 +193,11 @@ FUSED = ("jit(fold)/Alphafold2/net/block/attn/triangle_attention_outgoing/"
          "attn/fused_attention/pallas_call")
 
 
+FUSED_MULTIPLY = ("jit(fold)/Alphafold2/net/block/attn/"
+                  "triangle_multiply_outgoing/fused_triangle_multiply/"
+                  "pallas_call")
+
+
 @pytest.mark.parametrize("opcode,op_name,fused", [
     ("custom-call", FUSED, True),
     # XLA joins merged instructions' names: the first is the instruction's
@@ -203,6 +208,13 @@ FUSED = ("jit(fold)/Alphafold2/net/block/attn/triangle_attention_outgoing/"
     ("custom-call", "jit(fold)/Alphafold2/net/block/attn/block_sparse/"
      "pallas_call", False),
     ("custom-call", None, False),
+    # the fused triangle multiply's three stages, and the XLA formulation a
+    # differentiated trace runs under the same scope for their backward
+    ("custom-call", FUSED_MULTIPLY, True),
+    ("custom-call", FUSED_MULTIPLY + ";jit(fold)/Alphafold2/net/reshape",
+     True),
+    ("fusion", FUSED_MULTIPLY.replace("pallas_call",
+                                      "left_proj/dot_general"), False),
 ])
 def test_is_fused(opcode, op_name, fused):
     assert device.is_fused(opcode, op_name) is fused
@@ -238,6 +250,39 @@ def test_reduce_books_the_fused_kernels_time_beside_its_kernels():
         == pytest.approx(kernels["msa_row_attention"]["seconds"])
     assert all(kernels[k]["fused_s"] == 0 for k in device.KERNEL_NAMES
                if k not in ("triangle_attention", "msa_row_attention"))
+
+
+def test_reduce_books_the_fused_triangle_multiply_to_its_kernel():
+    """`fused_s` counts two mechanisms: the triangle multiply's custom calls
+    (scope `fused_triangle_multiply` inside the module's own) are booked to
+    `triangle_multiply`, beside what XLA still runs there, and leave every
+    other kernel's counter alone."""
+    from types import SimpleNamespace as NS
+    xla = FUSED_MULTIPLY.replace("fused_triangle_multiply/pallas_call",
+                                 "to_out/dot_general")
+    table = {"fused_triangle_multiply.3": FUSED_MULTIPLY,
+             "fused_triangle_multiply.4": FUSED_MULTIPLY.replace(
+                 "outgoing", "ingoing"),
+             "fusion.9": xla, "fused_attention.3": FUSED}
+    opcode = lambda instr: "fusion" if instr.startswith("fusion") \
+        else "custom-call"
+    durations = {"fused_triangle_multiply.3": 2_000_000,
+                 "fused_triangle_multiply.4": 1_500_000,
+                 "fusion.9": 500_000, "fused_attention.3": 3_000_000}
+    start, events = 10_000, []
+    for instr, ns in durations.items():
+        events.append(NS(
+            name=f"%{instr} = bf16[64,256,64] {opcode(instr)}(bf16[8] %a)",
+            start_ns=start, duration_ns=ns, stats=()))
+        start += ns + 100
+    data = NS(planes=[NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=events)])])
+    kernels = device.reduce(data, table)["kernels"]
+    assert kernels["triangle_multiply"]["fused_s"] == pytest.approx(3.5e-3)
+    assert kernels["triangle_multiply"]["seconds"] == pytest.approx(4e-3)
+    assert kernels["triangle_attention"]["fused_s"] == pytest.approx(3e-3)
+    assert all(kernels[k]["fused_s"] == 0 for k in device.KERNEL_NAMES
+               if k not in ("triangle_multiply", "triangle_attention"))
 
 
 REMAT = ("jit(step)/transpose(jvp(Alphafold2))/net/while/body/closed_call/"

@@ -676,3 +676,254 @@ def test_backward_rule_follows_the_length():
     assert [n for n in (64, 128, 192, 256, 384, 512, 640, 1024)
             if admits(n, n)] == [64, 128, 192, 256, 384, 512, 640]
     assert not admits(256, 256, 128)
+
+
+# -- the fused triangle multiply (ops/triangle_multiply.py) -------------------
+
+from alphafold2_tpu.ops import triangle_multiply as ops_tm  # noqa: E402
+
+TM_DIM, TM_HIDDEN = 32, 128
+
+
+def triangle_multiply_params(key, dim=TM_DIM, hidden=TM_HIDDEN):
+    """The module's parameter leaves, every one drawn (the module's own
+    initialisers make the gates pass-through and hide their arithmetic)."""
+    keys = iter(jax.random.split(key, 32))
+    dense = lambda i, o: {
+        "kernel": jax.random.normal(next(keys), (i, o)) / np.sqrt(i),
+        "bias": 0.1 * jax.random.normal(next(keys), (o,))}
+    norm = lambda w: {"LayerNorm_0": {
+        "scale": 1 + 0.1 * jax.random.normal(next(keys), (w,)),
+        "bias": 0.1 * jax.random.normal(next(keys), (w,))}}
+    p = {name: dense(dim, hidden) for name in ops_tm.PROJECTIONS}
+    p.update(LayerNorm_0=norm(dim), LayerNorm_1=norm(hidden),
+             to_out=dense(hidden, dim))
+    return p
+
+
+def triangle_multiply_inputs(n, batch, dtype, masked):
+    x = jax.random.normal(jax.random.PRNGKey(n + batch),
+                          (batch, n, n, TM_DIM)).astype(dtype)
+    mask = None
+    if masked:   # ragged lengths, and one row of the map fully padded
+        lengths = jax.random.randint(jax.random.PRNGKey(3), (batch, 1),
+                                     n // 2, n + 1)
+        valid = jnp.arange(n)[None, :] < lengths
+        mask = (valid[:, :, None] & valid[:, None, :]).at[:, 0].set(False)
+    return x, mask
+
+
+# (n, batch, dtype, mix, masked): both mixes, with and without a mask, batch
+# 1 and 8, a length that is no power of two
+TM_CASES = {
+    "n64-f32-outgoing-mask": (64, 1, jnp.float32, "outgoing", True),
+    "n64-f32-ingoing-b8": (64, 8, jnp.float32, "ingoing", False),
+    "n128-f32-ingoing-mask": (128, 1, jnp.float32, "ingoing", True),
+    "n192-f32-outgoing": (192, 1, jnp.float32, "outgoing", False),
+    "n192-f32-ingoing-mask": (192, 1, jnp.float32, "ingoing", True),
+    "n64-bf16-outgoing-mask-b8": (64, 8, jnp.bfloat16, "outgoing", True),
+    "n128-bf16-ingoing": (128, 1, jnp.bfloat16, "ingoing", False),
+    "n192-bf16-outgoing-mask": (192, 1, jnp.bfloat16, "outgoing", True),
+}
+
+
+def _middle(t):
+    """(b, i, k, hidden) -> (b * i, hidden, k), the layout the stages hand
+    each other: a row of the map is a (hidden, k) tile."""
+    b, n, _, hidden = t.shape
+    return t.swapaxes(-1, -2).reshape(b * n, hidden, n)
+
+
+def _close(got, want, dtype, what):
+    """float32: 1e-5 of the tensor's scale; bf16: the attention kernel's
+    tolerance (the stages keep float32 where XLA rounds to bf16 between a
+    matmul and its gate)."""
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    assert got.shape == want.shape and np.isfinite(got).all(), what
+    tol = 1e-5 if dtype == jnp.float32 else 4e-2
+    assert np.abs(got - want).max() <= tol * max(1.0, np.abs(want).max()), \
+        (what, np.abs(got - want).max())
+
+
+@pytest.mark.parametrize("stage", ("project", "contract", "finish", "whole"))
+@pytest.mark.parametrize("case", sorted(TM_CASES))
+def test_fused_triangle_multiply_matches_xla(case, stage):
+    """Each fused stage, interpreted, against the XLA formulation of the same
+    stage on the same inputs (on the layout the stages hand each other), and
+    the whole update + its residual against `triangle_multiply_xla`."""
+    n, batch, dtype, mix, masked = TM_CASES[case]
+    p = triangle_multiply_params(jax.random.PRNGKey(1))
+    x, mask = triangle_multiply_inputs(n, batch, dtype, masked)
+    if stage == "whole":
+        got = jax.jit(lambda p, x, m: ops_tm.fused_triangle_multiply(
+            p, x, m, x, mix=mix, dtype=dtype, interpret=True))(p, x, mask)
+        want = ops_tm.triangle_multiply_xla(p, x, mask, mix=mix,
+                                            dtype=dtype) + x
+        assert got.dtype == dtype
+        return _close(got, want, dtype, case)
+    left, right, gate = ops_tm.project_xla(p, x, mask, dtype)
+    if stage == "project":
+        got = ops_tm._project_pallas(
+            p, x, None if mask is None else mask.astype(jnp.float32),
+            dtype=dtype, interpret=True)
+        want = (left, right, gate)
+    elif stage == "contract":
+        got = (ops_tm._contract_pallas(_middle(left), _middle(right), mix,
+                                       interpret=True),)
+        want = (ops_tm.contract_xla(left, right, mix),)
+    else:
+        out = ops_tm.contract_xla(left, right, mix)
+        got = ops_tm._finish_pallas(p, _middle(out), _middle(gate), x,
+                                    dtype=dtype, interpret=True)
+        return _close(got, ops_tm.finish_xla(p, out, gate, dtype) + x,
+                      dtype, case)
+    for g, w in zip(got, want):
+        assert g.dtype == dtype
+        _close(g, _middle(w), dtype, case)
+
+
+@pytest.mark.parametrize("mix", ("outgoing", "ingoing"))
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16))
+def test_contraction_in_blocks_matches_xla(mix, dtype, monkeypatch):
+    """A map longer than a block of the contraction (1,024 on the chip: two
+    blocks of 512 a side; here 256 in blocks of 128), batch 2: every block of
+    the result from its own blocks of the operands."""
+    monkeypatch.setattr(ops_tm, "_CONTRACT_BLOCK", 128)
+    keys = jax.random.split(jax.random.PRNGKey(4), 2)
+    left, right = (jax.random.normal(k, (2, 256, 256, 32)).astype(dtype)
+                   for k in keys)
+    got = ops_tm._contract_pallas(_middle(left), _middle(right), mix,
+                                  interpret=True)
+    want = _middle(ops_tm.contract_xla(left, right, mix))
+    assert got.dtype == dtype
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    # the sums of 256 products of unit normals: an ulp of bf16 at ~50
+    tol = 1e-4 if dtype == jnp.float32 else 0.5
+    assert np.abs(got - want).max() <= tol
+
+
+@pytest.mark.parametrize("mix", ("outgoing", "ingoing"))
+def test_fused_triangle_multiply_gradient_is_the_xla_formulations(mix):
+    """`jax.grad` through the update's `custom_vjp` against the gradient of
+    `triangle_multiply_xla`, with respect to every parameter leaf and the
+    input; and the value beside the gradient is the fused forward's."""
+    p = triangle_multiply_params(jax.random.PRNGKey(2))
+    x, mask = triangle_multiply_inputs(64, 2, jnp.float32, True)
+    weight = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+    fused = lambda p, x: ops_tm.fused_triangle_multiply(
+        p, x, mask, x, mix=mix, dtype=jnp.float32, interpret=True)
+    xla = lambda p, x: ops_tm.triangle_multiply_xla(
+        p, x, mask, mix=mix, dtype=jnp.float32) + x
+    grad = lambda fn: jax.jit(jax.grad(
+        lambda p, x: jnp.sum(fn(p, x) * weight), argnums=(0, 1)))(p, x)
+    got, want = grad(fused), grad(xla)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, a), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        a, w = np.asarray(a), np.asarray(w)
+        assert np.abs(w).max() > 0, path
+        assert np.abs(a - w).max() <= 1e-4 * max(1.0, np.abs(w).max()), path
+
+
+def test_triangle_multiply_admits():
+    # the three cells' buckets at their batch, bf16, hidden 256: a pair
+    # tensor of 32 MiB or less is XLA's (it keeps it on the chip)
+    for n, batch, fused in ((64, 8, False), (128, 8, True), (256, 8, True),
+                            (256, 1, False), (384, 1, True), (512, 1, True),
+                            (640, 1, True), (1024, 1, True)):
+        assert ops_tm.admits(n, 256, batch) is fused, (n, batch)
+    assert ops_tm.admits(256, 256, 1, itemsize=4)      # 64 MiB in float32
+    assert not ops_tm.admits(520, 256)     # no multiple of 64
+    assert not ops_tm.admits(512, 32, 64)  # a hidden width under a lane tile
+    assert not ops_tm.admits(512, 192, 8)
+    # rows of the map a step of the first and the last stage takes
+    assert [ops_tm._rows_a_step(n) for n in (64, 192, 256, 640, 1024)] \
+        == [32, 8, 8, 2, 2]
+
+
+# what `TriangleMultiplicativeModule.__call__` decides, by what the trace can
+# see: (on a TPU, the flag, length, hidden width, mesh size) -> fused
+TM_RULE_CASES = {
+    "tpu-small-pair-tensor": (True, False, 64, 128, 0, False),
+    "cpu-flag-off": (False, False, 64, 128, 0, False),
+    "cpu-flag-on": (False, True, 64, 128, 0, True),
+    "tpu": (True, False, 64, 128, 0, True),
+    "tpu-short-map": (True, False, 32, 128, 0, False),
+    "tpu-length-no-multiple-of-64": (True, False, 72, 128, 0, False),
+    "tpu-narrow-hidden": (True, False, 64, 32, 0, False),
+    "tpu-under-a-mesh": (True, False, 64, 128, 2, False),
+    "tpu-under-a-mesh-of-one": (True, False, 64, 128, 1, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TM_RULE_CASES))
+def test_triangle_multiply_takes_the_kernels_by_what_it_can_see(
+        case, monkeypatch):
+    """The attention's rule: a TPU (or the CPU tests' door), one device, a
+    shape `admits` accepts (a side Mosaic tiles, whole lane tiles of hidden
+    channels, a pair tensor larger than XLA keeps on the chip); everything
+    else falls back to the XLA formulation, and both give the same update +
+    residual."""
+    from alphafold2_tpu import runtime
+    from alphafold2_tpu.model.primitives import TriangleMultiplicativeModule
+    from alphafold2_tpu.parallel.sharding import use_mesh
+    on_tpu, flag, n, hidden, mesh_size, expect = TM_RULE_CASES[case]
+    if case != "tpu-small-pair-tensor":
+        # the test's maps are far under the size XLA keeps on the chip
+        monkeypatch.setattr(ops_tm, "_MIN_PAIR_BYTES", 0)
+    mod = TriangleMultiplicativeModule(dim=TM_DIM, hidden_dim=hidden,
+                                       mix="outgoing")
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, n, n, TM_DIM))
+    mask = jnp.ones((1, n, n), bool).at[:, :, n - 5:].set(False)
+    params = {"params": triangle_multiply_params(jax.random.PRNGKey(1),
+                                                 hidden=hidden)}
+    want = mod.apply(params, x, mask=mask) + x      # the suite's XLA path
+    calls, fused = [], ops_tm.fused_triangle_multiply
+
+    def spy(*args, **kwargs):
+        calls.append(kwargs)
+        return fused(*args, **dict(kwargs, interpret=True))
+
+    monkeypatch.setattr(ops_tm, "fused_triangle_multiply", spy)
+    monkeypatch.setattr(runtime, "on_tpu", lambda: on_tpu)
+    devices = np.array(jax.devices()[:mesh_size])
+    mesh = jax.sharding.Mesh(devices, ("data",)) if devices.size else None
+    with ops_attn.pallas_attention(flag), use_mesh(mesh):
+        out = mod.apply(params, x, mask=mask, residual=x)
+    assert bool(calls) == expect
+    _close(out, want, jnp.float32, case)
+
+
+def test_triangle_multiply_parameter_tree_is_the_dense_layers():
+    """The module holds its leaves itself; the tree is the one the five
+    Dense layers, `to_out` and the two layer norms gave it (the benchmark
+    draws weights by these names), and so are the initial values: gates that
+    pass through, unit layer norms."""
+    from alphafold2_tpu.model.primitives import TriangleMultiplicativeModule
+    mod = TriangleMultiplicativeModule(dim=TM_DIM, hidden_dim=TM_HIDDEN)
+    params = mod.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, TM_DIM)))
+    shapes = jax.tree.map(lambda a: (a.shape, a.dtype.name), params)
+    dense = lambda i, o: {"kernel": ((i, o), "float32"),
+                          "bias": ((o,), "float32")}
+    norm = lambda w: {"LayerNorm_0": {"scale": ((w,), "float32"),
+                                      "bias": ((w,), "float32")}}
+    assert shapes == {"params": {
+        "LayerNorm_0": norm(TM_DIM), "LayerNorm_1": norm(TM_HIDDEN),
+        "left_proj": dense(TM_DIM, TM_HIDDEN),
+        "right_proj": dense(TM_DIM, TM_HIDDEN),
+        "left_gate": dense(TM_DIM, TM_HIDDEN),
+        "right_gate": dense(TM_DIM, TM_HIDDEN),
+        "out_gate": dense(TM_DIM, TM_HIDDEN),
+        "to_out": dense(TM_HIDDEN, TM_DIM)}}
+    p = params["params"]
+    for name in ("left_gate", "right_gate", "out_gate"):
+        assert not np.asarray(p[name]["kernel"]).any()
+        assert (np.asarray(p[name]["bias"]) == 1).all()
+    for name in ("left_proj", "right_proj", "to_out"):
+        kernel = np.asarray(p[name]["kernel"])
+        assert not np.asarray(p[name]["bias"]).any()
+        # LeCun normal: variance 1 / fan-in
+        assert 0.5 < kernel.std() * np.sqrt(kernel.shape[0]) < 1.5
+    for name in ("LayerNorm_0", "LayerNorm_1"):
+        assert (np.asarray(p[name]["LayerNorm_0"]["scale"]) == 1).all()
+        assert not np.asarray(p[name]["LayerNorm_0"]["bias"]).any()

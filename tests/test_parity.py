@@ -7,7 +7,9 @@ The reference is float32 `jax.numpy` with no padding and no masks (masks are
 length, the smallest the fused kernel admits (`MIN_FUSED_LENGTH` positions,
 heads of 8), so that the same inputs go through both doors of
 `Attention.__call__`: XLA's einsum + softmax + einsum (what the CPU suite
-takes) and the fused kernel, interpreted (what a TPU takes). Through the
+takes) and the fused kernel, interpreted (what a TPU takes); the triangle
+multiply on its own goes through `ops/triangle_multiply.py`'s fused stages the
+same way. Through the
 kernel the tolerances are the ones `test_ops.py` holds its float32 cases
 to; everywhere else 1e-5 of the tensor's scale.
 
@@ -25,11 +27,13 @@ import pytest
 from alphafold2_tpu.core.rigid import Rigid
 from alphafold2_tpu.model import evoformer, primitives, structure
 from alphafold2_tpu.ops import attention as ops_attn
+from alphafold2_tpu.ops import triangle_multiply as ops_tm
 from benchmark import reference
 
 N = ops_attn.MIN_FUSED_LENGTH       # residues: the attended axis
 ROWS = 4                            # alignment rows, or rows of a folded axis
 DIM, HEADS, DIM_HEAD = 32, 2, 8
+TRIANGLE_HIDDEN = 128               # of the triangle multiply on its own
 DEPTH = 2                           # of the trunk and the structure module
 NX = reference.Numerics("f32")
 
@@ -58,8 +62,11 @@ def _axial(column: bool, edges: bool):
 
 
 def _triangle(outgoing: bool):
+    # a hidden width the fused stages admit (a lane tile), so that the same
+    # module goes through both doors
     module = primitives.TriangleMultiplicativeModule(
-        dim=DIM, mix="outgoing" if outgoing else "ingoing")
+        dim=DIM, hidden_dim=TRIANGLE_HIDDEN,
+        mix="outgoing" if outgoing else "ingoing")
     return (module, [(N, N, DIM)],
             lambda params, x: module.apply(params, x[None])[0],
             lambda p, x: reference._triangle_multiply(NX, p, x, outgoing))
@@ -143,6 +150,11 @@ BLOCKS = dict(TRUNK_BLOCKS, trunk=_trunk, ipa=_ipa,
               structure_module=_structure_module)
 HOLD_AN_ATTENTION = [name for name in TRUNK_BLOCKS
                      if name.startswith(("axial", "evoformer"))]
+# the blocks whose triangle multiply the fused stages admit (inside the
+# evoformer block it is `DIM` wide, under a lane tile: XLA's)
+HOLD_A_FUSED_MULTIPLY = [name for name in TRUNK_BLOCKS
+                         if name.startswith("triangle_multiply")]
+THROUGH_KERNELS = HOLD_AN_ATTENTION + HOLD_A_FUSED_MULTIPLY
 
 
 def _draw(tree, key):
@@ -193,29 +205,39 @@ def _assert_close(got, want, tol, what):
 
 @pytest.fixture
 def door(request, monkeypatch):
-    """Which attention `Attention.__call__` takes: "xla", as on the CPU, or
-    "kernel", the fused kernel (interpreted), as on a TPU; and afterwards,
-    that it was the one taken."""
-    calls = []
-    interpreted = functools.partial(ops_attn.fused_attention_merged,
-                                    interpret=True)
+    """Which path the blocks take: "xla", as on the CPU, or "kernel", the
+    fused attention and the fused triangle multiply (interpreted), as on a
+    TPU; and afterwards, that each block took the one it holds."""
+    calls = {"attention": [], "triangle_multiply": []}
 
-    def spy(*args, **kwargs):
-        calls.append(args[0].shape)
-        return interpreted(*args, **kwargs)
+    def spy(kind, fn):
+        def spied(*args, **kwargs):
+            calls[kind].append(kwargs)
+            return fn(*args, **dict(kwargs, interpret=True))
+        return spied
 
-    monkeypatch.setattr(ops_attn, "fused_attention_merged", spy)
+    monkeypatch.setattr(ops_attn, "fused_attention_merged",
+                        spy("attention", ops_attn.fused_attention_merged))
+    monkeypatch.setattr(ops_tm, "fused_triangle_multiply",
+                        spy("triangle_multiply",
+                            ops_tm.fused_triangle_multiply))
+    # a test's map is far under the size XLA keeps on the chip
+    monkeypatch.setattr(ops_tm, "_MIN_PAIR_BYTES", 0)
     with ops_attn.pallas_attention(request.param == "kernel"):
         yield request.param
-    assert bool(calls) == (request.param == "kernel"), calls
+    name = request.node.callspec.params["name"]
+    kernel = request.param == "kernel"
+    assert {kind: bool(made) for kind, made in calls.items()} == {
+        "attention": kernel and name in HOLD_AN_ATTENTION,
+        "triangle_multiply": kernel and name in HOLD_A_FUSED_MULTIPLY}, calls
 
 
 def _doors(names):
-    """Every block through XLA's attention, and those that hold an attention
-    through the kernel as well."""
+    """Every block through XLA, and those that hold an attention or a
+    triangle multiply of a fused width through the kernels as well."""
     return [pytest.param(name, door, id=f"{name}-{door}")
             for door in ("xla", "kernel") for name in names
-            if door == "xla" or name in HOLD_AN_ATTENTION]
+            if door == "xla" or name in THROUGH_KERNELS]
 
 
 @pytest.mark.parametrize("name,door", _doors(BLOCKS), indirect=["door"])
