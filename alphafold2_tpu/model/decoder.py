@@ -1,7 +1,10 @@
-"""A causal token decoder with latent attention and routed experts
-(the `deepseek_v3` layer: kanana-2, DeepSeek-V3), for the trainer.
+"""A causal token decoder with routed experts, for the trainer; its
+attention latent in every layer (the `deepseek_v3` layer: kanana-2,
+DeepSeek-V3), or grouped-query with a gate a head, full and windowed layers
+mixed (the `laguna` layer: Laguna-S-2.1), layer by layer as the model's
+`layer_attention` says.
 
-    layer:  h = x + MLA(RMS(x));  y = h + F(RMS(h))
+    layer:  h = x + A(RMS(x));  y = h + F(RMS(h))
     F:      SwiGLU at the dense width in the first `first_dense` layers, the
             expert layer after them
     model:  embedding -> layers -> RMS -> untied head over the vocabulary held
@@ -19,13 +22,22 @@ interleaved one differs by a fixed permutation of W_q's and W_kva's columns).
 On a TPU the attention is `ops.attention.causal_attention` (blocked, logits
 in VMEM only); elsewhere masked dense attention.
 
+Grouped-query attention (`GroupedAttention`, named `full_attention` or
+`window_attention` by its layer's type): H query heads over G key and value
+heads, query head h reading key head h // (H / G), a sigmoid gate a query
+head on the output before W_o; RoPE by the layer type's `rope_parameters`
+(YaRN on part of each head, or plain); the window a causal band of keys. The
+same kernel, told the band, visits only the blocks it touches. The layer
+holds a share of the heads: one chip's of a tensor-parallel attention.
+
 The expert layer (`ExpertLayer`) is TOLD which experts it holds
 (`expert_start`, `experts_held`): one chip's share of an expert-parallel
 layer. It routes over all `router_experts` (sigmoid scores in float32, the
-top `experts_per_token` of score + bias, weights score / sum x
-`routed_scale`), and adds the terms of its own experts only, plus the shared
-expert. What the absent experts would add is left out; nothing stands in for
-the other chips or their exchange.
+top `experts_per_token` of score + bias, or of the score alone where the
+router has no `correction_bias`, weights score / sum x `routed_scale`), and
+adds the terms of its own experts only, plus the shared expert. What the
+absent experts would add is left out; nothing stands in for the other chips
+or their exchange.
 
 Its device time is a function of shapes alone. The slots routed to held
 experts are laid, sorted by expert, into ONE buffer of STATIC rows, each
@@ -37,21 +49,25 @@ gather is a gather through the inverse map (`_gather_rows`), so no
 scatter-add runs. Routing is dropless: a slot beyond the buffer is COUNTED
 (`expert_overflow`) and the benchmark's step turns any into a NaN loss; none
 is dropped silently. A buffer of `capacity_factor` = router_experts /
-experts_held takes every slot a step has, whatever the routing.
+experts_held takes every slot a step has, whatever the routing (router_experts
+x min(experts_per_token, experts_held) / (experts_per_token x experts_held)
+is the least that does).
 
 Every module's name is a kernel of `obs/device.py`'s table: `mla_attention`,
-`expert_router` (scores, top-k, the rows' indices, both gathers),
-`expert_mlp`, `shared_expert`, `dense_mlp`, `lm_head` (embedding, head; the
-loss takes the same scope in the trainer).
+`full_attention`, `window_attention`, `expert_router` (scores, top-k, the
+rows' indices, both gathers), `expert_mlp`, `shared_expert`, `dense_mlp`,
+`lm_head` (embedding, head; the loss takes the same scope in the trainer).
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
@@ -62,6 +78,8 @@ from alphafold2_tpu.ops.grouped_matmul import (grouped_matmul,
                                                grouped_matmul_reference)
 
 ATTENTION_SCOPE = "mla_attention"
+FULL_SCOPE = "full_attention"
+WINDOW_SCOPE = "window_attention"
 ROUTER_SCOPE = "expert_router"
 DENSE_SCOPE = "dense_mlp"
 HEAD_SCOPE = "lm_head"
@@ -105,13 +123,66 @@ class SwiGLU(nn.Module):
 def rope(x, theta: float):
     """Rotary embedding along axis -2 (positions 0..n-1) of (..., n, d),
     dimension i paired with i + d / 2; float32 inside."""
-    n, d = x.shape[-2], x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    d = x.shape[-1]
+    return rotary(x, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
+def rotary(x, freq, scale=None):
+    """x (..., n, d) turned at positions 0..n-1 by the angles position x
+    `freq` (d / 2 of them), dimension i paired with i + d / 2; cos and sin
+    times `scale` where one is given (YaRN's attention factor)."""
+    n = x.shape[-2]
     angle = jnp.arange(n, dtype=jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max_position_embeddings: int,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+    """The dim / 2 angular frequencies of YaRN (`rope_type` "yarn", as
+    transformers' `_compute_yarn_parameters` makes them, `truncate` on):
+    each the extrapolated theta^(-2i/dim) or that over `factor`, blended by
+    a ramp between the dimensions that turn `beta_fast` and `beta_slow` times
+    over the original context."""
+    def dim_of(turns):
+        return dim * math.log(original_max_position_embeddings
+                              / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), dim - 1)
+    high = high + 0.001 if low == high else high
+    extrapolated = theta ** (-np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return (extrapolated / factor * ramp
+            + extrapolated * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_by_type(x, params: dict):
+    """A layer type's `rope_parameters` entry on (..., n, head_dim): the
+    first `partial_rotary_factor` of each head's dimensions turned ("default"
+    at `rope_theta`, or "yarn" with its cos and sin times
+    `attention_factor`), the rest passed through."""
+    d = x.shape[-1]
+    dim = int(d * params.get("partial_rotary_factor", 1))
+    theta = float(params["rope_theta"])
+    if params["rope_type"] == "yarn":
+        freq = yarn_frequencies(
+            dim, theta, params["factor"],
+            params["original_max_position_embeddings"], params["beta_fast"],
+            params["beta_slow"])
+        turned = rotary(x[..., :dim], jnp.asarray(freq),
+                        params["attention_factor"])
+    elif params["rope_type"] == "default":
+        turned = rope(x[..., :dim], theta)
+    else:
+        raise ValueError(f"rope_type {params['rope_type']!r}")
+    return turned if dim == d else jnp.concatenate(
+        [turned, x[..., dim:]], axis=-1)
 
 
 class MLAttention(nn.Module):
@@ -162,6 +233,63 @@ class MLAttention(nn.Module):
         return _dense(dim, self.dtype, "o_proj")(out)
 
 
+class GroupedAttention(nn.Module):
+    """Grouped-query attention with a sigmoid gate a head (the `laguna`
+    layer): `heads` query heads over `kv_heads` key and value heads, query
+    head h reading key head h // (heads / kv_heads); `window`: each query
+    sees the `window` latest keys, itself included (None: all before it).
+    The heads HELD: one chip's share of a tensor-parallel layer, whole
+    groups of query heads with their key head. Which heads they are does not
+    enter the arithmetic, only how many; what the absent heads would add
+    through `o_proj` is left out, as the absent experts' part is.
+
+        u = RMS(x); q = W_q u, k = W_k u, v = W_v u (heads of `head_dim`);
+        q, k = RoPE by the layer type's `rope` entry;
+        a_h = softmax(q_h k_g(h)^T / sqrt(head_dim) + mask) v_g(h);
+        out = W_o [a_h sigmoid(W_g u)_h]_h
+    """
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope: dict
+    window: Optional[int] = None
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, n, dim = x.shape
+        h, g, d = self.heads, self.kv_heads, self.head_dim
+        u = RMSNorm(self.eps, self.dtype, name="norm")(x)
+        by_head = lambda t, count: t.reshape(b, n, count, d).transpose(
+            0, 2, 1, 3)
+        q = by_head(_dense(h * d, self.dtype, "q_proj")(u), h)
+        k = by_head(_dense(g * d, self.dtype, "k_proj")(u), g)
+        v = by_head(_dense(g * d, self.dtype, "v_proj")(u), g)
+        q = rope_by_type(q, self.rope) * d ** -0.5
+        k = rope_by_type(k, self.rope)
+
+        # the door as `MLAttention`'s
+        kernel = runtime.on_tpu() or attention_ops.pallas_attention_enabled()
+        if kernel and attention_ops.causal_admits(n):
+            out = attention_ops.causal_attention(
+                q, k, v, window=self.window, interpret=not runtime.on_tpu())
+        else:
+            out = checkpoint_name(
+                attention_ops.causal_attention_reference(q, k, v,
+                                                         self.window),
+                attention_ops.KEPT_CAUSAL)
+        gate = jax.nn.sigmoid(_dense(h, self.dtype, "head_gate")(u))
+        out = out.transpose(0, 2, 1, 3) * gate[..., None]
+        return _dense(dim, self.dtype, "o_proj")(out.reshape(b, n, h * d))
+
+
+# a layer's attention by its kind, which is also its module's name and its
+# kernel's in obs/device.py
+ATTENTIONS = {ATTENTION_SCOPE: MLAttention, FULL_SCOPE: GroupedAttention,
+              WINDOW_SCOPE: GroupedAttention}
+
+
 @jax.custom_vjp
 def _gather_rows(table, index, inverse):
     """table[index] for a (rows + 1, d) table whose last row is zeros (the
@@ -204,10 +332,13 @@ def expert_buffer(tokens: int, experts_per_token: int, router_experts: int,
 
 class ExpertRouter(nn.Module):
     """RMS(h), and from it the scores, the choice and its weights, all
-    float32: (u in `dtype`, choice (t, k), weights (t, k))."""
+    float32: (u in `dtype`, choice (t, k), weights (t, k)).
+    `correction_bias`: a bias that steers the choice (`deepseek_v3`'s
+    `e_score_correction_bias`); without it the choice is the top k scores."""
     router_experts: int
     experts_per_token: int
     routed_scale: float
+    correction_bias: bool = True
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
 
@@ -217,13 +348,15 @@ class ExpertRouter(nn.Module):
             h, keep_float32=True)
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (h.shape[-1], self.router_experts))
-        bias = self.param("bias", nn.initializers.zeros_init(),
-                          (self.router_experts,))
         scores = jax.nn.sigmoid(jnp.dot(
             u32, kernel, precision=jax.lax.Precision.HIGHEST))
-        # `e_score_correction_bias` steers the choice and takes no gradient
-        _, choice = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias), self.experts_per_token)
+        steered = scores
+        if self.correction_bias:
+            bias = self.param("bias", nn.initializers.zeros_init(),
+                              (self.router_experts,))
+            # the bias steers the choice and takes no gradient
+            steered = scores + jax.lax.stop_gradient(bias)
+        _, choice = jax.lax.top_k(steered, self.experts_per_token)
         # the chosen scores by a one-hot product: its backward is dense,
         # where `take_along_axis`'s is a scatter-add
         picked = jnp.einsum("te,tke->tk", scores, jax.nn.one_hot(
@@ -280,6 +413,7 @@ class ExpertLayer(nn.Module):
     shared_experts: int
     routed_scale: float
     capacity_factor: float
+    correction_bias: bool = True
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
 
@@ -292,8 +426,8 @@ class ExpertLayer(nn.Module):
         slots = tokens * k
 
         u, choice, weights = ExpertRouter(
-            self.router_experts, k, self.routed_scale, self.eps, self.dtype,
-            name=ROUTER_SCOPE)(x.reshape(tokens, dim))
+            self.router_experts, k, self.routed_scale, self.correction_bias,
+            self.eps, self.dtype, name=ROUTER_SCOPE)(x.reshape(tokens, dim))
 
         with jax.named_scope(ROUTER_SCOPE):
             local = (choice - self.expert_start).reshape(slots)
@@ -358,8 +492,11 @@ class ExpertLayer(nn.Module):
 
 
 class DecoderLayer(nn.Module):
-    """One layer; `expert` chooses F. Returns (y, the expert layer's
-    counters; None for a dense layer)."""
+    """One layer; `expert` chooses F, and the `kind` of `attention` (an entry
+    of `ATTENTIONS`; latent attention where it names none) the attention,
+    which takes the rest of `attention` as its fields and the kind as its
+    name. Returns (y, the expert layer's counters; None for a dense
+    layer)."""
     expert: bool
     attention: dict
     dense_width: int
@@ -369,11 +506,13 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        fields = dict(self.attention)
+        kind = fields.pop("kind", ATTENTION_SCOPE)
+        attended = ATTENTIONS[kind](**fields, eps=self.eps, dtype=self.dtype,
+                                    name=kind)(x)
         # the residual sums take their branch's name too: the profile's
         # reader goes by names (obs/device.py)
-        attended = MLAttention(**self.attention, eps=self.eps,
-                               dtype=self.dtype, name=ATTENTION_SCOPE)(x)
-        with jax.named_scope(ATTENTION_SCOPE):
+        with jax.named_scope(kind):
             h = x + attended
         if self.expert:
             f, counters = ExpertLayer(**self.moe, eps=self.eps,
@@ -446,11 +585,6 @@ class CausalDecoder(nn.Module):
     hidden_size: int
     num_layers: int
     first_dense: int
-    heads: int
-    qk_nope_dim: int
-    qk_rope_dim: int
-    v_head_dim: int
-    kv_lora_rank: int
     dense_width: int
     expert_width: int
     router_experts: int
@@ -458,9 +592,19 @@ class CausalDecoder(nn.Module):
     experts_per_token: int
     shared_experts: int
     routed_scale: float
+    # latent attention in every layer, of these sizes...
+    heads: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    kv_lora_rank: int = 0
+    rope_theta: float = 1e6
+    # ...or one `DecoderLayer.attention` a layer, its kind and its fields
+    # (`FrozenDict`s: a module's fields are hashed)
+    layer_attention: tuple = ()
     expert_start: int = 0
     capacity_factor: float = 2.0
-    rope_theta: float = 1e6
+    correction_bias: bool = True
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
 
@@ -475,10 +619,11 @@ class CausalDecoder(nn.Module):
         head = LMHead(self.vocab_size, self.hidden_size, self.eps, self.dtype,
                       name=HEAD_SCOPE)
         x = head.embed(tokens)
-        attention = dict(
+        latent = dict(
             heads=self.heads, qk_nope_dim=self.qk_nope_dim,
             qk_rope_dim=self.qk_rope_dim, v_head_dim=self.v_head_dim,
             kv_lora_rank=self.kv_lora_rank, rope_theta=self.rope_theta)
+        attention = self.layer_attention or (latent,) * self.num_layers
         moe = dict(
             router_experts=self.router_experts,
             experts_held=self.experts_held, expert_start=self.expert_start,
@@ -486,7 +631,8 @@ class CausalDecoder(nn.Module):
             expert_width=self.expert_width,
             shared_experts=self.shared_experts,
             routed_scale=self.routed_scale,
-            capacity_factor=self.capacity_factor)
+            capacity_factor=self.capacity_factor,
+            correction_bias=self.correction_bias)
         # each layer made again for its backward, but for the attention
         # kernel's output and log-sum-exp (1/15 of a layer's activations)
         layer_cls = remat_block((attention_ops.KEPT_CAUSAL,),
@@ -495,7 +641,7 @@ class CausalDecoder(nn.Module):
         counted = []
         for i in range(self.num_layers):
             x, counters = layer_cls(
-                expert=i >= self.first_dense, attention=attention,
+                expert=i >= self.first_dense, attention=attention[i],
                 dense_width=self.dense_width, moe=moe, eps=self.eps,
                 dtype=self.dtype, name=f"layers_{i}")(x)
             if counters is not None:

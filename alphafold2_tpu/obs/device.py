@@ -14,7 +14,7 @@ program can join them. Three parts, one clock (the profiler's):
 
 - `KERNELS` / `kernel_of`: the vocabulary, from path components of an
   `op_name` to the kernels' names (the folder's seven, the token decoder's
-  six, `other`);
+  eight, `other`);
 - `profile(executable, call)`: run one compiled program under the profiler
   and return its device seconds per execution by kernel;
 - `reduce(profile_data, op_names)`: the reduction itself, also of a capture
@@ -45,9 +45,12 @@ FOLD_KERNEL_NAMES = ("triangle_multiply", "triangle_attention",
                      "msa_row_attention", "msa_col_attention",
                      "outer_product_mean", "transition", "structure")
 # the causal token decoder's (`model/decoder.py`): its modules' own names.
-# `expert_router` holds the scores, the choice, the rows' indices and both
-# gathers; `lm_head` the embedding, the head and the token loss
-DECODER_KERNEL_NAMES = ("mla_attention", "expert_router", "expert_mlp",
+# A layer's attention takes its kind's (latent; grouped-query under the
+# causal mask or a band of keys); `expert_router` holds the scores, the
+# choice, the rows' indices and both gathers; `lm_head` the embedding, the
+# head and the token loss
+DECODER_KERNEL_NAMES = ("mla_attention", "full_attention",
+                        "window_attention", "expert_router", "expert_mlp",
                         "shared_expert", "dense_mlp", "lm_head")
 KERNEL_NAMES = FOLD_KERNEL_NAMES + DECODER_KERNEL_NAMES + ("other",)
 

@@ -715,15 +715,21 @@ def attention_reference(q, k, v, bias=None, q_mask=None, k_mask=None,
 # -- causal attention for a token decoder -----------------------------------
 #
 # A decoder's self-attention over thousands of keys, with keys wider than
-# values (latent attention: 192-wide q and k, 128-wide v). Nothing above is
-# touched by it: the axial kernel is non-causal, takes one head width and a
-# whole row of keys. Here the blocked flash kernel that ships with JAX
+# values (latent attention: 192-wide q and k, 128-wide v), or with fewer key
+# and value heads than query heads (grouped queries: query head h reads key
+# head h // (heads / key heads)), under the causal mask or a causal band of
+# `window` keys. Nothing above is touched by it: the axial kernel is
+# non-causal, takes one head width and a whole row of keys. Here the blocked
+# flash kernel that ships with JAX
 # (`jax.experimental.pallas.ops.tpu.splash_attention`) does the work: it takes
-# a key width and a value width of its own without padding v, visits only the
-# blocks on and under the diagonal (the block mask is static: the grid does
-# not follow the data), and differentiates through a `custom_vjp` of two more
-# Pallas kernels (dq; dk and dv) that make the logits again block by block.
-# Logits never reach HBM, forward or backward.
+# a key width and a value width of its own without padding v, a key head for
+# each group of query heads without repeating it (its index maps read head
+# h // group; the dk/dv kernel sums a group's query heads in VMEM), visits
+# only the blocks the mask touches (the block mask is static and the grid
+# shrinks to the band: it does not follow the data), and differentiates
+# through a `custom_vjp` of two more Pallas kernels (dq; dk and dv) that make
+# the logits again block by block. Logits never reach HBM, forward or
+# backward.
 
 CAUSAL_SCOPE = "causal_attention"
 # the mark on the kernel's output and its log-sum-exp: what a rematerialised
@@ -731,6 +737,12 @@ CAUSAL_SCOPE = "causal_attention"
 KEPT_CAUSAL = "causal_attention_out"
 # query and key rows a grid step takes: the largest of these that divides n
 _CAUSAL_BLOCKS = (1024, 512, 256, 128)
+# the same under a band of keys: at a band of 512, 18 query heads over 2 key
+# heads x 8,192 on the v5e, 512 rows took 0.76 ms forward and 2.69 with the
+# backward, against 1.24 / 4.01 at 256, 3.05 / 8.64 at 128, 0.99 / 3.53 at
+# 1,024 queries and 512 keys (PERF.md, PR 37); the whole triangle of the same
+# heads 2.50 / 9.50
+_WINDOW_BLOCKS = (512, 256, 128)
 
 
 def causal_admits(n: int) -> bool:
@@ -739,39 +751,53 @@ def causal_admits(n: int) -> bool:
     return n % _CAUSAL_BLOCKS[-1] == 0
 
 
-def causal_attention_reference(q, k, v):
+def causal_attention_reference(q, k, v, window=None):
     """Masked dense causal attention, float32 logits: the kernel's contract
-    (tests), and the path off the chip. q, k: (b, h, n, dk), q pre-scaled;
-    v: (b, h, n, dv)."""
-    n = q.shape[2]
-    logits = jnp.einsum("bhid,bhjd->bhij", q, k,
+    (tests), and the path off the chip. q: (b, h, n, dk), q pre-scaled;
+    k: (b, g, n, dk), v: (b, g, n, dv), g dividing h (query head i reads key
+    head i // (h / g)); `window`: a query at i sees the keys j with
+    i - window < j <= i."""
+    b, h, n, _ = q.shape
+    group = h // k.shape[1]
+    q = q.reshape(b, h // group, group, n, q.shape[-1])
+    logits = jnp.einsum("bgqid,bgjd->bgqij", q, k,
                         preferred_element_type=jnp.float32)
-    visible = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+    offset = jnp.arange(n)[:, None] - jnp.arange(n)[None, :]
+    visible = offset >= 0 if window is None else (offset >= 0) & (
+        offset < window)
     attn = jax.nn.softmax(jnp.where(visible, logits, MASK_VALUE), axis=-1)
-    return jnp.einsum("bhij,bhjd->bhid", attn.astype(v.dtype), v)
+    out = jnp.einsum("bgqij,bgjd->bgqid", attn.astype(v.dtype), v)
+    return out.reshape(b, h, n, v.shape[-1])
 
 
 @functools.lru_cache(maxsize=None)
-def _causal_kernel(heads: int, n: int, interpret: bool):
-    """The splash kernel for `heads` heads of n positions under one causal
-    mask, built once a shape (its block tables are numpy, made on the host)."""
+def _causal_kernel(heads: int, n: int, interpret: bool, window=None):
+    """The splash kernel for `heads` query heads of n positions under one
+    causal mask, or one causal band of `window` keys, built once a shape (its
+    block tables are numpy, made on the host)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
         splash_attention_mask as masks,
     )
-    # 1,024 rows of queries and of keys a grid step, the keys computed 512 at
-    # a time: the fastest of nine sets at 2 x 32 heads x 8,192 on the v5e that
-    # keep dq a kernel of its own, 14.5 ms forward, 56.7 with the backward
-    # (PERF.md, PR 35). The fused dq/dk/dv kernel took 49.3, but holds a
-    # float32 dq for every block of keys (3.2 GB there); 2,048 rows do not
-    # fit VMEM
-    block = next(b for b in _CAUSAL_BLOCKS if n % b == 0)
+    if window is None:
+        # 1,024 rows of queries and of keys a grid step, the keys computed
+        # 512 at a time: the fastest of nine sets at 2 x 32 heads x 8,192 on
+        # the v5e that keep dq a kernel of its own, 14.5 ms forward, 56.7
+        # with the backward (PERF.md, PR 35). The fused dq/dk/dv kernel took
+        # 49.3, but holds a float32 dq for every block of keys (3.2 GB
+        # there); 2,048 rows do not fit VMEM
+        blocks, mask = _CAUSAL_BLOCKS, masks.CausalMask((n, n))
+    else:
+        # the band's right side at 0 is the causal bound
+        blocks = _WINDOW_BLOCKS
+        mask = masks.LocalMask((n, n), (window - 1, 0), 0)
+    block = next(b for b in blocks if n % b == 0)
     sizes = splash.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=min(block, 512),
         block_q_dkv=block, block_kv_dkv=block,
         block_kv_dkv_compute=min(block, 512),
         block_q_dq=block, block_kv_dq=block)
-    mask = masks.MultiHeadMask([masks.CausalMask((n, n))] * heads)
+    mask = masks.MultiHeadMask([mask] * heads)
     # built under `ensure_compile_time_eval`: the tables are constants of
     # whichever trace asks first, not tracers of it
     with jax.ensure_compile_time_eval():
@@ -781,19 +807,22 @@ def _causal_kernel(heads: int, n: int, interpret: bool):
 
 
 @jax.named_scope(CAUSAL_SCOPE)
-def causal_attention(q, k, v, *, interpret: bool = False):
+def causal_attention(q, k, v, *, interpret: bool = False, window=None):
     """softmax(q k^T + causal mask) v, blocked, forward and backward, logits
-    in VMEM only. q, k: (b, h, n, dk), q pre-scaled; v: (b, h, n, dv); the
-    output has v's width: (b, h, n, dv). n has to be a multiple of 128
-    (`causal_admits`)."""
+    in VMEM only. q: (b, h, n, dk), q pre-scaled; k: (b, g, n, dk) and v:
+    (b, g, n, dv), g dividing h (grouped queries, as
+    `causal_attention_reference`); `window`: the causal band of that many
+    keys. The output has v's width: (b, h, n, dv). n has to be a multiple of
+    128 (`causal_admits`)."""
     batch, heads, n, _ = q.shape
     if not causal_admits(n):
         raise ValueError(f"causal_attention: {n} positions are no multiple "
                          f"of {_CAUSAL_BLOCKS[-1]}")
     # the batch folded into the heads (all under one mask), not `vmap`ped:
     # a batched Pallas call loses its `op_name`, which the profile's reader
-    # (obs/device.py) goes by
-    fold = lambda t: t.reshape(batch * heads, n, t.shape[-1])
-    out = _causal_kernel(batch * heads, n, interpret)(fold(q), fold(k),
-                                                      fold(v))
+    # (obs/device.py) goes by. Row b * g + i of k is query rows b * h + i *
+    # h / g onwards: the kernel's group map holds across the fold
+    fold = lambda t: t.reshape(batch * t.shape[1], n, t.shape[-1])
+    out = _causal_kernel(batch * heads, n, interpret, window)(
+        fold(q), fold(k), fold(v))
     return out.reshape(batch, heads, n, v.shape[-1])

@@ -488,6 +488,33 @@ def test_causal_attention_compiles_for_v5e(n, one_chip, no_persistent_cache):
     assert len(calls) == 3, len(calls)
 
 
+@pytest.mark.parametrize("heads,window", [(12, None), (18, 512)],
+                         ids=["full", "window"])
+def test_grouped_causal_attention_compiles_for_v5e(heads, window, one_chip,
+                                                   no_persistent_cache):
+    """The same kernel with grouped queries at the laguna cell's shapes (2
+    key heads held, groups of 6 under the causal mask and of 9 under a band
+    of 512 keys, 128 wide, 8,192 positions, bf16), forward and backward:
+    three Mosaic calls."""
+    from alphafold2_tpu.ops.attention import causal_attention
+
+    n, kv, width = 8192, 2, 128
+    shape = lambda h: jax.ShapeDtypeStruct((1, h, n, width), jnp.bfloat16,
+                                           sharding=one_chip)
+
+    def loss_gradients(q, k, v):
+        return jax.grad(lambda *a: causal_attention(
+            *a, window=window).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("default"):
+        text = _compiled_kernel_text(loss_gradients,
+                                     (shape(heads), shape(kv), shape(kv)))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 3, len(calls)
+
+
 def test_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache):
     """The expert layer's grouped matmuls at the benchmark's decoder's own
     sizes (a buffer of 6 x 16,384 slots and a tile for each of 16 held

@@ -67,7 +67,7 @@ def test_the_table_names_only_the_kernels():
     assert {kernel for _, kernel in device.KERNELS} == \
         set(device.KERNEL_NAMES) - {"other"}
     assert NAMED < set(device.KERNEL_NAMES)
-    assert len(device.KERNEL_NAMES) == 14
+    assert len(device.KERNEL_NAMES) == 16
     assert len(device.FOLD_KERNEL_NAMES) == 7
 
 
@@ -496,22 +496,31 @@ def test_the_fold_tables_verdicts_are_what_they_were():
     assert not set(FOLD_TABLE) & set(device.DECODER_KERNEL_NAMES)
 
 
-def test_every_decoder_instruction_lands_in_a_named_kernel():
+# each decoder family's kernels: its attention's kind, then what both have
+DECODER_CELLS = {
+    "kanana2_30b_a3b_ep8": ("kanana2", {"mla_attention"}),
+    "laguna_s21_ep32": ("laguna", {"full_attention", "window_attention"})}
+
+
+@pytest.mark.parametrize("config_name", sorted(DECODER_CELLS))
+def test_every_decoder_instruction_lands_in_a_named_kernel(config_name):
     """A tiny training step of the causal decoder, traced and compiled here:
     every instruction whose `op_name` passes through the model belongs to one
     of the decoder's kernels, forward, backward and made again, and every one
-    of the six has some."""
+    of the family's has some: its attention's and the six the layers share
+    (the laguna step has both attentions, and no latent one)."""
     import jax
-    from benchmark import weights
+    from benchmark import families
     from benchmark.drivers import train_steps
-    from benchmark.families import kanana2
+    name, attention = DECODER_CELLS[config_name]
+    family = families.load(name)
     with open(os.path.join(os.path.dirname(DATA), os.pardir, "benchmark",
-                           "configs", "kanana2_30b_a3b_ep8.json")) as f:
-        config = {**json.load(f), **kanana2.TINY}
+                           "configs", config_name + ".json")) as f:
+        config = {**json.load(f), **family.TINY}
     traffic = dict(batch=2, tokens=16, learning_rate=3e-4)
-    model = kanana2.build_model(config)
+    model = family.build_model(config)
     place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype)
-    shapes = kanana2.param_shapes(model)
+    shapes = family.param_shapes(model)
     _, step, args = train_steps.largest_program(model, shapes, config,
                                                 traffic, place)
     # an executable from the persistent cache keeps the names of the trace
@@ -535,8 +544,32 @@ def test_every_decoder_instruction_lands_in_a_named_kernel():
     astray = sorted(n for n, k in verdicts.items()
                     if k not in device.DECODER_KERNEL_NAMES)
     assert not astray, astray[:10]
-    assert set(verdicts.values()) == set(device.DECODER_KERNEL_NAMES)
+    shared = set(device.DECODER_KERNEL_NAMES) - {
+        "mla_attention", "full_attention", "window_attention"}
+    assert set(verdicts.values()) == shared | attention
     assert any(device.is_remat(name) for name in inside)
+
+
+def test_a_window_attention_call_goes_to_its_kernel_made_again_and_backward():
+    """The banded kernel's calls as the laguna step names them: forward, made
+    again under remat, and the backward's (dq; dk and dv) all go to
+    `window_attention`; the made-again one is counted as remat; the full
+    layers' go to `full_attention`."""
+    layer = "jit(step)/{}CausalDecoder/layers_1/{}window_attention/" \
+        "causal_attention/{}"
+    cases = [
+        layer.format("jvp(loss)/", "", "pallas_call"),
+        layer.format("jvp(loss)/", "checkpoint/rematted_computation/",
+                     "pallas_call"),
+        layer.format("transpose(jvp(loss))/", "checkpoint/",
+                     "splash_mha_dq_no_residuals/pallas_call"),
+        layer.format("transpose(jvp(loss))/", "checkpoint/",
+                     "splash_mha_dkv_no_residuals/pallas_call")]
+    assert [device.kernel_of(c) for c in cases] == ["window_attention"] * 4
+    assert [device.is_remat(c) for c in cases] == [False, True, False, False]
+    assert device.kernel_of(cases[0].replace(
+        "layers_1/window_attention", "layers_4/full_attention")) \
+        == "full_attention"
 
 
 def test_an_instruction_over_several_lines_keeps_its_name_and_its_module():
