@@ -39,19 +39,21 @@ adds the terms of its own experts only, plus the shared expert. What the
 absent experts would add is left out; nothing stands in for the other chips
 or their exchange.
 
-Its device time is a function of shapes alone. The slots routed to held
-experts are laid, sorted by expert, into ONE buffer of STATIC rows, each
-expert's group padded to whole row tiles, and every tile is computed (a
-grouped matmul whose tile -> expert map is data: `ops/grouped_matmul.py`);
-where a slot lands is data (row indices), never a shape, a grid extent or a
-trip count. Rows move by gathers in both directions: the backward of a
-gather is a gather through the inverse map (`_gather_rows`), so no
-scatter-add runs. Routing is dropless: a slot beyond the buffer is COUNTED
-(`expert_overflow`) and the benchmark's step turns any into a NaN loss; none
-is dropped silently. A buffer of `capacity_factor` = router_experts /
-experts_held takes every slot a step has, whatever the routing (router_experts
-x min(experts_per_token, experts_held) / (experts_per_token x experts_held)
-is the least that does).
+The slots routed to held experts are laid, sorted by expert, into ONE
+buffer of STATIC rows, each expert's group padded to whole row tiles, the
+groups one after another from row 0 (a grouped matmul whose tile -> expert
+map is data: `ops/grouped_matmul.py`). Where a slot lands is data (row
+indices), never a shape, a grid extent or a trip count; so is how many
+leading tiles the groups fill (`expert_tiles`), and the grouped matmuls
+compute those alone: the expert layer's device time follows the routing by
+that count, the rest of the step's does not. Rows move by gathers in both
+directions: the backward of a gather is a gather through the inverse map
+(`_gather_rows`), so no scatter-add runs. Routing is dropless: a slot
+beyond the buffer is COUNTED (`expert_overflow`) and the benchmark's step
+turns any into a NaN loss; none is dropped silently. A buffer of
+`capacity_factor` = router_experts / experts_held takes every slot a step
+has, whatever the routing (router_experts x min(experts_per_token,
+experts_held) / (experts_per_token x experts_held) is the least that does).
 
 Every module's name is a kernel of `obs/device.py`'s table: `mla_attention`,
 `full_attention`, `window_attention`, `expert_router` (scores, top-k, the
@@ -320,13 +322,21 @@ def expert_buffer(tokens: int, experts_per_token: int, router_experts: int,
                   experts_held: int, capacity_factor: float) -> tuple:
     """(rows, row tile) of the held experts' one buffer: `capacity_factor`
     times the slots a step of `tokens` tokens sends the held experts under
-    even routing, rounded up to the grouped matmul's row tile (512; 8 for a
-    toy), and a tile more for each held expert, since every group is padded
-    to whole tiles. At `capacity_factor` = router_experts / experts_held the
-    buffer takes every slot of the step: no routing can overflow it."""
+    even routing, rounded up to the grouped matmul's row tile, and a tile
+    more for each held expert, since every group is padded to whole tiles.
+    At `capacity_factor` = router_experts / experts_held the buffer takes
+    every slot of the step: no routing can overflow it.
+
+    The tile is 128 rows where a held expert expects 128 slots or more (8
+    for a toy): the kernels compute the filled tiles alone, so what a tile
+    costs is the padding of each group's last one and a step of the grid
+    for each tile of the buffer. On a v5e, at 320 and at 768 slots an
+    expert, 128 rows gave the expert layer its shortest time (against 256
+    and 512), by the smaller buffer the routing's gathers fill."""
     slots = math.ceil(capacity_factor * tokens * experts_per_token
                       * experts_held / router_experts)
-    tile = 512 if slots >= 512 * experts_held else 8
+    expected = tokens * experts_per_token / router_experts
+    tile = 128 if expected >= 128 else 8
     return (-(-slots // tile) + experts_held) * tile, tile
 
 
@@ -379,14 +389,15 @@ class Kernel(nn.Module):
 
 class ExpertMLP(nn.Module):
     """The held experts' SwiGLUs on their one buffer, (rows, d) in and out:
-    three grouped matmuls (`ops/grouped_matmul.py`), every tile computed;
-    `tile_group` says whose expert's rows a tile holds."""
+    three grouped matmuls (`ops/grouped_matmul.py`); `tile_group` says whose
+    expert's rows a tile holds, `live_tiles` how many leading tiles hold
+    rows (the rows out past them are unspecified on the kernels' path)."""
     experts_held: int
     width: int
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, buf, tile_group):
+    def __call__(self, buf, tile_group, live_tiles):
         dim = buf.shape[-1]
         stack = lambda name, *shape: Kernel(
             (self.experts_held, *shape), name=name)().astype(self.dtype)
@@ -394,7 +405,7 @@ class ExpertMLP(nn.Module):
         # gather of each tile's weights, or the kernels interpreted behind
         # the CPU tests' door (as the attention above)
         if runtime.on_tpu() or attention_ops.pallas_attention_enabled():
-            mm = functools.partial(grouped_matmul,
+            mm = functools.partial(grouped_matmul, live_tiles=live_tiles,
                                    interpret=not runtime.on_tpu())
         else:
             mm = grouped_matmul_reference
@@ -455,6 +466,11 @@ class ExpertLayer(nn.Module):
                 (jnp.arange(rows // tile)[:, None] * tile
                  >= (start + group)[None, :]).sum(1), held - 1
             ).astype(jnp.int32)
+            # the tiles the groups fill, a prefix: the grouped matmuls
+            # compute those alone. Nothing reads a row past it: the combine
+            # gathers the rows of `row_of_slot`, the buffer's gradient those
+            # its `inverse` lists, and the weights' gradient skips the rest.
+            live = jnp.minimum((start[-1] + group[-1]) // tile, rows // tile)
             row_group = jnp.repeat(tile_group, tile)
             within = jnp.arange(rows) - jnp.take(start, row_group)
             filled = within < jnp.take(load, row_group)
@@ -469,7 +485,7 @@ class ExpertLayer(nn.Module):
                 pad(row_of_slot.reshape(tokens, k), rows))
 
         out = ExpertMLP(held, self.expert_width, self.dtype,
-                        name="expert_mlp")(buf, tile_group)
+                        name="expert_mlp")(buf, tile_group, live)
 
         with jax.named_scope(ROUTER_SCOPE):
             back = _gather_rows(
@@ -487,6 +503,7 @@ class ExpertLayer(nn.Module):
                 "expert_slots": load.sum(),
                 "expert_overflow": (is_held & ~fits).sum(),
                 "expert_max_load": load.max(),
+                "expert_tiles": live,
             }
         return y, counters
 
@@ -579,7 +596,8 @@ class CausalDecoder(nn.Module):
     `counters`: `expert_slots` (slots routed to held experts, mean over the
     expert layers), `expert_overflow` (slots beyond their expert's rows, all
     layers), `expert_max_load` (the fullest expert's slots, any layer),
-    `expert_rows` (rows of one layer's buffer).
+    `expert_tiles` (row tiles the grouped matmuls compute, mean over the
+    expert layers). `expert_rows` gives the rows of one layer's buffer.
     """
     vocab_size: int
     hidden_size: int
@@ -653,5 +671,6 @@ class CausalDecoder(nn.Module):
                 "expert_slots": total("expert_slots", jnp.mean),
                 "expert_overflow": total("expert_overflow", jnp.sum),
                 "expert_max_load": total("expert_max_load", jnp.max),
+                "expert_tiles": total("expert_tiles", jnp.mean),
             }
         return head(x), counters

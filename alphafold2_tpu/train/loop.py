@@ -135,7 +135,8 @@ def make_decoder_train_step(model):
     `batch["tokens"]` is (b, n + 1); the model reads the first n and is
     held to the last n (mean next-token cross-entropy over the vocabulary
     held, float32 logits). The expert layers' counters (`expert_slots`,
-    `expert_overflow`, `expert_max_load`) come back beside the loss."""
+    `expert_overflow`, `expert_max_load`, `expert_tiles`) come back beside
+    the loss."""
 
     def loss_fn(params, batch, rng):
         tokens = batch["tokens"]

@@ -515,28 +515,34 @@ def test_grouped_causal_attention_compiles_for_v5e(heads, window, one_chip,
     assert len(calls) == 3, len(calls)
 
 
-def test_grouped_matmul_compiles_for_v5e(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("live", [False, True],
+                         ids=["every_tile", "live_tiles"])
+def test_grouped_matmul_compiles_for_v5e(live, one_chip, no_persistent_cache):
     """The expert layer's grouped matmuls at the benchmark's decoder's own
-    sizes (a buffer of 6 x 16,384 slots and a tile for each of 16 held
-    experts, 2,048 -> 768 -> 2,048, bf16), forward and backward: six Mosaic
-    calls (y, dx and dw of each of two matmuls)."""
+    sizes (a buffer of 6 x 16,384 slots and a tile of 128 rows for each of
+    16 held experts, 2,048 -> 768 -> 2,048, bf16), forward and backward: six
+    Mosaic calls (y, dx and dw of each of two matmuls); with every tile
+    computed, or with the count of live tiles handed in as data."""
     from alphafold2_tpu.ops.grouped_matmul import grouped_matmul
 
-    rows, dim, width, held, tile = 6 * 16384 + 16 * 512, 2048, 768, 16, 512
+    rows, dim, width, held, tile = 6 * 16384 + 16 * 128, 2048, 768, 16, 128
     shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         s, dtype, sharding=one_chip)
 
-    def loss_gradients(x, w_in, w_out, tile_group):
+    def loss_gradients(x, w_in, w_out, tile_group, live_tiles):
+        live_tiles = live_tiles if live else None
+
         def loss(x, w_in, w_out):
-            hidden = grouped_matmul(x, w_in, tile_group)
-            return grouped_matmul(hidden, w_out, tile_group).astype(
-                jnp.float32).sum()
+            hidden = grouped_matmul(x, w_in, tile_group, live_tiles)
+            return grouped_matmul(hidden, w_out, tile_group,
+                                  live_tiles).astype(jnp.float32).sum()
         return jax.grad(loss, argnums=(0, 1, 2))(x, w_in, w_out)
 
     with jax.default_matmul_precision("default"):
         text = _compiled_kernel_text(loss_gradients, (
             shape(rows, dim), shape(held, dim, width),
-            shape(held, width, dim), shape(rows // tile, dtype=jnp.int32)))
+            shape(held, width, dim), shape(rows // tile, dtype=jnp.int32),
+            shape(dtype=jnp.int32)))
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 5, len(calls)

@@ -230,11 +230,17 @@ def test_an_overflow_is_counted_and_fails_the_step():
 def test_the_buffer_is_static_and_takes_every_slot_at_the_bound():
     """Rows and row tile from the shapes alone; at `capacity_factor` =
     router_experts / experts_held the buffer holds all of a step's slots and
-    a tile of padding an expert: no routing can overflow it."""
+    a tile of padding an expert: no routing can overflow it. The tile is 128
+    rows where a held expert expects 128 slots or more (768 and 320 in the
+    decoder cells), 8 below."""
     assert decoder.expert_buffer(16384, 6, 128, 16, 8.0) == (
-        16384 * 6 + 16 * 512, 512)
+        16384 * 6 + 16 * 128, 128)
     assert decoder.expert_buffer(16384, 6, 128, 16, 2.0) == (
-        24576 + 16 * 512, 512)
+        24576 + 16 * 128, 128)
+    assert decoder.expert_buffer(8192, 10, 256, 8, 25.6) == (
+        65536 + 8 * 128, 128)
+    assert decoder.expert_buffer(2048, 8, 128, 16, 8.0)[1] == 128
+    assert decoder.expert_buffer(2040, 8, 128, 16, 8.0)[1] == 8
     assert decoder.expert_buffer(32, 2, 8, 2, 4.0) == (32 * 2 + 2 * 8, 8)
     model = family.build_model(_config())
     assert model.expert_rows(32) == 32 * 2 + 2 * 8
@@ -251,3 +257,51 @@ def test_the_expert_layer_through_the_grouped_kernels():
             lambda p, x: module.apply(p, x)[0],
             functools.partial(plain.expert_layer, NX, cfg), params, _x(),
             1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", DTYPES, ids=IDS)
+def test_the_kernels_compute_only_the_tiles_the_routing_filled(
+        dtype, tol, monkeypatch):
+    """Held experts 2..3 of 8, a buffer of 66 tiles of 8 rows: the grouped
+    kernels, under the TPU interpreter (unwritten memory reads NaN), compute
+    the filled prefix alone, and the layer's output and every parameter's
+    gradient are the every-tile path's (XLA's gathered weights): nothing
+    reads a row past the prefix. `expert_tiles` is the sum over the held
+    experts of max(ceil(load / tile), 1)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from alphafold2_tpu.ops import grouped_matmul as gm
+    cfg = _config(expert_start=2)
+    module = _expert_layer(cfg, dtype)
+    x = _x()
+    params = _draw(module, x)
+
+    def run(p):
+        y, vjp, counters = jax.vjp(lambda p: module.apply(p, x), p,
+                                   has_aux=True)
+        cot = jax.random.normal(jax.random.PRNGKey(9), y.shape, y.dtype)
+        return y, vjp(cot)[0], counters
+    want, want_grad, _ = run(params)
+    monkeypatch.setattr(
+        decoder, "grouped_matmul",
+        lambda x, w, tg, live_tiles=None, interpret=False: gm.grouped_matmul(
+            x, w, tg, live_tiles, interpret=pltpu.InterpretParams()))
+    with ops_attn.pallas_attention():
+        got, got_grad, counters = run(params)
+    _close(got, want, tol, "output")
+    flat = lambda t: jax.tree_util.tree_flatten_with_path(t)[0]
+    for (path, g), (_, w) in zip(flat(got_grad), flat(want_grad)):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        _close(g, w, tol, jax.tree_util.keystr(path))
+
+    held, k = cfg["n_routed_experts"], cfg["num_experts_per_tok"]
+    _, choice, _ = decoder.ExpertRouter(
+        cfg["router_experts"], k, cfg["routed_scaling_factor"],
+        dtype=dtype).apply(
+        {"params": params["params"]["expert_router"]}, x.reshape(-1, DIM))
+    local = np.asarray(choice).ravel() - 2
+    load = np.bincount(local[(local >= 0) & (local < held)], minlength=held)
+    rows, tile = decoder.expert_buffer(BATCH * N, k, cfg["router_experts"],
+                                       held, cfg["capacity_factor"])
+    tiles = sum(max(-(-int(n) // tile), 1) for n in load)
+    assert int(counters["expert_tiles"]) == tiles < rows // tile

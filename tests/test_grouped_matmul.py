@@ -1,11 +1,13 @@
 """The grouped matmul of the decoder's expert layer (`ops/grouped_matmul.py`:
 a static buffer, the tile -> group map as data) against XLA's gather of each
-tile's weights, forward and gradient, interpreted on the CPU."""
+tile's weights, forward and gradient, interpreted on the CPU; with a count of
+live tiles, under the TPU interpreter, whose unwritten memory reads NaN."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from alphafold2_tpu.ops.grouped_matmul import (grouped_matmul,
                                                grouped_matmul_reference)
@@ -59,3 +61,34 @@ def test_rows_must_be_whole_tiles_of_one_dtype():
         grouped_matmul(x[:-1], w, TILE_GROUP, interpret=True)
     with pytest.raises(ValueError, match="one dtype"):
         grouped_matmul(x.astype(jnp.bfloat16), w, TILE_GROUP, interpret=True)
+
+
+@pytest.mark.parametrize("live", [1, 4, 7, None],
+                         ids=["one_tile", "four_tiles", "every_tile", "none"])
+def test_only_the_live_tiles_are_read_and_written(live):
+    """Rows of x and dy past the live tiles are NaN: the live rows of y and
+    dx, and all of dw, are what the reference gives on those rows zeroed
+    (groups 2 and 3 have no live tile at four: their dw is zero). `None` is
+    every tile, the three-argument call."""
+    x, w, dy = _operands(jnp.float32)
+    rows = x.shape[0]
+    cut = rows if live is None else live * TILE
+    dead = (jnp.arange(rows) >= cut)[:, None]
+    poison = lambda t: jnp.where(dead, jnp.nan, t)
+    clean = lambda t: jnp.where(dead, 0.0, t)
+
+    args = () if live is None else (jnp.int32(live),)
+    y, vjp = jax.vjp(lambda x, w: grouped_matmul(
+        x, w, TILE_GROUP, *args, interpret=pltpu.InterpretParams()),
+        poison(x), w)
+    dx, dw = vjp(poison(dy))
+    want_y, want_vjp = jax.vjp(lambda x, w: grouped_matmul_reference(
+        x, w, TILE_GROUP), clean(x), w)
+    want_dx, want_dw = want_vjp(clean(dy))
+    for name, got, ref in (("y", y[:cut], want_y[:cut]),
+                           ("dx", dx[:cut], want_dx[:cut]),
+                           ("dw", dw, want_dw)):
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5,
+                                   err_msg=name)
+    if live == 4:
+        assert not np.any(want_dw[2:]) and not np.any(dw[2:])
