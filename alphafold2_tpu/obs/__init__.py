@@ -19,6 +19,12 @@ package replaces their private bookkeeping with these primitives:
             gaps booked to the scheduler worker's intervals, which the
             tracer enters as `jax.profiler.TraceAnnotation`s
             (`device.reduce`).
+- builds:   set-up on the same clock: every program's trace, lowering,
+            compile or cache read, and first run as ordered build records
+            (`builds.records()`, tagged with the program: a
+            `FoldExecutor` key, the training step), and each collection
+            of Python's collector (`builds.collections()`); installed on
+            import, always on.
 - export:   Prometheus text exposition + JSONL sharing one versioned
             `"schema": 1` record convention; `flatten()` for
             arbitrary-depth dict keys.
@@ -27,7 +33,7 @@ package replaces their private bookkeeping with these primitives:
 top-K slowest traces from a trace JSONL file (README "Observability").
 """
 
-from alphafold2_tpu.obs import device  # noqa: F401
+from alphafold2_tpu.obs import builds, device  # noqa: F401
 from alphafold2_tpu.obs.export import (JsonlExporter, SCHEMA_VERSION,  # noqa: F401
                                        flatten, prometheus_text,
                                        registry_json, write_prometheus)

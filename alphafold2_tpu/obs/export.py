@@ -23,6 +23,7 @@ import os
 import time
 from typing import IO, Optional
 
+from alphafold2_tpu.obs import builds
 from alphafold2_tpu.obs.registry import MetricsRegistry, get_registry
 
 SCHEMA_VERSION = 1
@@ -82,6 +83,7 @@ def _fmt_value(v: float) -> str:
 
 def prometheus_text(registry: Optional[MetricsRegistry] = None) -> str:
     """Render the registry in Prometheus text exposition format."""
+    builds.flush()
     registry = registry or get_registry()
     lines = []
     for metric in registry.metrics():
@@ -126,6 +128,7 @@ def write_prometheus(path: str,
 
 def registry_json(registry: Optional[MetricsRegistry] = None) -> dict:
     """One JSON object for the whole registry, schema-versioned."""
+    builds.flush()
     registry = registry or get_registry()
     return {"schema": SCHEMA_VERSION,
             "unix_s": round(time.time(), 3),

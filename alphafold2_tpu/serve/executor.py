@@ -55,7 +55,9 @@ from typing import Iterable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
+from alphafold2_tpu.obs import builds
 from alphafold2_tpu.obs.trace import NULL_TRACE
 from alphafold2_tpu.parallel.mesh import make_mesh
 from alphafold2_tpu.parallel.sharding import (fold_input_shardings,
@@ -75,6 +77,22 @@ ExecKey = Tuple[int, int, int, int, MeshShape, str, str]
 
 _SINGLE: MeshShape = (1, 1)
 _BATCH_INPUTS = ("seq", "mask", "msa", "msa_mask")
+
+
+def program_tag(cache_key: tuple) -> str:
+    """The name the build records give a key's program: variant, bucket x
+    batch, MSA depth, recycles (`fold/640x1/m128/r3`), and the mesh where it
+    is not one chip (`/2x2`)."""
+    bucket_len, batch_size, msa_depth, recycles, shape = cache_key[:5]
+    tag = (f"{cache_key[6]}/{bucket_len}x{batch_size}/m{msa_depth}"
+           f"/r{recycles}")
+    return tag if tuple(shape) == _SINGLE else f"{tag}/{mesh_label(shape)}"
+
+
+def _stage(name: str, trace):
+    """One stage of a build: a child span of `trace`'s `compile` span, or,
+    with no trace, a profiler annotation of the same name alone."""
+    return trace.span(name) if trace.enabled else TraceAnnotation(name)
 
 
 def _zero_batch(bucket_len: int, batch_size: int, msa_depth: int) -> dict:
@@ -190,20 +208,29 @@ class FoldExecutor:
         return jax.jit(run_step)
 
     def _compile(self, cache_key: tuple, num_recycles: int, args,
-                 mesh=None, variant: str = "fold"):
+                 mesh=None, variant: str = "fold", trace=NULL_TRACE):
         """AOT-compile the key's executable OUTSIDE the cache lock (an
         XLA compile can take seconds; holding the lock would stall
         concurrent hit lookups) and insert it. Falls back to the lazily
         compiling jitted callable on JAX versions/paths where AOT
         lowering refuses the argument structure. `mesh` (multi-chip
         slices only) is entered during lowering so the model's sharding
-        constraints bake into the executable."""
+        constraints bake into the executable. The three stages run one
+        by one, each a child span of `trace` (`trace`, `lower`,
+        `backend_compile`: the persistent cache is read inside the
+        last), and the build records book them under the key's
+        `program_tag`."""
         jitted = self._builder(variant, num_recycles)
         ctx = use_mesh(mesh) if mesh is not None \
             else contextlib.nullcontext()
         try:
-            with ctx:
-                fn = jitted.lower(*args).compile()
+            with ctx, builds.program(program_tag(cache_key)):
+                with _stage("trace", trace):
+                    traced = jitted.trace(*args)
+                with _stage("lower", trace):
+                    lowered = traced.lower()
+                with _stage("backend_compile", trace):
+                    fn = lowered.compile()
         except Exception:
             fn = jitted          # first call will compile lazily
         with self._lock:
@@ -320,14 +347,16 @@ class FoldExecutor:
         args = (self.params, batch["seq"], batch["mask"], batch["msa"],
                 batch["msa_mask"])
         cache_key = key + ((),)
-        fn = self._lookup(cache_key)
+        fn, first = self._lookup(cache_key), None
         if fn is None:
             with trace.span("compile", bucket_len=key[0],
                             batch_size=key[1], msa_depth=key[2],
                             num_recycles=key[3]):
-                fn = self._compile(cache_key, key[3], args)
+                fn = self._compile(cache_key, key[3], args, trace=trace)
+            first = cache_key
         with trace.span("fold", bucket_len=key[0]):
-            return self._invoke(fn, args, batch, trace=trace)
+            return self._invoke(fn, args, batch, trace=trace,
+                                first_run=first)
 
     def _run_on_slice(self, batch: dict, num_recycles: int, trace,
                       devices, mesh_shape) -> FoldResult:
@@ -341,19 +370,22 @@ class FoldExecutor:
         with trace.span("shard", mesh=label, devices=len(devices)):
             mesh, params = self._placed_params(devices, mesh_shape)
             args = (params,) + self._place_inputs(batch, mesh, devices)
-        fn = self._lookup(cache_key)
+        fn, first = self._lookup(cache_key), None
         if fn is None:
             with trace.span("compile", bucket_len=key[0],
                             batch_size=key[1], msa_depth=key[2],
                             num_recycles=key[3], mesh=label):
-                fn = self._compile(cache_key, key[3], args, mesh=mesh)
+                fn = self._compile(cache_key, key[3], args, mesh=mesh,
+                                   trace=trace)
+            first = cache_key
         with trace.span("fold", bucket_len=key[0], mesh=label):
             # the lazy-compile fallback traces on first call, so the
             # mesh context must be live during invocation too
             ctx = use_mesh(mesh) if mesh is not None \
                 else contextlib.nullcontext()
             with ctx:
-                return self._invoke(fn, args, batch, trace=trace)
+                return self._invoke(fn, args, batch, trace=trace,
+                                    first_run=first)
 
     # -- step-mode execution (scheduler-owned recycle loop) --------------
 
@@ -452,7 +484,7 @@ class FoldExecutor:
             cache_key = key + ((),)
             args = (self.params, batch["seq"], batch["mask"],
                     batch["msa"], batch["msa_mask"]) + tuple(extra_args)
-        fn = self._lookup(cache_key)
+        fn, first = self._lookup(cache_key), None
         if fn is None:
             with trace.span("compile", bucket_len=key[0],
                             batch_size=key[1], msa_depth=key[2],
@@ -460,21 +492,25 @@ class FoldExecutor:
                             **({"mesh": attrs["mesh"]} if "mesh" in attrs
                                else {})):
                 fn = self._compile(cache_key, 0, args, mesh=mesh,
-                                   variant=variant)
+                                   variant=variant, trace=trace)
+            first = cache_key
         with trace.span(span, bucket_len=key[0], **attrs):
             ctx = use_mesh(mesh) if mesh is not None \
                 else contextlib.nullcontext()
             with ctx:
                 return self._invoke(fn, args, batch, variant=variant,
                                     recycle=attrs.get("recycle"),
-                                    trace=trace)
+                                    trace=trace, first_run=first)
 
     def _invoke(self, fn, args, batch, variant: str = "fold",
-                recycle=None, trace=NULL_TRACE) -> FoldResult:
+                recycle=None, trace=NULL_TRACE,
+                first_run=None) -> FoldResult:
         """One execution, inside the caller's `fold` (or `recycle` /
         `admit`) span, which it splits in two for `trace`: `dispatch`,
         the host's share (inputs to the device, the call enqueued), and
-        `device_wait`, blocked until the results have landed."""
+        `device_wait`, blocked until the results have landed.
+        `first_run`: the cache key of a program built for this call, whose
+        first execution the build records book."""
         if self.faults is not None:
             # injected exceptions/latency fire BEFORE the device
             # call (a chaos fault must not waste real accelerator
@@ -483,16 +519,18 @@ class FoldExecutor:
             # index let a chaos plan hit a SPECIFIC recycle depth
             self.faults.on_executor_run(batch, variant=variant,
                                         recycle=recycle)
-        with trace.span("dispatch"):
-            result = fn(*args)
-        with trace.span("device_wait"):
-            result = jax.block_until_ready(result)
+        with builds.first_run(program_tag(first_run)) \
+                if first_run is not None else contextlib.nullcontext():
+            with trace.span("dispatch"):
+                result = fn(*args)
+            with trace.span("device_wait"):
+                result = jax.block_until_ready(result)
         if self.faults is not None:
             result = self.faults.mutate_result(batch, result)
         return result
 
     def warmup(self, keys: Iterable,
-               timer=None, devices: Optional[Sequence] = None,
+               devices: Optional[Sequence] = None,
                mesh_shape: Optional[MeshShape] = None,
                step_mode: bool = False,
                continuous: bool = False) -> int:
@@ -507,38 +545,30 @@ class FoldExecutor:
         (step_mode only) additionally warms the row-masked `init_rows`
         admission program, so a continuous batcher's first mid-loop row
         admission never triggers a mid-serving compile (ISSUE 11).
-        Returns the number of fresh compiles. Optional `timer` is a
-        profiling.StepTimer measuring each warmup (== compile+first-run)
-        wall time."""
+        Returns the number of fresh compiles. Each build's stages and its
+        first run are in the build records (`obs.builds`), under the
+        key's `program_tag`."""
         fresh = 0
         for key in keys:
             bucket_len, batch_size, msa_depth, num_recycles = \
                 self._normalize_key(key)[:4]
             before = self.misses
             batch = _zero_batch(bucket_len, batch_size, msa_depth)
-
-            def _one():
-                if step_mode:
-                    state = self.run_init(batch, devices=devices,
-                                          mesh_shape=mesh_shape)
-                    if continuous:
-                        # shape-only warm: the mask values never change
-                        # the compiled program, only which rows reinit
-                        mask0 = jnp.zeros((batch_size,), bool)
-                        state = self.run_init_rows(
-                            batch, state, mask0, devices=devices,
-                            mesh_shape=mesh_shape)
-                    self.run_step(batch, state, 0, devices=devices,
-                                  mesh_shape=mesh_shape)
-                else:
-                    self.run(batch, num_recycles, devices=devices,
-                             mesh_shape=mesh_shape)
-
-            if timer is not None:
-                with timer.measure():
-                    _one()
+            if step_mode:
+                state = self.run_init(batch, devices=devices,
+                                      mesh_shape=mesh_shape)
+                if continuous:
+                    # shape-only warm: the mask values never change
+                    # the compiled program, only which rows reinit
+                    mask0 = jnp.zeros((batch_size,), bool)
+                    state = self.run_init_rows(
+                        batch, state, mask0, devices=devices,
+                        mesh_shape=mesh_shape)
+                self.run_step(batch, state, 0, devices=devices,
+                              mesh_shape=mesh_shape)
             else:
-                _one()
+                self.run(batch, num_recycles, devices=devices,
+                         mesh_shape=mesh_shape)
             fresh += self.misses - before
         return fresh
 

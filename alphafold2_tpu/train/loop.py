@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from alphafold2_tpu.obs import builds
 from alphafold2_tpu.parallel.mesh import DATA_AXIS
 from alphafold2_tpu.parallel.sharding import active_mesh
 from alphafold2_tpu.train import losses
@@ -104,9 +105,12 @@ def compute_loss(model, params, batch, rng, train: bool = True,
 def _make_step(loss_fn):
     """The one step builder: `loss_fn(params, batch, rng) -> (loss,
     metrics)` becomes state, batch -> state, metrics (gradient, the
-    optimizer's update, the carried key split)."""
+    optimizer's update, the carried key split). Its body marks the build
+    that traces it as the program `train_step` (`obs.builds`), whatever
+    jit wraps it."""
 
     def train_step(state: TrainState, batch):
+        builds.mark("train_step")
         rng, new_rng = jax.random.split(state.rng)
 
         # `loss` and `optimizer`: no flax module names what happens

@@ -8,7 +8,7 @@ the benchmark metric (BASELINE.json), so the timer is first-class:
   through (StepTimer, serve.ServeMetrics, bench) — one stats path, no
   two subtly-different p99 definitions;
 - `StepTimer`: wall-clock accumulator with mean/p50/p90/p99/min stats,
-  used by `train.fit(step_timer=...)`, bench.py, and serve warmup.
+  used by `train.fit(step_timer=...)` and bench.py.
 
 The door to the profiler is `alphafold2_tpu.obs.device` (device time by
 kernel, and a capture's idle gaps booked to the worker's intervals).

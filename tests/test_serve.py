@@ -250,9 +250,15 @@ class TestExecutor:
 
     def test_warmup_precompiles(self, model_and_params):
         ex = FoldExecutor(*model_and_params, max_entries=4)
-        timer = StepTimer()
-        fresh = ex.warmup([(16, 1, MSA_DEPTH, 0)], timer=timer)
-        assert fresh == 1 and timer.count == 1
+        since = len(obs.builds.records())
+        fresh = ex.warmup([(16, 1, MSA_DEPTH, 0)])
+        booked = [(r["stage"], r["tagged"]) for r in
+                  obs.builds.records()[since:]
+                  if r["program"] == f"fold/16x1/m{MSA_DEPTH}/r0"]
+        # the key's build, stage by stage, then its first run
+        assert fresh == 1 and booked == [
+            ("trace", True), ("lower", True), ("compile", True),
+            ("first_run", True)]
         policy = BucketPolicy((16,))
         batch, _ = policy.assemble(requests_of((8,)), 16, 1)
         ex.run(batch, 0)
@@ -270,8 +276,9 @@ class TestExecutor:
         ex.run(batch, 0, trace=cold)
         cold.finish("ok")
         names = [s["name"] for s in cold.record()["spans"]]
-        # `fold` closes after the two halves it is split in
-        assert names == ["compile", "dispatch", "device_wait", "fold"]
+        # `compile` and `fold` close after the stages they are split in
+        assert names == ["trace", "lower", "backend_compile", "compile",
+                         "dispatch", "device_wait", "fold"]
         warm = tracer.start_trace("warm")
         ex.run(batch, 0, trace=warm)
         warm.finish("ok")

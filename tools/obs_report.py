@@ -83,6 +83,8 @@ from alphafold2_tpu.utils.profiling import percentile  # noqa: E402
 # gaps to (obs/device.py). dispatch and device_wait lie INSIDE their
 # parent span, so the waterfall's stages no longer add up to a request's
 # latency: read fold OR its two halves.
+# trace / lower / backend_compile (the three stages of a fresh build,
+# run one by one by the executor) lie INSIDE compile the same way.
 # --check's orphan-span rules apply to all of them unchanged, which is
 # how the chaos smokes prove recovery cost is fully accounted.
 #
@@ -92,7 +94,8 @@ from alphafold2_tpu.utils.profiling import percentile  # noqa: E402
 # phase instead of silently rendering at the bottom of the waterfall.
 STAGE_ORDER = ("reconcile", "featurize", "submit", "forward", "rpc",
                "queue", "parked", "retry", "drain", "batch_form",
-               "shard", "compile", "fold", "recycle", "admit",
+               "shard", "compile", "trace", "lower", "backend_compile",
+               "fold", "recycle", "admit",
                "dispatch", "device_wait", "fetch",
                "watchdog", "resume", "writeback", "peer_fetch",
                "peer_serve", "cache_lookup", "write", "preempt",
