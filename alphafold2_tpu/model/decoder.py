@@ -45,19 +45,25 @@ groups one after another from row 0 (a grouped matmul whose tile -> expert
 map is data: `ops/grouped_matmul.py`). Where a slot lands is data (row
 indices), never a shape, a grid extent or a trip count; so is how many
 leading tiles the groups fill (`expert_tiles`), and the grouped matmuls
-compute those alone: the expert layer's device time follows the routing by
-that count, the rest of the step's does not. Rows move by gathers in both
-directions: the backward of a gather is a gather through the inverse map
-(`_gather_rows`), so no scatter-add runs. Routing is dropless: a slot
-beyond the buffer is COUNTED (`expert_overflow`) and the benchmark's step
-turns any into a NaN loss; none is dropped silently. A buffer of
+compute those alone. On a TPU the rows move between the tokens and the
+buffer by two kernels, each the other's transpose (`ops/expert_rows.py`):
+the dispatch writes the filled rows of the live tiles, one row DMA each,
+and the rest of each live tile as zeros (the tiles past them unspecified);
+the combine reads the held slots' rows alone, a token tile's range of each
+held expert's group, and sums each token's weighted rows in float32. So the
+expert layer's device time follows the routing by the live tiles and the
+held slots, the rest of the step's does not. Off the TPU the rows move by
+XLA's gathers in both directions (`_gather_rows`: the backward of a gather
+is a gather through the inverse map, no scatter-add). Routing is dropless:
+a slot beyond the buffer is COUNTED (`expert_overflow`) and the benchmark's
+step turns any into a NaN loss; none is dropped silently. A buffer of
 `capacity_factor` = router_experts / experts_held takes every slot a step
 has, whatever the routing (router_experts x min(experts_per_token,
 experts_held) / (experts_per_token x experts_held) is the least that does).
 
 Every module's name is a kernel of `obs/device.py`'s table: `mla_attention`,
 `full_attention`, `window_attention`, `expert_router` (scores, top-k, the
-rows' indices, both gathers), `expert_mlp`, `shared_expert`, `dense_mlp`,
+rows' indices, both row moves), `expert_mlp`, `shared_expert`, `dense_mlp`,
 `lm_head` (embedding, head; the loss takes the same scope in the trainer).
 """
 
@@ -76,6 +82,7 @@ from jax.ad_checkpoint import checkpoint_name
 from alphafold2_tpu import runtime
 from alphafold2_tpu.model.evoformer import remat_block
 from alphafold2_tpu.ops import attention as attention_ops
+from alphafold2_tpu.ops import expert_rows
 from alphafold2_tpu.ops.grouped_matmul import (grouped_matmul,
                                                grouped_matmul_reference)
 
@@ -297,7 +304,10 @@ def _gather_rows(table, index, inverse):
     """table[index] for a (rows + 1, d) table whose last row is zeros (the
     row a padding index reads). `inverse` (rows + 1, m) lists, for each row
     of the table, the positions of `index.ravel()` that read it, padded with
-    `index.size`: the backward pass is the gather through it, no scatter."""
+    `index.size`: the backward pass is the gather through it, no scatter.
+    The row moves' contract off the TPU (and the tests' reference for the
+    kernels of `ops/expert_rows.py`): every row of the buffer and every
+    (token, k) slot moves."""
     return jnp.take(table, index, axis=0)
 
 
@@ -328,11 +338,13 @@ def expert_buffer(tokens: int, experts_per_token: int, router_experts: int,
     every slot of the step: no routing can overflow it.
 
     The tile is 128 rows where a held expert expects 128 slots or more (8
-    for a toy): the kernels compute the filled tiles alone, so what a tile
-    costs is the padding of each group's last one and a step of the grid
-    for each tile of the buffer. On a v5e, at 320 and at 768 slots an
-    expert, 128 rows gave the expert layer its shortest time (against 256
-    and 512), by the smaller buffer the routing's gathers fill."""
+    for a toy): the kernels compute and move the filled tiles alone (on a
+    TPU the padding rows of a live tile are written as zeros, the tiles
+    past them never), so what a tile costs is the padding of each group's
+    last one and a step of each kernel's grid for each tile of the buffer.
+    On a v5e, at 320 and at 768 slots an expert, 128 rows gave the expert
+    layer its shortest time (against 256 and 512), when XLA's gathers still
+    filled the whole buffer."""
     slots = math.ceil(capacity_factor * tokens * experts_per_token
                       * experts_held / router_experts)
     expected = tokens * experts_per_token / router_experts
@@ -467,9 +479,7 @@ class ExpertLayer(nn.Module):
                  >= (start + group)[None, :]).sum(1), held - 1
             ).astype(jnp.int32)
             # the tiles the groups fill, a prefix: the grouped matmuls
-            # compute those alone. Nothing reads a row past it: the combine
-            # gathers the rows of `row_of_slot`, the buffer's gradient those
-            # its `inverse` lists, and the weights' gradient skips the rest.
+            # compute those alone, and the row moves touch no row past it
             live = jnp.minimum((start[-1] + group[-1]) // tile, rows // tile)
             row_group = jnp.repeat(tile_group, tile)
             within = jnp.arange(rows) - jnp.take(start, row_group)
@@ -477,23 +487,41 @@ class ExpertLayer(nn.Module):
             slot_of_row = jnp.where(filled, jnp.take(order, jnp.minimum(
                 jnp.take(first, row_group) + within, slots - 1)), slots)
             token_of_row = jnp.where(filled, slot_of_row // k, tokens)
-            pad = lambda idx, fill: jnp.concatenate(
-                [idx, jnp.full((1,) + idx.shape[1:], fill, idx.dtype)])
-            zero_row = jnp.zeros((1, dim), self.dtype)
-            buf = _gather_rows(
-                jnp.concatenate([u, zero_row]), token_of_row,
-                pad(row_of_slot.reshape(tokens, k), rows))
+            # On a TPU the row moves are the kernels, by what the trace can
+            # see; off it XLA's gathers, or the kernels interpreted behind
+            # the CPU tests' door (as the grouped matmuls)
+            kernels = ((runtime.on_tpu()
+                        or attention_ops.pallas_attention_enabled())
+                       and expert_rows.admits(dim, self.dtype))
+            if kernels:
+                plan = expert_rows.plan_rows(
+                    token_of_row, slot_of_row, row_of_slot, local, start,
+                    live, k=k, tile=tile)
+                moves = dict(scope=ROUTER_SCOPE,
+                             interpret=not runtime.on_tpu())
+                buf = expert_rows.dispatch_rows(u, plan, **moves)
+            else:
+                pad = lambda idx, fill: jnp.concatenate(
+                    [idx, jnp.full((1,) + idx.shape[1:], fill, idx.dtype)])
+                zero_row = jnp.zeros((1, dim), self.dtype)
+                buf = _gather_rows(
+                    jnp.concatenate([u, zero_row]), token_of_row,
+                    pad(row_of_slot.reshape(tokens, k), rows))
 
         out = ExpertMLP(held, self.expert_width, self.dtype,
                         name="expert_mlp")(buf, tile_group, live)
 
         with jax.named_scope(ROUTER_SCOPE):
-            back = _gather_rows(
-                jnp.concatenate([out, zero_row]),
-                row_of_slot.reshape(tokens, k),
-                pad(slot_of_row[:, None], slots))           # (tokens, k, d)
-            w = jnp.where(fits.reshape(tokens, k), weights, 0.0)
-            routed = jnp.einsum("tk,tkd->td", w, back.astype(jnp.float32))
+            if kernels:
+                routed = expert_rows.combine_rows(out, weights, plan, **moves)
+            else:
+                back = _gather_rows(
+                    jnp.concatenate([out, zero_row]),
+                    row_of_slot.reshape(tokens, k),
+                    pad(slot_of_row[:, None], slots))       # (tokens, k, d)
+                w = jnp.where(fits.reshape(tokens, k), weights, 0.0)
+                routed = jnp.einsum("tk,tkd->td", w,
+                                    back.astype(jnp.float32))
 
         shared = SwiGLU(self.shared_experts * self.expert_width,
                         dtype=self.dtype, name="shared_expert")(u)

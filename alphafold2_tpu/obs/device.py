@@ -47,7 +47,7 @@ FOLD_KERNEL_NAMES = ("triangle_multiply", "triangle_attention",
 # the causal token decoder's (`model/decoder.py`): its modules' own names.
 # A layer's attention takes its kind's (latent; grouped-query under the
 # causal mask or a band of keys); `expert_router` holds the scores, the
-# choice, the rows' indices and both gathers; `lm_head` the embedding, the
+# choice, the rows' indices and both row moves; `lm_head` the embedding, the
 # head and the token loss
 DECODER_KERNEL_NAMES = ("mla_attention", "full_attention",
                         "window_attention", "expert_router", "expert_mlp",
@@ -82,9 +82,10 @@ _BY_COMPONENT = dict(KERNELS)
 
 # The name scopes the fused kernels put around their Pallas calls
 # (`ops.attention.fused_attention_merged`, `ops.triangle_multiply.
-# fused_triangle_multiply`): a custom call whose `op_name` has one of these
+# fused_triangle_multiply`, the expert layer's row moves `ops.expert_rows`,
+# inside `expert_router`): a custom call whose `op_name` has one of these
 # components ran a fused kernel
-FUSED_SCOPES = ("fused_attention", "fused_triangle_multiply")
+FUSED_SCOPES = ("fused_attention", "fused_triangle_multiply", "expert_rows")
 
 
 def _parts(op_name: Optional[str]) -> list:

@@ -546,3 +546,52 @@ def test_grouped_matmul_compiles_for_v5e(live, one_chip, no_persistent_cache):
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 5, len(calls)
+
+
+@pytest.mark.parametrize("tokens,dim,k,held,rows", [
+    (8192, 3072, 10, 8, 66560), (16384, 2048, 6, 16, 100352)],
+    ids=["laguna", "kanana"])
+def test_expert_row_moves_compile_for_v5e(tokens, dim, k, held, rows,
+                                          one_chip, no_persistent_cache):
+    """The expert layer's row moves at the decoder cells' own sizes (a
+    buffer of `rows` x `dim` in bf16, tiles of 128 rows, `held` experts,
+    `k` slots a token), forward and backward, the index lists built from the
+    routing's arrays: seven Mosaic calls (the table packed, dispatched,
+    combined; the cotangent packed, dispatched scaled and dotted with the
+    experts' output; the buffer's cotangent combined), and no XLA gather or
+    scatter that moves rows of `dim` values: what XLA still gathers are
+    scalars of the index lists."""
+    import re
+
+    from alphafold2_tpu.ops import expert_rows
+
+    shape = lambda *s, dtype=jnp.int32: jax.ShapeDtypeStruct(
+        s, dtype, sharding=one_chip)
+
+    def moves(u, out, weights, token_of_row, slot_of_row, row_of_slot,
+              expert_of_slot, group_start, live):
+        plan = expert_rows.plan_rows(token_of_row, slot_of_row, row_of_slot,
+                                     expert_of_slot, group_start, live, k=k,
+                                     tile=128)
+
+        def loss(u, out, weights):
+            buf = expert_rows.dispatch_rows(u, plan)
+            routed = expert_rows.combine_rows(out, weights, plan)
+            return buf.astype(jnp.float32).sum() + routed.astype(
+                jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(u, out, weights)
+
+    with jax.default_matmul_precision("default"):
+        text = _compiled_kernel_text(moves, (
+            shape(tokens, dim, dtype=jnp.bfloat16),
+            shape(rows, dim, dtype=jnp.bfloat16),
+            shape(tokens, k, dtype=jnp.float32), shape(rows), shape(rows),
+            shape(tokens * k), shape(tokens * k), shape(held), shape()))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 7, len(calls)
+    moved = [line for line in text.splitlines()
+             if re.search(r"\b(gather|scatter)\(", line)
+             and (" scatter(" in line or re.search(
+                 rf"slice_sizes=\{{[^}}]*\b{dim}\b", line))]
+    assert not moved, moved

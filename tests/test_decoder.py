@@ -263,13 +263,15 @@ def test_the_expert_layer_through_the_grouped_kernels():
 def test_the_kernels_compute_only_the_tiles_the_routing_filled(
         dtype, tol, monkeypatch):
     """Held experts 2..3 of 8, a buffer of 66 tiles of 8 rows: the grouped
-    kernels, under the TPU interpreter (unwritten memory reads NaN), compute
-    the filled prefix alone, and the layer's output and every parameter's
-    gradient are the every-tile path's (XLA's gathered weights): nothing
-    reads a row past the prefix. `expert_tiles` is the sum over the held
-    experts of max(ceil(load / tile), 1)."""
+    kernels and the row moves, under the TPU interpreter (unwritten memory
+    reads NaN), compute the filled prefix alone, and the layer's output and
+    every parameter's gradient, all finite, are the every-tile path's (XLA's
+    gathered weights and gathered rows): nothing reads a row past the
+    prefix. `expert_tiles` is the sum over the held experts of
+    max(ceil(load / tile), 1)."""
     from jax.experimental.pallas import tpu as pltpu
 
+    from alphafold2_tpu.ops import expert_rows
     from alphafold2_tpu.ops import grouped_matmul as gm
     cfg = _config(expert_start=2)
     module = _expert_layer(cfg, dtype)
@@ -286,8 +288,17 @@ def test_the_kernels_compute_only_the_tiles_the_routing_filled(
         decoder, "grouped_matmul",
         lambda x, w, tg, live_tiles=None, interpret=False: gm.grouped_matmul(
             x, w, tg, live_tiles, interpret=pltpu.InterpretParams()))
+    moved = []
+    for name in ("dispatch_rows", "combine_rows"):
+        def interpreted(*args, _move=getattr(expert_rows, name), _name=name,
+                        **kwargs):
+            moved.append((_name, kwargs["interpret"]))
+            return _move(*args, **kwargs)
+        monkeypatch.setattr(expert_rows, name, interpreted)
     with ops_attn.pallas_attention():
         got, got_grad, counters = run(params)
+    # the row moves ran, interpreted (the TPU interpreter, off the chip)
+    assert sorted(moved) == [("combine_rows", True), ("dispatch_rows", True)]
     _close(got, want, tol, "output")
     flat = lambda t: jax.tree_util.tree_flatten_with_path(t)[0]
     for (path, g), (_, w) in zip(flat(got_grad), flat(want_grad)):
